@@ -107,6 +107,8 @@ def main() -> None:
                     help="copy the merged bench.json over the baseline file "
                          "instead of gating against it")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
 
     suites = _suite_registry()
     assert sorted(suites) == sorted(SUITE_NAMES), \

@@ -1,58 +1,39 @@
-"""jax version bridging.
+"""Mesh helpers over jax's sharding surface.
 
-The codebase targets the modern sharding surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, positional ``AbstractMesh``), but the
-pinned toolchain ships jax 0.4.37 where
-
-  * ``shard_map`` still lives in ``jax.experimental.shard_map``,
-  * ``jax.make_mesh`` has no ``axis_types`` parameter (and
-    ``jax.sharding.AxisType`` does not exist — every mesh axis behaves as the
-    later ``Auto`` type, which is exactly what this repo wants),
-  * ``AbstractMesh`` takes a ``((name, size), ...)`` shape-tuple instead of
-    separate shapes/names sequences.
-
-Everything that touches one of those APIs goes through this module so the
-rest of the tree reads like current-jax code.  Each shim probes the modern
-spelling first, so on a newer jax these become thin pass-throughs.
+``shard_map`` is ``jax.shard_map``; :func:`make_mesh` and
+:func:`abstract_mesh` fix the two conventions the rest of the tree relies
+on — every mesh axis Auto-typed, and a small mesh taking the first devices
+of a process that has more.
 """
 from __future__ import annotations
 
+import math
 from typing import Sequence
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
 __all__ = ["shard_map", "make_mesh", "abstract_mesh"]
 
-
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+shard_map = jax.shard_map
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> "jax.sharding.Mesh":
-    """``jax.make_mesh`` with all axes Auto-typed, on any jax version.
+    """``jax.make_mesh`` with all axes Auto-typed.
 
-    Also slices ``jax.devices()`` down to the mesh size when ``devices`` is
-    not given — a (1, 1) test mesh must work inside a subprocess that forced
+    Slices ``jax.devices()`` down to the mesh size when ``devices`` is not
+    given — a (1, 1) test mesh must work inside a subprocess that forced
     8 host devices.
     """
-    import math
     if devices is None:
         devices = jax.devices()[:math.prod(tuple(axis_shapes))]
-    kwargs = {"devices": devices}
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5: be explicit
-        kwargs["axis_types"] = (
-            (jax.sharding.AxisType.Auto,) * len(tuple(axis_names)))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         devices=devices,
+                         axis_types=(AxisType.Auto,) * len(tuple(axis_names)))
 
 
 def abstract_mesh(axis_shapes: Sequence[int],
-                  axis_names: Sequence[str]) -> "jax.sharding.AbstractMesh":
+                  axis_names: Sequence[str]) -> AbstractMesh:
     """Device-free mesh (sharding-spec rules only read shape/axis names)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:  # jax 0.4.x signature: ((name, size), ...)
-        return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return AbstractMesh(tuple(axis_shapes), tuple(axis_names))

@@ -184,6 +184,18 @@ class PanelCSR:
         the SDD kernel's panel-layout output."""
         return panel_arr[self.src_panel, self.src_lane]
 
+    @functools.cached_property
+    def lane_cols(self) -> np.ndarray:
+        """Flat signed gather columns, the kernels' layout
+        (``kernels.panel_common.lane_cols``)."""
+        from ..kernels.panel_common import lane_cols
+        return lane_cols(self.panel_cols, self.panel_mask)
+
+    @functools.cached_property
+    def lane_vals(self) -> np.ndarray:
+        """Flat ``(P·G,)`` values, the CSR kernel's SMEM layout."""
+        return self.panel_vals.reshape(-1)
+
 
 @dataclasses.dataclass(frozen=True)
 class PanelBCSR:
@@ -232,6 +244,20 @@ class PanelBCSR:
         """Inverse of :meth:`scatter_values`: ``(P, Br, G)`` panel-layout
         data -> ``(ntiles, Br)`` (padding columns dropped)."""
         return panel_arr[self.src_panel, :, self.src_lane]
+
+    @functools.cached_property
+    def lane_cols(self) -> np.ndarray:
+        """Flat signed gather columns, the kernels' layout
+        (``kernels.panel_common.lane_cols``)."""
+        from ..kernels.panel_common import lane_cols
+        return lane_cols(self.panel_cols, self.panel_mask)
+
+    @functools.cached_property
+    def vals_window(self) -> np.ndarray:
+        """Lane-dense ``(Br, L)`` values, the kernel's layout
+        (``kernels.panel_common.values_window``)."""
+        from ..kernels.panel_common import values_window
+        return values_window(self.panel_vals)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -348,13 +374,14 @@ def _ensure_nonempty_rows(row_ptr, col_idx, vals):
     np.cumsum(new_counts, out=new_ptr[1:])
     new_cols = np.zeros(new_ptr[-1], np.int32)
     new_vals = np.zeros(new_ptr[-1], vals.dtype)
-    for i in range(nrows):
-        s, e = row_ptr[i], row_ptr[i + 1]
-        ns = new_ptr[i]
-        if e > s:
-            new_cols[ns:ns + (e - s)] = col_idx[s:e]
-            new_vals[ns:ns + (e - s)] = vals[s:e]
-        # else: the zero pad entry at (i, 0) is already in place.
+    # Entry k of row r moves to new_ptr[r] + (k - row_ptr[r]); an empty
+    # row's slot keeps the zero pad entry at (r, 0).
+    row = np.repeat(np.arange(nrows, dtype=np.int64), counts)
+    dest = (new_ptr[:-1].astype(np.int64)[row]
+            + np.arange(len(col_idx), dtype=np.int64)
+            - row_ptr[:-1].astype(np.int64)[row])
+    new_cols[dest] = col_idx
+    new_vals[dest] = vals
     return new_ptr, new_cols, new_vals
 
 
@@ -454,38 +481,30 @@ def bcsr_from_csr_rows(csr: CSR, start: int, stop: int, br: int, *,
     """
     nrows = stop - start
     nblocks = max((nrows + br - 1) // br, 1)
-    tile_map = {}
-    entry_dest = []  # (tr, j, off) per sliced entry, or None when dropped
-    for i in range(start, stop):
-        local = i - start
-        tr = local // br
-        off = local % br
-        for k in range(int(csr.row_ptr[i]), int(csr.row_ptr[i + 1])):
-            j = int(csr.col_idx[k])
-            v = csr.vals[k]
-            if v == 0 and not keep_zeros:
-                entry_dest.append(None)
-                continue  # drop structural pads from the parent CSR
-            key = (tr, j)
-            tile = tile_map.get(key)
-            if tile is None:
-                tile = np.zeros(br, csr.vals.dtype)
-                tile_map[key] = tile
-            tile[off] += v
-            entry_dest.append((tr, j, off))
-
-    # Ensure every block-row is visited at least once.
-    present = {tr for tr, _ in tile_map}
-    for tr in range(nblocks):
-        if tr not in present:
-            tile_map[(tr, 0)] = np.zeros(br, csr.vals.dtype)
-
-    keys = sorted(tile_map.keys())
-    ntiles = len(keys)
-    tile_rows = np.fromiter((k[0] for k in keys), np.int32, ntiles)
-    tile_cols = np.fromiter((k[1] for k in keys), np.int32, ntiles)
-    tile_vals = np.stack([tile_map[k] for k in keys]) if ntiles else \
-        np.zeros((0, br), csr.vals.dtype)
+    s, e = int(csr.row_ptr[start]), int(csr.row_ptr[stop])
+    local = np.repeat(np.arange(nrows, dtype=np.int64),
+                      np.diff(csr.row_ptr[start:stop + 1]).astype(np.int64))
+    cols = csr.col_idx[s:e].astype(np.int64)
+    vals = np.asarray(csr.vals[s:e])
+    # Zero-valued entries are structural pads of the parent CSR: dropped
+    # unless the structure must not depend on the values.
+    keep = np.ones(e - s, bool) if keep_zeros else vals != 0
+    tr, off = local[keep] // br, local[keep] % br
+    width = max(int(csr.shape[1]), 1)
+    # Tile key (block_row, col), linearised so np.unique sorts by
+    # (block_row, col); every block-row without a tile gets a zero tile at
+    # column 0 so the kernel still visits it.
+    missing = np.setdiff1d(np.arange(nblocks, dtype=np.int64), tr)
+    keys, inv = np.unique(np.concatenate([tr * width + cols[keep],
+                                          missing * width]),
+                          return_inverse=True)
+    tile_of = inv[:tr.size]
+    ntiles = int(keys.size)
+    tile_rows = (keys // width).astype(np.int32)
+    tile_cols = (keys % width).astype(np.int32)
+    tile_vals = np.zeros((ntiles, br), csr.vals.dtype)
+    # Duplicate (row, col) entries sum into one slot, in entry order.
+    np.add.at(tile_vals, (tile_of, off), vals[keep])
     counts = np.bincount(tile_rows, minlength=nblocks)
     block_ptr = np.zeros(nblocks + 1, np.int32)
     np.cumsum(counts, out=block_ptr[1:])
@@ -494,10 +513,8 @@ def bcsr_from_csr_rows(csr: CSR, start: int, stop: int, br: int, *,
                       nrows=nrows, shape=(nrows, csr.shape[1]))
     if not return_map:
         return bcsr
-    tile_of = {k: t for t, k in enumerate(keys)}
-    slot_map = np.fromiter(
-        (-1 if d is None else tile_of[(d[0], d[1])] * br + d[2]
-         for d in entry_dest), np.int64, len(entry_dest))
+    slot_map = np.full(e - s, -1, np.int64)
+    slot_map[keep] = tile_of * br + off
     return bcsr, slot_map
 
 

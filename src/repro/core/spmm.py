@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import engine, ref
-from ..kernels.panel_common import default_bn
+from ..kernels.panel_common import CSR_WORDS, default_bn, panel_calls
 from ..resilience import fallback as _resilience
 from . import partition
 from .formats import (CSR, DEFAULT_PANEL_G, HALF_PACKED_ROWS, LoopsFormat,
@@ -147,9 +147,9 @@ def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
         except Exception as e:   # noqa: BLE001 - the parts path IS the handler
             # The fused chain (pallas → interpret) is exhausted: degrade to
             # the two-pass parts path below, whose per-part chains reach the
-            # jnp oracle.  Respect the kill switch — with fallback disabled
-            # the failure must propagate for tests/operators to see.
-            if not _resilience.get_policy().enabled:
+            # jnp oracle.  With the kill switch on, or from pallas on a TPU,
+            # the failure propagates for tests/operators to see.
+            if not _resilience.degrades(backend):
                 raise
             _resilience.note_degraded("engine.fallback", part="fused",
                                       op="spmm",
@@ -361,7 +361,9 @@ def loops_grid_steps(fmt: LoopsFormat, n_cols: int,
     from the ideal).  ``macro_m > 1`` widens the effective panels (the
     cached panel views are built at ``panel_g_eff``), shrinking the count
     a further ``~macro_m``-fold; ``pipeline_depth = d`` adds ``d - 1``
-    fill/drain ramp steps per *executed* (non-empty) part.
+    fill/drain ramp steps per ``pallas_call`` of each *executed*
+    (non-empty) part — one call per SMEM-sized panel chunk
+    (``panel_common.panel_calls``).
     """
     bn = bn or default_bn(n_cols)
     col_blocks = -(-n_cols // bn)
@@ -375,10 +377,11 @@ def loops_grid_steps(fmt: LoopsFormat, n_cols: int,
         p_csr = 0
     if fmt.r_boundary == fmt.nrows:
         p_bcsr = 0
+    g = fmt.panel_g_eff
     steps = 0
-    for p in (p_csr, p_bcsr):
+    for p, words in ((p_csr, CSR_WORDS), (p_bcsr, 1)):
         if p > 0:
-            steps += (p + depth - 1) * col_blocks
+            steps += (p + panel_calls(p, g, words) * (depth - 1)) * col_blocks
     return steps
 
 

@@ -37,12 +37,16 @@ Precision (§3.3 FP16 path, Algorithm 3): the paper uses the 2-way widening
 shuffles.  The TPU MXU natively multiplies bf16 operands and accumulates in
 fp32 (``preferred_element_type=float32``), which realises the same
 half-in/single-accumulate contract without any shuffle — the packing is done
-by the hardware.  FP64 uses ``preferred_element_type=float64`` (lowered by
-XLA to VPU sequences on real TPUs, which have no f64 MXU mode).
+by the hardware.  fp32 operands contract at ``Precision.HIGHEST`` — full
+fp32, not the TPU's default single bf16 pass
+(``panel_common.dot_precision``).
 
 grid = (N // bn, P) (batched: (batch // bz, N // bn, P)); ``panel_rows`` is
 nondecreasing so output-block revisiting is legal, exactly as in the CSR
-kernel.  ``carry`` + ``row_block_offset`` support the fused single-pass
+kernel.  The panel values travel lane-dense — a ``(Br, W)`` window serves
+``W / G`` consecutive panels (``panel_common.values_window``) — because a
+``(Br, G)`` block per panel would pad to a whole HBM tile.  ``carry`` +
+``row_block_offset`` support the fused single-pass
 ``loops_spmm``: the kernel writes its blocks at a row offset into a shared
 buffer whose other rows (the CSR part's) are preserved through
 ``input_output_aliases``.
@@ -57,51 +61,70 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import batch_block, register_kernel, resolve_dtypes
-from .panel_common import (check_pipeline_depth, default_bn, first_last,
-                           first_last_at, grid_dims, panel_operands, parity,
-                           split_panel_refs)
+from .panel_common import (check_pipeline_depth, default_bn, dot_precision,
+                           first_last, first_last_at, grid_dims, init_acc,
+                           panel_operands, parity, row_view,
+                           pad_window, run_panel_chunks, split_panel_refs,
+                           window_panel)
+from .panel_common import panels_per_call as default_panels_per_call
 
 __all__ = ["bcsr_spmm_pallas", "bcsr_panels_spmm_pallas"]
+
+
+def _contract(acc_ref, a_panel, bpan, bz: int | None):
+    """``acc += (Br, G) @ (G, bn)`` on the MXU — once per batch slice
+    (sharing the A panel) when batched — at full precision for fp32
+    operands."""
+    prec = dot_precision(a_panel.dtype)
+    dims = (((1,), (0,)), ((), ()))
+    if bz is None:
+        acc_ref[...] += jax.lax.dot_general(
+            a_panel, bpan, dims, precision=prec,
+            preferred_element_type=acc_ref.dtype)
+        return
+    for z in range(bz):
+        acc_ref[z] += jax.lax.dot_general(
+            a_panel, bpan[z], dims, precision=prec,
+            preferred_element_type=acc_ref.dtype)
+
+
+def _gather_rows(b_refs, cols_ref, p, g: int, bpan_ref, bz: int | None,
+                 slot=None):
+    """Masked gather: assemble panel ``p``'s ``(G, bn)`` B panel(s) in VMEM
+    scratch (``bpan_ref`` is ``(G, bn)``, or ``(bz, G, bn)`` when batched,
+    behind a leading ping-pong ``slot`` axis when pipelined), zeroing
+    padding lanes (column -1: panels shorter than G at block-row
+    boundaries)."""
+    lead = () if slot is None else (slot,)
+    for i, b_ref in enumerate(b_refs):
+        row = b_ref[...].astype(bpan_ref.dtype)   # (1, bn) / (bz, 1, bn)
+        row = jnp.where(cols_ref[p * g + i] >= 0, row, jnp.zeros_like(row))
+        if bz is None:
+            bpan_ref[lead + (pl.ds(i, 1), slice(None))] = row
+        else:
+            bpan_ref[lead + (slice(None), pl.ds(i, 1), slice(None))] = row
 
 
 def _panel_kernel(g: int, has_carry: bool, bz: int | None, *refs):
     """One grid step: gather G rows of B into scratch, one (Br,G)@(G,bn)
     MXU contraction (``bz`` of them, sharing the A panel, when batched)."""
-    rows_ref, _, vals_ref, mask_ref, b_refs, (o_ref, bpan_ref, acc_ref) = \
-        split_panel_refs(refs, g, has_carry)
-    first, last = first_last(rows_ref, panel_axis=1 if bz is None else 2)
+    (prev_ref, rows_ref, cols_ref), (win_ref,), carry_ref, b_refs, \
+        (o_ref, bpan_ref, acc_ref) = \
+        split_panel_refs(refs, g, 3, 1, has_carry)
+    axis = 1 if bz is None else 2
+    k = pl.program_id(axis)
+    first, last = first_last(rows_ref, panel_axis=axis)
 
     @pl.when(first)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_acc(acc_ref, carry_ref, prev_ref, rows_ref, k)
 
-    # Masked gather: assemble the (G, bn) B panel(s) in VMEM scratch, zeroing
-    # padding lanes (panels shorter than G at block-row boundaries).
-    for i, b_ref in enumerate(b_refs):
-        if bz is None:
-            row = b_ref[...].astype(bpan_ref.dtype)      # (1, bn)
-            bpan_ref[i, :] = jnp.where(mask_ref[0, i] > 0, row,
-                                       jnp.zeros_like(row))[0]
-        else:
-            row = b_ref[...][:, 0, :].astype(bpan_ref.dtype)  # (bz, bn)
-            bpan_ref[:, i, :] = jnp.where(mask_ref[0, i] > 0, row,
-                                          jnp.zeros_like(row))
+    _gather_rows(b_refs, cols_ref, k, g, bpan_ref, bz)
 
     # One real MXU contraction per grid step: G batched fmopa rounds
     # (Figure 2) instead of a chain of rank-1 (Br,1)@(1,bn) updates.  For
     # bf16 the MXU widens to fp32 in hardware (2-way fmopa equivalent).
-    a_panel = vals_ref[0]        # (Br, G)
-    if bz is None:
-        acc_ref[...] += jax.lax.dot_general(
-            a_panel, bpan_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)
-    else:
-        # The A panel is shared across the bz batch slices: broadcast it and
-        # contract batch-wise — (bz, Br, G) @ (bz, G, bn) -> (bz, Br, bn).
-        a_b = jnp.broadcast_to(a_panel, (bz,) + a_panel.shape)
-        acc_ref[...] += jax.lax.dot_general(
-            a_b, bpan_ref[...], (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=acc_ref.dtype)
+    _contract(acc_ref, window_panel(win_ref[...], k, g), bpan_ref[...], bz)
 
     @pl.when(last)
     def _flush():
@@ -116,27 +139,18 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
     gather DMAs of the next panel overlap this panel's ``(Br,G)@(G,bn)``
     contraction.  Compute/init/flush are predicated off during the
     ``depth - 1`` fill ramp steps."""
-    rows_ref, _, vals_ref, mask_ref, b_refs, (o_ref, bpan_ref, acc_ref) = \
-        split_panel_refs(refs, g, has_carry)
+    (prev_ref, rows_ref, cols_ref), (win_ref,), carry_ref, b_refs, \
+        (o_ref, bpan_ref, acc_ref) = \
+        split_panel_refs(refs, g, 3, 1, has_carry)
     axis = 1 if bz is None else 2
     k = pl.program_id(axis)
     npanels = pl.num_programs(axis) - (depth - 1)
-
-    def _assemble(slot):
-        for i, b_ref in enumerate(b_refs):
-            if bz is None:
-                row = b_ref[...].astype(bpan_ref.dtype)          # (1, bn)
-                bpan_ref[slot, i, :] = jnp.where(
-                    mask_ref[0, i] > 0, row, jnp.zeros_like(row))[0]
-            else:
-                row = b_ref[...][:, 0, :].astype(bpan_ref.dtype)  # (bz, bn)
-                bpan_ref[slot, :, i, :] = jnp.where(
-                    mask_ref[0, i] > 0, row, jnp.zeros_like(row))
+    load = jnp.minimum(k, npanels - 1)
 
     for s in (0, 1):
         @pl.when(parity(k) == s)
         def _(s=s):
-            _assemble(s)
+            _gather_rows(b_refs, cols_ref, load, g, bpan_ref, bz, slot=s)
 
     @pl.when(k >= depth - 1)
     def _compute():
@@ -145,25 +159,14 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
 
         @pl.when(first)
         def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+            init_acc(acc_ref, carry_ref, prev_ref, rows_ref, c)
 
-        a_panel = vals_ref[0]        # (Br, G), panel c's values
-
-        def _contract(slot):
-            if bz is None:
-                acc_ref[...] += jax.lax.dot_general(
-                    a_panel, bpan_ref[slot], (((1,), (0,)), ((), ())),
-                    preferred_element_type=acc_ref.dtype)
-            else:
-                a_b = jnp.broadcast_to(a_panel, (bz,) + a_panel.shape)
-                acc_ref[...] += jax.lax.dot_general(
-                    a_b, bpan_ref[slot], (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=acc_ref.dtype)
+        a_panel = window_panel(win_ref[...], c, g)   # (Br, G), panel c
 
         for s in (0, 1):
             @pl.when(parity(k + 1) == s)
             def _(s=s):
-                _contract(s)
+                _contract(acc_ref, a_panel, bpan_ref[s], bz)
 
         @pl.when(last)
         def _flush():
@@ -172,112 +175,127 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("nblocks", "row_block_offset", "out_rows", "bn",
-                     "out_dtype", "interpret", "pipeline_depth"))
-def bcsr_panels_spmm_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
-                            panel_vals: jax.Array, panel_mask: jax.Array,
-                            b: jax.Array, *, nblocks: int,
+    static_argnames=("g", "nblocks", "row_block_offset", "out_rows", "bn",
+                     "out_dtype", "interpret", "pipeline_depth",
+                     "panels_per_call"))
+def bcsr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
+                            vals_window: jax.Array, b: jax.Array, *, g: int,
+                            nblocks: int, interpret: bool,
                             row_block_offset: int = 0,
                             out_rows: int | None = None,
                             bn: int | None = None, out_dtype=None,
-                            interpret: bool = True,
                             carry: jax.Array | None = None,
-                            pipeline_depth: int = 1) -> jax.Array:
+                            pipeline_depth: int = 1,
+                            panels_per_call: int | None = None) -> jax.Array:
     """Panelized vector-wise BCSR SpMM.
 
     Args:
       panel_rows: (P,) int32 block-row per panel, nondecreasing.
-      panel_cols: (P, G) int32 gather rows of ``b`` per panel lane.
-      panel_vals: (P, Br, G) stacked tile values (zero columns = padding).
-      panel_mask: (P, G) lane validity (1 real / 0 padding), vals dtype.
+      lane_cols:  (P·G,) int32 gather rows of ``b``, lane ``i`` of panel
+                  ``p`` at ``p·G + i``, -1 on padding lanes
+                  (``PanelBCSR.lane_cols``).
+      vals_window: (Br, L) lane-dense tile values, panel ``p``'s ``(Br,
+                  G)`` operand at lanes ``[p·G, (p+1)·G)``, ``L >= P·G``
+                  padded to whole windows (``PanelBCSR.vals_window``).
+      g:          panel width G (static).
       b:          (K, N) dense operand, or (batch, K, N) for the native
                   batched grid (one kernel call serves every slice).
       nblocks:    number of block-rows (static).
+      interpret:  run the Pallas interpreter (CPU validation) or compile
+                  for the TPU; every caller states which.
       row_block_offset: first output block-row this kernel writes (static;
                   the fused path sets it to ``r_boundary // Br``).
       out_rows:   total rows of the returned array; defaults to
                   ``(row_block_offset + nblocks) * Br``.
       bn:         B/accumulator column width per visit (multi-ZA-tile
-                  factor); defaults to ``panel_common.default_bn(N)`` —
-                  min(N, 512) when 512 | N, else the largest lane-aligned
-                  divisor (N=600 -> 200).
+                  factor); defaults to ``panel_common.default_bn(N)``.
       carry:      optional (..., out_rows, N) array aliased into the output;
                   rows not visited here keep its contents (fused mode).
       pipeline_depth: 1 (serial gather->contract, default) or 2 (double-
                   buffered B-panel prefetch through a ping-pong scratch
                   slot).  Unbatched results are bitwise identical across
                   depths; batched results agree to ~1 ulp.
+      panels_per_call: panels per ``pallas_call`` (default
+                  ``panel_common.panels_per_call(G)``, the SMEM bound);
+                  more panels run as chained chunks.
     """
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
                          f"{b.ndim}")
     depth = check_pipeline_depth(pipeline_depth)
-    npanels, br, g = panel_vals.shape
+    br = vals_window.shape[0]
     n = b.shape[-1]
     bn = bn or default_bn(n)
     if n % bn:
         raise ValueError(f"N={n} not divisible by bn={bn}")
-    acc_dtype, out_dtype = resolve_dtypes(panel_vals.dtype, out_dtype)
+    acc_dtype, out_dtype = resolve_dtypes(vals_window.dtype, out_dtype)
     out_rows = out_rows or (row_block_offset + nblocks) * br
-    has_carry = carry is not None
     batch = b.shape[0] if b.ndim == 3 else None
     bz = batch_block(batch) if batch is not None else 0
-    grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels,
-                        pipeline_depth=depth)
+    out_shape = ((out_rows, n) if batch is None else (batch, out_rows, n))
+    b_view = row_view(b, b.ndim - 2)
 
     def _rows(rows, k, j):
         return (row_block_offset + rows[k], j)
 
-    in_specs, args, aliases = panel_operands(
-        g=g, bn=bn, vals_block=(1, br, g), vals=panel_vals, mask=panel_mask,
-        b=b, carry=carry, carry_block=(br, bn), row_map=_rows,
-        bz=None if batch is None else bz, pipeline_depth=depth,
-        npanels=npanels)
+    def call(prev, rows, cols, win, acc_in):
+        npanels = rows.shape[0]
+        has_carry = acc_in is not None
+        grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels,
+                            pipeline_depth=depth)
+        in_specs, args, aliases = panel_operands(
+            g=g, bn=bn, b=b_view, carry=acc_in, carry_block=(br, bn),
+            row_map=_rows, bz=None if batch is None else bz,
+            pipeline_depth=depth, npanels=npanels,
+            window=pad_window(win, g))
 
-    if depth == 1:
-        def _out_k(k):
-            return k
-    else:
-        def _out_k(k):
-            return jnp.maximum(k - (depth - 1), 0)
+        if depth == 1:
+            def _out_k(k):
+                return k
+        else:
+            def _out_k(k):
+                return jnp.maximum(k - (depth - 1), 0)
 
-    if batch is None:
-        out_specs = pl.BlockSpec(
-            (br, bn), lambda j, k, rows, cols: _rows(rows, _out_k(k), j))
-        out_shape = jax.ShapeDtypeStruct((out_rows, n), out_dtype)
-        bpan_shape = (g, bn) if depth == 1 else (depth, g, bn)
+        if batch is None:
+            out_specs = pl.BlockSpec(
+                (br, bn), lambda j, k, *s: _rows(s[1], _out_k(k), j))
+            bpan_shape = (g, bn) if depth == 1 else (depth, g, bn)
+            acc_shape = (br, bn)
+        else:
+            out_specs = pl.BlockSpec(
+                (bz, br, bn),
+                lambda z, j, k, *s: (z,) + _rows(s[1], _out_k(k), j))
+            bpan_shape = (bz, g, bn) if depth == 1 else (depth, bz, g, bn)
+            acc_shape = (bz, br, bn)
         scratch = [pltpu.VMEM(bpan_shape, b.dtype),     # B panel (packed)
-                   pltpu.VMEM((br, bn), acc_dtype)]     # accumulator
-    else:
-        out_specs = pl.BlockSpec(
-            (bz, br, bn),
-            lambda z, j, k, rows, cols: (z,) + _rows(rows, _out_k(k), j))
-        out_shape = jax.ShapeDtypeStruct((batch, out_rows, n), out_dtype)
-        bpan_shape = (bz, g, bn) if depth == 1 else (depth, bz, g, bn)
-        scratch = [pltpu.VMEM(bpan_shape, b.dtype),     # B panels (packed)
-                   pltpu.VMEM((bz, br, bn), acc_dtype)]
+                   pltpu.VMEM(acc_shape, acc_dtype)]    # accumulator
 
-    if depth > 1:
-        kernel = functools.partial(_piped_panel_kernel, g, has_carry,
-                                   None if batch is None else bz, depth)
-    else:
-        kernel = functools.partial(_panel_kernel, g, has_carry,
-                                   None if batch is None else bz)
+        if depth > 1:
+            kernel = functools.partial(_piped_panel_kernel, g, has_carry,
+                                       None if batch is None else bz, depth)
+        else:
+            kernel = functools.partial(_panel_kernel, g, has_carry,
+                                       None if batch is None else bz)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # panel_rows, panel_cols
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(panel_rows, panel_cols, *args)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # prev_row, rows, signed cols
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(prev, rows, cols, *args)
+
+    return run_panel_chunks(
+        call, panel_rows, ((lane_cols, 0), (vals_window, 1)), g=g,
+        per_call=panels_per_call or default_panels_per_call(g),
+        carry=carry, out_shape=(out_shape, out_dtype))
 
 
 @functools.partial(
@@ -285,20 +303,18 @@ def bcsr_panels_spmm_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
     static_argnames=("nblocks", "bn", "out_dtype", "interpret"))
 def bcsr_spmm_pallas(tile_rows: jax.Array, tile_cols: jax.Array,
                      tile_vals: jax.Array, b: jax.Array, *, nblocks: int,
-                     bn: int | None = None, out_dtype=None,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool, bn: int | None = None,
+                     out_dtype=None) -> jax.Array:
     """Flat-array entry point: one tile per panel (G = 1, rank-1 updates).
 
     Returns the padded (..., nblocks * Br, N) result.  Format-level callers
     should prefer :func:`bcsr_panels_spmm_pallas` with a host-packed
     ``PanelBCSR`` for real G-wide matmul panels.
     """
-    ntiles, br = tile_vals.shape
     return bcsr_panels_spmm_pallas(
-        tile_rows, tile_cols.reshape(ntiles, 1),
-        tile_vals.reshape(ntiles, br, 1), jnp.ones((ntiles, 1),
-                                                   tile_vals.dtype),
-        b, nblocks=nblocks, bn=bn, out_dtype=out_dtype, interpret=interpret)
+        tile_rows, tile_cols.astype(jnp.int32), jnp.transpose(tile_vals), b,
+        g=1, nblocks=nblocks, bn=bn, out_dtype=out_dtype,
+        interpret=interpret)
 
 
 register_kernel("bcsr", "spmm", "panels", bcsr_panels_spmm_pallas)

@@ -39,6 +39,12 @@ Implementation notes
 * ``panel_rows``/``panel_cols`` arrive via scalar prefetch (SMEM) so the B-row
   gathers are expressed in BlockSpec index_maps — the standard Pallas-TPU
   sparse-gather idiom; the DMAs for step k+1 overlap with compute of step k.
+  SMEM bounds the panels per ``pallas_call``; longer panel streams run in
+  chained chunks (``panel_common.run_panel_chunks``).
+* B rows and output rows are single-row blocks, addressed through ``(rows,
+  1, N)`` views so that every block satisfies the TPU tiling rule
+  (``panel_common.row_view``); panel values ride in SMEM as scalars, and the
+  lane mask as column -1 (``panel_common.lane_cols``).
 * Accumulation runs in fp32 scratch for {bf16, f16} inputs (f16f16f32
   contract) and in the native dtype for f32/f64 — the shared promotion
   helper ``repro.kernels.engine.resolve_dtypes``.
@@ -60,73 +66,76 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import batch_block, register_kernel, resolve_dtypes
-from .panel_common import (check_pipeline_depth, default_bn, first_last,
-                           first_last_at, grid_dims, panel_operands, parity,
-                           split_panel_refs)
+from .panel_common import (CSR_WORDS, check_pipeline_depth, default_bn,
+                           first_last, first_last_at, grid_dims, init_acc,
+                           panel_operands, parity, row_view,
+                           run_panel_chunks, split_panel_refs)
+from .panel_common import panels_per_call as default_panels_per_call
 
 __all__ = ["csr_spmm_pallas", "csr_panels_spmm_pallas"]
+
+
+def _axpy(acc, cols_ref, vals_ref, lane, row):
+    """``acc + vals[lane] * row`` where the lane is real, else ``acc``.
+    A padding lane's B row is zeroed before the multiply (its value is 0),
+    so a padding lane never turns a non-finite B row into a NaN."""
+    row = row.astype(acc.dtype)
+    row = jnp.where(cols_ref[lane] >= 0, row, jnp.zeros_like(row))
+    return acc + vals_ref[lane] * row     # AXPY over N lanes
 
 
 def _panel_kernel(g: int, has_carry: bool, bz: int | None, *refs):
     """One grid step: masked gather of G rows of B, multiply-reduce over G
     into the resident accumulator (``bz`` batch slices at once when
-    batched)."""
-    rows_ref, _, vals_ref, mask_ref, b_refs, (o_ref, acc_ref) = \
-        split_panel_refs(refs, g, has_carry)
-    first, last = first_last(rows_ref, panel_axis=1 if bz is None else 2)
+    batched).  Rows are ``(1, bn)``, or ``(bz, 1, bn)`` when batched."""
+    (prev_ref, rows_ref, cols_ref, vals_ref), _, carry_ref, b_refs, \
+        (o_ref, acc_ref) = split_panel_refs(refs, g, 4, 0, has_carry)
+    axis = 1 if bz is None else 2
+    k = pl.program_id(axis)
+    first, last = first_last(rows_ref, panel_axis=axis)
 
     @pl.when(first)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_acc(acc_ref, carry_ref, prev_ref, rows_ref, k)
 
     # Masked broadcast-multiply-reduce over the G axis: lane i contributes
-    # vals[i] * B[cols[i], :] iff mask[i] (padding lanes are dropped by the
-    # mask, so panels shorter than G — nnz not divisible by G, row
-    # boundaries — are exact, not approximate).  B's rows stay packed in
-    # their storage dtype; only the multiply promotes (bf16 -> f32 is exact,
-    # so half-precision panels cost half the VMEM traffic at identical
-    # results).
+    # vals[i] * B[cols[i], :] iff the lane is real (padding lanes carry
+    # column -1 and value 0, so panels shorter than G — nnz not divisible
+    # by G, row boundaries — are exact, not approximate).  Values are SMEM
+    # scalars; B's rows stay packed in their storage dtype and only the multiply
+    # promotes (bf16 -> f32 is exact, so half-precision panels cost half
+    # the VMEM traffic at identical results).
     acc = acc_ref[...]
     for i, b_ref in enumerate(b_refs):
-        v = vals_ref[0, i].astype(acc_ref.dtype)
-        row = b_ref[...] if bz is None else b_ref[...][:, 0, :]
-        contrib = v * row  # AXPY over N lanes; promotion at the multiply
-        acc = acc + jnp.where(mask_ref[0, i] > 0, contrib,
-                              jnp.zeros_like(contrib))
+        acc = _axpy(acc, cols_ref, vals_ref, k * g + i, b_ref[...])
     acc_ref[...] = acc
 
     @pl.when(last)
     def _flush():
-        out = acc_ref[...]
-        o_ref[...] = (out if bz is None else out[:, None, :]).astype(
-            o_ref.dtype)
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
                         *refs):
     """Depth-2 software pipeline: grid step ``k`` assembles panel
-    ``min(k, P-1)``'s (masked) B rows into ping-pong scratch slot ``k % 2``
-    while contracting panel ``max(k - 1, 0)`` out of slot ``(k+1) % 2`` —
-    the B gathers of the next panel overlap the AXPY of the current one.
-    The grid carries ``depth - 1`` extra fill/drain ramp steps; compute,
-    init and flush are predicated off during the fill ramp."""
-    rows_ref, _, vals_ref, mask_ref, b_refs, \
-        (o_ref, bpan_ref, mpan_ref, acc_ref) = \
-        split_panel_refs(refs, g, has_carry)
+    ``min(k, P-1)``'s B rows into ping-pong scratch slot ``k % 2`` while
+    contracting panel ``max(k - 1, 0)`` out of slot ``(k+1) % 2`` — the B
+    gathers of the next panel overlap the AXPY of the current one.  The
+    grid carries ``depth - 1`` extra fill/drain ramp steps; compute, init
+    and flush are predicated off during the fill ramp."""
+    (prev_ref, rows_ref, cols_ref, vals_ref), _, carry_ref, b_refs, \
+        (o_ref, bpan_ref, acc_ref) = \
+        split_panel_refs(refs, g, 4, 0, has_carry)
     axis = 1 if bz is None else 2
     k = pl.program_id(axis)
     npanels = pl.num_programs(axis) - (depth - 1)
 
     def _assemble(slot):
-        # Stage the raw (packed-dtype) B rows plus the mask panel; the
-        # compute stream applies the mask exactly like the depth-1 kernel
-        # (where AFTER the multiply) so results stay bitwise identical.
-        mpan_ref[slot] = mask_ref[...]
+        # Stage the raw (packed-dtype) B rows; the compute stream applies
+        # the lane mask exactly like the depth-1 kernel, so results stay
+        # bitwise identical.
         for i, b_ref in enumerate(b_refs):
-            if bz is None:
-                bpan_ref[slot, i, :] = b_ref[...][0]
-            else:
-                bpan_ref[slot, i, :, :] = b_ref[...][:, 0, :]
+            bpan_ref[slot, i] = b_ref[...]
 
     for s in (0, 1):
         @pl.when(parity(k) == s)
@@ -140,17 +149,13 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
 
         @pl.when(first)
         def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+            init_acc(acc_ref, carry_ref, prev_ref, rows_ref, c)
 
         def _accumulate(slot):
             acc = acc_ref[...]
             for i in range(g):
-                v = vals_ref[0, i].astype(acc_ref.dtype)
-                row = (bpan_ref[slot, i, :][None] if bz is None
-                       else bpan_ref[slot, i, :, :])
-                contrib = v * row   # promotion at the multiply (packed B)
-                acc = acc + jnp.where(mpan_ref[slot, 0, i] > 0, contrib,
-                                      jnp.zeros_like(contrib))
+                acc = _axpy(acc, cols_ref, vals_ref, c * g + i,
+                            bpan_ref[slot, i])
             acc_ref[...] = acc
 
         for s in (0, 1):
@@ -160,43 +165,46 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
 
         @pl.when(last)
         def _flush():
-            out = acc_ref[...]
-            o_ref[...] = (out if bz is None else out[:, None, :]).astype(
-                o_ref.dtype)
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("nrows", "out_rows", "bn", "out_dtype", "interpret",
-                     "pipeline_depth"))
-def csr_panels_spmm_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
-                           panel_vals: jax.Array, panel_mask: jax.Array,
-                           b: jax.Array, *, nrows: int,
+    static_argnames=("g", "nrows", "out_rows", "bn", "out_dtype",
+                     "interpret", "pipeline_depth", "panels_per_call"))
+def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
+                           lane_vals: jax.Array, b: jax.Array, *, g: int,
+                           nrows: int, interpret: bool,
                            out_rows: int | None = None, bn: int | None = None,
-                           out_dtype=None, interpret: bool = True,
-                           carry: jax.Array | None = None,
-                           pipeline_depth: int = 1) -> jax.Array:
-    """C[r] += sum_i mask[p,i] * vals[p,i] * B[cols[p,i], :] per panel p.
+                           out_dtype=None, carry: jax.Array | None = None,
+                           pipeline_depth: int = 1,
+                           panels_per_call: int | None = None) -> jax.Array:
+    """C[r] += sum_i vals[p,i] * B[cols[p,i], :] over the real lanes of
+    every panel p.
 
     Args:
       panel_rows: (P,) int32, nondecreasing output row per panel.
-      panel_cols: (P, G) int32 gather rows of ``b`` per panel lane.
-      panel_vals: (P, G) values (0 on padding lanes).
-      panel_mask: (P, G) lane validity (1 real / 0 padding), vals dtype.
+      lane_cols:  (P·G,) int32 gather rows of ``b``, lane ``i`` of panel
+                  ``p`` at ``p·G + i``, -1 on padding lanes
+                  (``PanelCSR.lane_cols``).
+      lane_vals:  (P·G,) values in the same order, 0 on padding lanes
+                  (``PanelCSR.lane_vals``).
+      g:          panel width G (static).
       b:          (K, N) dense operand, or (batch, K, N) for the native
                   batched grid (one kernel call serves every slice).
       nrows:      logical output row count this kernel writes (static).
+      interpret:  run the Pallas interpreter (CPU validation) or compile
+                  for the TPU; every caller states which.
       out_rows:   total rows of the returned array (>= nrows; rows beyond
                   ``nrows`` are the fused path's BCSR territory).  Defaults
                   to ``nrows``.
       bn:         dense-column block width; defaults to
-                  ``panel_common.default_bn(N)`` (min(N, 512) when 512 | N,
-                  else the largest lane-aligned divisor) — the wide block is
+                  ``panel_common.default_bn(N)`` (the whole row up to 512,
+                  else the widest 128-lane multiple) — the wide block is
                   the column-direction analogue of the paper's multi-tile
                   trick (several 128-lane tiles per visit).
       carry:      optional (..., out_rows, N) array aliased into the output;
                   rows not visited here keep its contents (fused mode).
-      interpret:  run the Pallas interpreter (CPU validation); False on TPU.
       pipeline_depth: 1 (serial gather->contract, default) or 2 (double-
                   buffered B-panel prefetch: the next panel's rows assemble
                   into a ping-pong VMEM slot while this panel contracts).
@@ -204,90 +212,102 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
                   (the compute stream replays the depth-1 expression);
                   batched results agree to ~1 ulp (XLA's multiply-add
                   contraction differs across the two graphs).
+      panels_per_call: panels per ``pallas_call`` (default
+                  ``panel_common.panels_per_call(G, CSR_WORDS)``, the SMEM
+                  bound for prefetched columns and values); more panels
+                  run as chained chunks.
     """
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
                          f"{b.ndim}")
     depth = check_pipeline_depth(pipeline_depth)
-    npanels, g = panel_cols.shape
     n = b.shape[-1]
     bn = bn or default_bn(n)
     if n % bn:
         raise ValueError(f"N={n} not divisible by bn={bn}")
-    acc_dtype, out_dtype = resolve_dtypes(panel_vals.dtype, out_dtype)
+    acc_dtype, out_dtype = resolve_dtypes(lane_vals.dtype, out_dtype)
     out_rows = out_rows or nrows
-    has_carry = carry is not None
     batch = b.shape[0] if b.ndim == 3 else None
     bz = batch_block(batch) if batch is not None else 0
-    grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels,
-                        pipeline_depth=depth)
+    row_axis = b.ndim - 2
+    out_shape = ((out_rows, n) if batch is None else (batch, out_rows, n))
+    b_view = row_view(b, row_axis)
 
     def _rows(rows, k, j):
-        return (rows[k], j)
+        return (rows[k], 0, j)
 
-    in_specs, args, aliases = panel_operands(
-        g=g, bn=bn, vals_block=(1, g), vals=panel_vals, mask=panel_mask,
-        b=b, carry=carry, carry_block=(1, bn), row_map=_rows,
-        bz=None if batch is None else bz, pipeline_depth=depth,
-        npanels=npanels)
+    def call(prev, rows, cols, vals, acc_in):
+        npanels = rows.shape[0]
+        has_carry = acc_in is not None
+        grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels,
+                            pipeline_depth=depth)
+        in_specs, args, aliases = panel_operands(
+            g=g, bn=bn, b=b_view,
+            carry=None if acc_in is None else row_view(acc_in, row_axis),
+            carry_block=(None, 1, bn), row_map=_rows,
+            bz=None if batch is None else bz, pipeline_depth=depth,
+            npanels=npanels)
 
-    if depth == 1:
-        def _out_k(k):
-            return k
-    else:
-        def _out_k(k):
-            return jnp.maximum(k - (depth - 1), 0)
+        if depth == 1:
+            def _out_k(k):
+                return k
+        else:
+            def _out_k(k):
+                return jnp.maximum(k - (depth - 1), 0)
 
-    if batch is None:
-        out_specs = pl.BlockSpec(
-            (1, bn), lambda j, k, rows, cols: _rows(rows, _out_k(k), j))
-        out_shape = jax.ShapeDtypeStruct((out_rows, n), out_dtype)
-        acc_shape = (1, bn)
-        bpan_shape = (depth, g, bn)
-    else:
-        out_specs = pl.BlockSpec(
-            (bz, 1, bn),
-            lambda z, j, k, rows, cols: (z,) + _rows(rows, _out_k(k), j))
-        out_shape = jax.ShapeDtypeStruct((batch, out_rows, n), out_dtype)
-        acc_shape = (bz, bn)
-        bpan_shape = (depth, g, bz, bn)   # contiguous (bz, bn) row reads
+        if batch is None:
+            out_specs = pl.BlockSpec(
+                (None, 1, bn), lambda j, k, *s: _rows(s[1], _out_k(k), j))
+            acc_shape = (1, bn)
+            bpan_shape = (depth, g, 1, bn)
+        else:
+            out_specs = pl.BlockSpec(
+                (bz, None, 1, bn),
+                lambda z, j, k, *s: (z,) + _rows(s[1], _out_k(k), j))
+            acc_shape = (bz, 1, bn)
+            bpan_shape = (depth, g, bz, 1, bn)
 
-    scratch = [pltpu.VMEM(acc_shape, acc_dtype)]
-    if depth > 1:
-        # Ping-pong B-panel buffer, packed in B's storage dtype (half
-        # precision stays half-width in VMEM; promotion happens at the
-        # multiply against the fp32-resident accumulator), plus the staged
-        # mask panel the compute stream applies one step later.
-        scratch.insert(0, pltpu.VMEM((depth, 1, g), panel_mask.dtype))
-        scratch.insert(0, pltpu.VMEM(bpan_shape, b.dtype))
-        kernel = functools.partial(_piped_panel_kernel, g, has_carry,
-                                   None if batch is None else bz, depth)
-    else:
-        kernel = functools.partial(_panel_kernel, g, has_carry,
-                                   None if batch is None else bz)
+        scratch = [pltpu.VMEM(acc_shape, acc_dtype)]
+        if depth > 1:
+            # Ping-pong B-panel buffer, packed in B's storage dtype (half
+            # precision stays half-width in VMEM; promotion happens at the
+            # multiply against the fp32-resident accumulator).
+            scratch.insert(0, pltpu.VMEM(bpan_shape, b.dtype))
+            kernel = functools.partial(_piped_panel_kernel, g, has_carry,
+                                       None if batch is None else bz, depth)
+        else:
+            kernel = functools.partial(_panel_kernel, g, has_carry,
+                                       None if batch is None else bz)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # panel_rows, panel_cols
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(panel_rows, panel_cols, *args)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # prev_row, rows, signed cols, values
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        )
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                tuple(out_shape[:-1]) + (1, n), out_dtype),
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(prev, rows, cols, vals.astype(acc_dtype), *args)
+        return out.reshape(out_shape)
+
+    return run_panel_chunks(
+        call, panel_rows, ((lane_cols, 0), (lane_vals, 0)), g=g,
+        per_call=panels_per_call or default_panels_per_call(g, CSR_WORDS),
+        carry=carry, out_shape=(out_shape, out_dtype))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("nrows", "bn", "out_dtype", "interpret"))
 def csr_spmm_pallas(row_ids: jax.Array, col_idx: jax.Array, vals: jax.Array,
-                    b: jax.Array, *, nrows: int, bn: int | None = None,
-                    out_dtype=None, interpret: bool = True) -> jax.Array:
+                    b: jax.Array, *, nrows: int, interpret: bool,
+                    bn: int | None = None, out_dtype=None) -> jax.Array:
     """Flat-array entry point: one nonzero per panel (G = 1).
 
     Packing a (row, col)-sorted nonzero stream into width-1 panels is pure
@@ -295,11 +315,9 @@ def csr_spmm_pallas(row_ids: jax.Array, col_idx: jax.Array, vals: jax.Array,
     prefer :func:`csr_panels_spmm_pallas` with a host-packed
     ``PanelCSR`` for real G-wide panels.
     """
-    nnz = row_ids.shape[0]
     return csr_panels_spmm_pallas(
-        row_ids, col_idx.reshape(nnz, 1), vals.reshape(nnz, 1),
-        jnp.ones((nnz, 1), vals.dtype), b, nrows=nrows, bn=bn,
-        out_dtype=out_dtype, interpret=interpret)
+        row_ids, col_idx.astype(jnp.int32), vals, b, g=1, nrows=nrows,
+        bn=bn, out_dtype=out_dtype, interpret=interpret)
 
 
 register_kernel("csr", "spmm", "panels", csr_panels_spmm_pallas)

@@ -242,14 +242,15 @@ def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
                        b_dtype, value_dtype) -> dict:
     """Pipeline observability fields for a G-wide panel dispatch.
 
-    ``steps`` — grid steps including the ``depth - 1`` fill/drain ramp
+    ``steps`` — grid steps including the ``depth - 1`` fill/drain ramp of
+    each ``pallas_call`` (one per SMEM-sized panel chunk)
     (× batch groups, matching ``_note``'s default accounting at depth 1);
     ``scratch_bytes`` — VMEM scratch footprint (accumulator + the packed
     ping-pong B-panel buffer, which stays in B's storage dtype);
     ``prefetch_overlap`` — fraction of grid steps whose B-row gathers
     overlap a contraction (0.0 for the serial depth-1 kernels).
     """
-    from .panel_common import default_bn
+    from .panel_common import CSR_WORDS, default_bn, panel_calls
     groups = max(-(-nb // batch_block(nb)), 1)
     bz = batch_block(nb)
     bn_eff = bn or default_bn(n)
@@ -261,8 +262,10 @@ def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
         bpan_elems = max(depth, 1) * g * bn_eff * bz
     else:   # depth-1 CSR reads gathered B rows directly (no staging buffer)
         bpan_elems = depth * g * bn_eff * bz if depth > 1 else 0
-    steps = npanels + depth - 1
-    overlap = (max(npanels - 1, 0) / steps) if depth > 1 else 0.0
+    # Each SMEM-sized chunk is its own launch with its own ramp.
+    calls = panel_calls(npanels, g, CSR_WORDS if part == "csr" else 1)
+    steps = npanels + calls * (depth - 1)
+    overlap = (max(npanels - calls, 0) / steps) if depth > 1 else 0.0
     return {"pipeline_depth": depth,
             "steps": steps * groups,
             "scratch_bytes": int(scratch + bpan_elems * b_item),
@@ -305,12 +308,17 @@ def get_kernel(part: str, op: str, impl: str = "panels") -> Callable:
 # ---------------------------------------------------------------------------
 
 def panel_values(panels, vals):
-    """Static host-packed panel values, or the traced scatter of ``vals``
-    into the panels' ``src_panel``/``src_lane`` layout (live parameters of a
-    learned-sparse layer ride the static structure)."""
+    """Panel values in the kernels' lane layout (flat ``(P·G,)`` for CSR
+    panels, the ``(Br, L)`` window for BCSR panels): the host-packed
+    constants, or the traced scatter of ``vals`` into the panels'
+    ``src_panel``/``src_lane`` layout (live parameters of a learned-sparse
+    layer ride the static structure)."""
+    from .panel_common import values_window
     if vals is None:
-        return jnp.asarray(panels.panel_vals)
-    return panels.scatter_values(jnp.asarray(vals))
+        return jnp.asarray(panels.vals_window if panels.panel_vals.ndim == 3
+                           else panels.lane_vals)
+    live = panels.scatter_values(jnp.asarray(vals))
+    return values_window(live) if live.ndim == 3 else live.reshape(-1)
 
 
 # ---------------------------------------------------------------------------
@@ -359,9 +367,9 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
               batch=nb, n=int(b.shape[-1]), **extra)
         if panels is not None:
             out = get_kernel("csr", "spmm", "panels")(
-                jnp.asarray(panels.panel_rows), jnp.asarray(panels.panel_cols),
-                panel_values(panels, vals), jnp.asarray(panels.panel_mask),
-                b3p, nrows=csr.nrows, bn=bn, out_dtype=out_dtype,
+                jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
+                panel_values(panels, vals), b3p, g=panels.g,
+                nrows=csr.nrows, bn=bn, out_dtype=out_dtype,
                 interpret=interpret, pipeline_depth=depth)
         else:
             out = get_kernel("csr", "spmm", "flat")(
@@ -416,9 +424,9 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
               batch=nb, n=int(b.shape[-1]), **extra)
         if panels is not None:
             padded = get_kernel("bcsr", "spmm", "panels")(
-                jnp.asarray(panels.panel_rows), jnp.asarray(panels.panel_cols),
-                panel_values(panels, vals), jnp.asarray(panels.panel_mask),
-                b3p, nblocks=panels.nblocks, bn=bn, out_dtype=out_dtype,
+                jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
+                panel_values(panels, vals), b3p, g=panels.g,
+                nblocks=panels.nblocks, bn=bn, out_dtype=out_dtype,
                 interpret=interpret, pipeline_depth=depth)
         else:
             padded = get_kernel("bcsr", "spmm", "flat")(
@@ -487,16 +495,16 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
                   b_dtype=b.dtype, value_dtype=vdt))
         r_pad = r_b + bp.nblocks * br
         out = get_kernel("csr", "spmm", "panels")(
-            jnp.asarray(cp.panel_rows), jnp.asarray(cp.panel_cols),
-            panel_values(cp, csr_vals), jnp.asarray(cp.panel_mask),
-            b3p, nrows=r_b, out_rows=r_pad, bn=bn, out_dtype=out_dtype,
+            jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols),
+            panel_values(cp, csr_vals), b3p, g=cp.g, nrows=r_b,
+            out_rows=r_pad, bn=bn, out_dtype=out_dtype,
             interpret=interpret, pipeline_depth=depth)
         out = get_kernel("bcsr", "spmm", "panels")(
-            jnp.asarray(bp.panel_rows), jnp.asarray(bp.panel_cols),
-            panel_values(bp, bcsr_vals), jnp.asarray(bp.panel_mask),
-            b3p, nblocks=bp.nblocks, row_block_offset=r_b // br,
-            out_rows=r_pad, bn=bn, out_dtype=out_dtype, interpret=interpret,
-            carry=out, pipeline_depth=depth)
+            jnp.asarray(bp.panel_rows), jnp.asarray(bp.lane_cols),
+            panel_values(bp, bcsr_vals), b3p, g=bp.g, nblocks=bp.nblocks,
+            row_block_offset=r_b // br, out_rows=r_pad, bn=bn,
+            out_dtype=out_dtype, interpret=interpret, carry=out,
+            pipeline_depth=depth)
         if b3p is not b3:
             out = out[:b3.shape[0]]
         if r_pad != fmt.nrows:
@@ -611,14 +619,15 @@ def _loops_sdd_impl(fmt, dy, b, backend, bn, pipeline_depth=1):
               pipeline_depth=depth)
     if has_csr:
         d_csr = cp.gather_values(get_kernel("csr", "sdd", "panels")(
-            jnp.asarray(cp.panel_rows), jnp.asarray(cp.panel_cols), dy3, b3,
-            bn=bn, interpret=interpret, pipeline_depth=depth))
+            jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols), dy3, b3,
+            g=cp.g, bn=bn, interpret=interpret, pipeline_depth=depth))
     else:
         d_csr = jnp.zeros((csr.nnz,), acc)
     if has_bcsr:
         d_bcsr = bp.gather_values(get_kernel("bcsr", "sdd", "panels")(
-            jnp.asarray(bp.panel_rows), jnp.asarray(bp.panel_cols), dy_pad3,
-            b3, br=br, bn=bn, interpret=interpret, pipeline_depth=depth))
+            jnp.asarray(bp.panel_rows), jnp.asarray(bp.lane_cols), dy_pad3,
+            b3, g=bp.g, br=br, bn=bn, interpret=interpret,
+            pipeline_depth=depth))
     else:
         d_bcsr = jnp.zeros(bc.tile_vals.shape, acc)
     return d_csr, d_bcsr

@@ -80,9 +80,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                           causal: bool = True, block_q: int = 512,
-                           block_k: int = 512,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool, causal: bool = True,
+                           block_q: int = 512,
+                           block_k: int = 512) -> jax.Array:
     """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) with H % KV == 0.
 
     Returns (B, Sq, H, hd) in q.dtype.  Scores never leave VMEM: HBM traffic

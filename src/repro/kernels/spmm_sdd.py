@@ -13,8 +13,8 @@ G-wide panels the forward kernels execute (``repro.core.formats.PanelCSR`` /
 contracting them against the panel's cotangent rows:
 
   * CSR part — one grid step computes the G dot products
-    ``dY[panel_rows[p], :] · B[panel_cols[p, i], :]`` (a VPU
-    multiply-reduce, the AXPY kernel read backwards);
+    ``dY[panel_rows[p], :] · B[panel_cols[p, i], :]`` as one ``(1, bn) @
+    (bn, G)`` contraction (the AXPY kernel read backwards);
   * BCSR part — one grid step computes a ``(Br, bn) @ (bn, G)`` MXU
     contraction between the block-row's cotangent slab and the gathered B
     panel, yielding all ``Br × G`` per-tile-element gradients at once.
@@ -34,6 +34,12 @@ batch, so their cotangent is the **batch sum**, which the kernels realise
 by folding the batch axis into the same resident accumulation the
 N-reduction already uses — ``bz`` slices per step, one flush per panel.
 
+Single-row blocks (B rows, the CSR part's cotangent row, its ``(1, G)``
+output panel) address ``(rows, 1, N)`` views so that every block satisfies
+the TPU tiling rule, and the panel axis runs in SMEM-sized chunks
+(``panel_common.panels_per_call``); fp32 contractions run at full fp32
+precision (``panel_common.dot_precision``).
+
 Outputs are panel-layout ``(P, G)`` / ``(P, Br, G)`` arrays in the fp32
 accumulation dtype (the f16f16f32 contract of the forward kernels applies to
 the backward pass too).
@@ -48,7 +54,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import acc_dtype_for, batch_block, register_kernel
-from .panel_common import check_pipeline_depth, default_bn, parity
+from .panel_common import (check_pipeline_depth, default_bn, dot_precision,
+                           gather_col, panels_per_call, parity, row_view)
 
 __all__ = ["csr_sdd_panels_pallas", "bcsr_sdd_panels_pallas"]
 
@@ -81,34 +88,60 @@ def _sdd_col_maps(depth: int, nb: int):
             lambda jj: jnp.maximum(jj - (depth - 1), 0))
 
 
-def _csr_sdd_kernel(g: int, bz: int | None, *refs):
-    """One grid step: G masked-free dot products dY[row]·B[col_i] into the
-    panel's (1, G) accumulator (summed over batch slices when batched);
-    flush after the last reduction block."""
+def _gather_rows(b_refs, bpan_ref, bz: int | None, slot=None):
+    """Copy the G gathered B rows into the ``(G, bn)`` panel scratch
+    (``(bz, G, bn)`` when batched, behind a leading ping-pong ``slot`` axis
+    when pipelined), packed in B's storage dtype."""
+    lead = () if slot is None else (slot,)
+    for i, b_ref in enumerate(b_refs):
+        row = b_ref[...].astype(bpan_ref.dtype)   # (1, bn) / (bz, 1, bn)
+        if bz is None:
+            bpan_ref[lead + (pl.ds(i, 1), slice(None))] = row
+        else:
+            bpan_ref[lead + (slice(None), pl.ds(i, 1), slice(None))] = row
+
+
+def _contract_dy(acc_ref, dy, bpan, bz: int | None):
+    """``acc += dY_block @ B_panelᵀ`` — ``(rows, bn) x (G, bn) -> (rows,
+    G)`` on the MXU, summed over the ``bz`` batch slices when batched (the
+    shared-values batch-sum contract of the backward pass)."""
+    acc = acc_ref.dtype
+    prec = dot_precision(acc)
+    dims = (((1,), (1,)), ((), ()))
+    slices = [(dy, bpan)] if bz is None else \
+        [(dy[z], bpan[z]) for z in range(bz)]
+    for d, bp in slices:
+        acc_ref[...] += jax.lax.dot_general(
+            d.astype(acc), bp.astype(acc), dims, precision=prec,
+            preferred_element_type=acc)
+
+
+def _sdd_kernel(g: int, bz: int | None, *refs):
+    """One grid step: gather the G B-rows into scratch, one MXU contraction
+    against the panel's cotangent rows — ``(1, bn)`` for the CSR part (the
+    G dot products ``dY[row]·B[col_i]``), ``(Br, bn)`` for the BCSR part
+    (all ``Br × G`` tile-element gradients) — summed over the batch slices
+    when batched; flush after the last reduction block."""
     _, _, dy_ref, *rest = refs
-    b_refs, (o_ref, acc_ref) = rest[:g], rest[g:]
+    b_refs, (o_ref, bpan_ref, acc_ref) = rest[:g], rest[g:]
     first, last = _reduction_edges(bz)
 
     @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    dy = dy_ref[...].astype(acc_ref.dtype)       # (1, bn) or (bz, 1, bn)
-    # jnp.sum over every axis reduces the batch slices too — exactly the
-    # shared-values batch-sum contract of the backward pass.
-    lanes = [jnp.sum(dy * b_ref[...].astype(acc_ref.dtype))[None]
-             for b_ref in b_refs]
-    acc_ref[...] += jnp.stack(lanes, axis=-1)    # (1, g)
+    _gather_rows(b_refs, bpan_ref, bz)
+    _contract_dy(acc_ref, dy_ref[...], bpan_ref[...], bz)
 
     @pl.when(last)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _piped_csr_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
+def _piped_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
     """Depth-2 SDD pipeline over the column-block reduction axis: step
     ``jj`` copies B's column block ``min(jj, nb-1)`` into ping-pong scratch
-    slot ``jj % 2`` (packed in B's storage dtype) while reducing the
+    slot ``jj % 2`` (packed in B's storage dtype) while contracting the
     cotangent's column block ``max(jj - 1, 0)`` against slot
     ``(jj+1) % 2``."""
     _, _, dy_ref, *rest = refs
@@ -117,18 +150,10 @@ def _piped_csr_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
     jj = pl.program_id(jaxis)
     first, last = _reduction_edges(bz, depth)
 
-    def _assemble(slot):
-        for i, b_ref in enumerate(b_refs):
-            if bz is None:
-                bpan_ref[slot, i, :] = b_ref[...].astype(bpan_ref.dtype)[0]
-            else:
-                bpan_ref[slot, :, i, :] = \
-                    b_ref[...][:, 0, :].astype(bpan_ref.dtype)
-
     for s in (0, 1):
         @pl.when(parity(jj) == s)
         def _(s=s):
-            _assemble(s)
+            _gather_rows(b_refs, bpan_ref, bz, slot=s)
 
     @pl.when(jj >= depth - 1)
     def _compute():
@@ -136,53 +161,51 @@ def _piped_csr_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        dy = dy_ref[...].astype(acc_ref.dtype)   # (1, bn) or (bz, 1, bn)
-
-        def _reduce(slot):
-            if bz is None:
-                lanes = [jnp.sum(dy * bpan_ref[slot, i, :]
-                                 .astype(acc_ref.dtype))[None]
-                         for i in range(g)]
-            else:
-                lanes = [jnp.sum(dy[:, 0, :] * bpan_ref[slot, :, i, :]
-                                 .astype(acc_ref.dtype))[None]
-                         for i in range(g)]
-            acc_ref[...] += jnp.stack(lanes, axis=-1)    # (1, g)
-
         for s in (0, 1):
             @pl.when(parity(jj + 1) == s)
             def _(s=s):
-                _reduce(s)
+                _contract_dy(acc_ref, dy_ref[...], bpan_ref[s], bz)
 
         @pl.when(last)
         def _flush():
             o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("bn", "interpret", "pipeline_depth"))
-def csr_sdd_panels_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
-                          dy: jax.Array, b: jax.Array, *,
-                          bn: int | None = None,
-                          interpret: bool = True,
-                          pipeline_depth: int = 1) -> jax.Array:
-    """Per-nonzero gradients for the CSR part, in panel layout.
+def _run_chunks(call, rows, cols, g: int, per_call: int):
+    """``call(rows, cols)`` over SMEM-sized chunks of the panel axis: a
+    ``lax.fori_loop`` over the full chunks (one compiled kernel) plus a
+    remainder launch, each writing its panels' rows of the output.  The SDD
+    grids carry no state across panels, so chunks are independent."""
+    npanels = int(rows.shape[0])
+    if npanels <= per_call:
+        return call(rows, cols)
+    nfull, head = npanels // per_call, npanels // per_call * per_call
 
-    Args:
-      panel_rows: (P,) int32 — cotangent row per panel (``PanelCSR`` order).
-      panel_cols: (P, G) int32 — gather rows of ``b`` per lane.
-      dy:         (M, N) output cotangent, or (batch, M, N) — batch summed
-                  (rows beyond the CSR region are simply never indexed).
-      b:          (K, N) or (batch, K, N) the forward dense operand.
-    Returns:
-      (P, G) gradients in the accumulation dtype; padding lanes undefined —
-      gather real slots with ``PanelCSR.gather_values``.
-    """
+    def body(c, out):
+        part = call(jax.lax.dynamic_slice_in_dim(rows, c * per_call,
+                                                 per_call),
+                    jax.lax.dynamic_slice_in_dim(cols, c * per_call * g,
+                                                 per_call * g))
+        return jax.lax.dynamic_update_slice_in_dim(out, part, c * per_call,
+                                                   0)
+
+    part = jax.eval_shape(call, rows[:per_call], cols[:per_call * g])
+    out = jnp.zeros((npanels,) + part.shape[1:], part.dtype)
+    out = jax.lax.fori_loop(0, nfull, body, out)
+    if head < npanels:
+        out = out.at[head:].set(call(rows[head:], cols[head * g:]))
+    return out
+
+
+def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
+                bn, interpret: bool, pipeline_depth: int, per_call):
+    """Shared entry of both SDD kernels: ``br=None`` is the CSR part
+    (one cotangent row per panel, addressed through its ``(M, 1, N)``
+    view), an int the BCSR part (one ``(Br, bn)`` block-row slab)."""
     if dy.ndim != b.ndim or b.ndim not in (2, 3):
         raise ValueError(f"dy/b must both be rank 2 or 3; got {dy.ndim} / "
                          f"{b.ndim}")
     depth = check_pipeline_depth(pipeline_depth)
-    npanels, g = panel_cols.shape
     n = b.shape[-1]
     bn = bn or default_bn(n)
     if n % bn:
@@ -191,227 +214,133 @@ def csr_sdd_panels_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
     batch = b.shape[0] if b.ndim == 3 else None
     nb = n // bn
     lj, cj = _sdd_col_maps(depth, nb)
+    row_axis = b.ndim - 2
+    b_view = row_view(b, row_axis)
+    rows_out = 1 if br is None else br
+    if br is None:
+        dy = row_view(dy, row_axis)
+        dy_block, dy_index = (None, 1, bn), (lambda r, j: (r, 0, cj(j)))
+    else:
+        dy_block, dy_index = (br, bn), (lambda r, j: (r, cj(j)))
     if batch is None:
-        grid = (npanels, nb + depth - 1)
         bz = None
+        grid = (nb + depth - 1,)
         in_specs = [
-            pl.BlockSpec((1, bn),
-                         lambda p, j, rows, cols: (rows[p], cj(j))),
-            *[pl.BlockSpec((1, bn),
-                           lambda p, j, rows, cols, i=i: (cols[p, i], lj(j)))
+            pl.BlockSpec(dy_block, lambda p, j, rows, cols:
+                         dy_index(rows[p], j)),
+            *[pl.BlockSpec((None, 1, bn),
+                           lambda p, j, rows, cols, i=i:
+                           (gather_col(cols, p * g + i), 0, lj(j)))
               for i in range(g)],
         ]
-        out_specs = pl.BlockSpec((1, g), lambda p, j, rows, cols: (p, 0))
-        bpan_shape = (depth, g, bn)
+        out_specs = pl.BlockSpec((None, rows_out, g),
+                                 lambda p, j, rows, cols: (p, 0, 0))
+        bpan_shape = (g, bn)
     else:
         bz = batch_block(batch)
-        grid = (npanels, batch // bz, nb + depth - 1)
+        grid = (batch // bz, nb + depth - 1)
         in_specs = [
-            pl.BlockSpec((bz, 1, bn),
-                         lambda p, z, j, rows, cols: (z, rows[p], cj(j))),
-            *[pl.BlockSpec((bz, 1, bn),
+            pl.BlockSpec((bz,) + dy_block, lambda p, z, j, rows, cols:
+                         (z,) + dy_index(rows[p], j)),
+            *[pl.BlockSpec((bz, None, 1, bn),
                            lambda p, z, j, rows, cols, i=i:
-                           (z, cols[p, i], lj(j)))
+                           (z, gather_col(cols, p * g + i), 0, lj(j)))
               for i in range(g)],
         ]
-        out_specs = pl.BlockSpec((1, g), lambda p, z, j, rows, cols: (p, 0))
-        bpan_shape = (depth, bz, g, bn)
-    scratch = [pltpu.VMEM((1, g), acc_dtype)]
+        out_specs = pl.BlockSpec((None, rows_out, g),
+                                 lambda p, z, j, rows, cols: (p, 0, 0))
+        bpan_shape = (bz, g, bn)
     if depth > 1:
-        scratch.insert(0, pltpu.VMEM(bpan_shape, b.dtype))  # packed ping-pong
-        kernel = functools.partial(_piped_csr_sdd_kernel, g, bz, depth)
+        bpan_shape = (depth,) + bpan_shape     # packed ping-pong
+        kernel = functools.partial(_piped_sdd_kernel, g, bz, depth)
     else:
-        kernel = functools.partial(_csr_sdd_kernel, g, bz)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # panel_rows, panel_cols
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels, g), acc_dtype),
-        interpret=interpret,
-    )(panel_rows, panel_cols, dy, *([b] * g))
+        kernel = functools.partial(_sdd_kernel, g, bz)
+    scratch = [pltpu.VMEM(bpan_shape, b.dtype),
+               pltpu.VMEM((rows_out, g), acc_dtype)]
+
+    def call(rows, cols):
+        npanels = rows.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # panel_rows, flat panel columns
+            grid=(npanels,) + grid,
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((npanels, rows_out, g),
+                                           acc_dtype),
+            interpret=interpret,
+        )(rows, cols, dy, *([b_view] * g))
+
+    out = _run_chunks(call, panel_rows, lane_cols, g,
+                      per_call or panels_per_call(g))
+    return out.reshape(out.shape[0], g) if br is None else out
 
 
-def _bcsr_sdd_kernel(g: int, bz: int | None, *refs):
-    """One grid step: gather the G B-rows into scratch, one (Br,bn)@(bn,G)
-    MXU contraction against the block-row's cotangent slab (contracted over
-    the batch slices too when batched)."""
-    _, _, dy_ref, *rest = refs
-    b_refs, (o_ref, bpan_ref, acc_ref) = rest[:g], rest[g:]
-    first, last = _reduction_edges(bz)
+@functools.partial(jax.jit,
+                   static_argnames=("g", "bn", "interpret", "pipeline_depth",
+                                    "panels_per_call"))
+def csr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
+                          dy: jax.Array, b: jax.Array, *, g: int,
+                          interpret: bool, bn: int | None = None,
+                          pipeline_depth: int = 1,
+                          panels_per_call: int | None = None) -> jax.Array:
+    """Per-nonzero gradients for the CSR part, in panel layout.
 
-    @pl.when(first)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # The B panel stays packed in B's storage dtype in scratch (half the
-    # VMEM for bf16/f16); promotion to the accumulation dtype happens at
-    # the dot operand read — bf16 -> f32 is exact, so results are
-    # unchanged.
-    if bz is None:
-        for i, b_ref in enumerate(b_refs):
-            bpan_ref[i, :] = b_ref[...].astype(bpan_ref.dtype)[0]
-        acc_ref[...] += jax.lax.dot_general(
-            dy_ref[...].astype(acc_ref.dtype),
-            bpan_ref[...].astype(acc_ref.dtype),
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=acc_ref.dtype)       # (br, g)
-    else:
-        for i, b_ref in enumerate(b_refs):
-            bpan_ref[:, i, :] = b_ref[...][:, 0, :].astype(bpan_ref.dtype)
-        # (bz, br, bn) x (bz, g, bn) contracted over (batch, bn) -> (br, g):
-        # the batch axis joins the N-reduction, realising the batch sum.
-        acc_ref[...] += jax.lax.dot_general(
-            dy_ref[...].astype(acc_ref.dtype),
-            bpan_ref[...].astype(acc_ref.dtype),
-            (((0, 2), (0, 2)), ((), ())),
-            preferred_element_type=acc_ref.dtype)       # (br, g)
-
-    @pl.when(last)
-    def _flush():
-        o_ref[...] = acc_ref[...][None].astype(o_ref.dtype)
+    Args:
+      panel_rows: (P,) int32 — cotangent row per panel (``PanelCSR`` order).
+      lane_cols:  (P·G,) int32 — gather rows of ``b``, lane ``i`` of panel
+                  ``p`` at ``p·G + i`` (``PanelCSR.lane_cols``).
+      dy:         (M, N) output cotangent, or (batch, M, N) — batch summed
+                  (rows beyond the CSR region are simply never indexed).
+      b:          (K, N) or (batch, K, N) the forward dense operand.
+      g:          panel width G (static).
+      interpret:  run the Pallas interpreter (CPU validation) or compile
+                  for the TPU; every caller states which.
+      panels_per_call: panels per ``pallas_call`` (default: the SMEM
+                  bound of ``panel_common.panels_per_call``).
+    Returns:
+      (P, G) gradients in the accumulation dtype; padding lanes undefined —
+      gather real slots with ``PanelCSR.gather_values``.
+    """
+    return _sdd_panels(panel_rows, lane_cols, dy, b, g=g, br=None, bn=bn,
+                       interpret=interpret, pipeline_depth=pipeline_depth,
+                       per_call=panels_per_call)
 
 
-def _piped_bcsr_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
-    """Depth-2 SDD pipeline over the column-block reduction axis (BCSR
-    part): step ``jj`` assembles B's column block ``min(jj, nb-1)`` into
-    ping-pong slot ``jj % 2`` while the MXU contracts the cotangent's
-    column block ``max(jj - 1, 0)`` against slot ``(jj+1) % 2``."""
-    _, _, dy_ref, *rest = refs
-    b_refs, (o_ref, bpan_ref, acc_ref) = rest[:g], rest[g:]
-    jaxis = 1 if bz is None else 2
-    jj = pl.program_id(jaxis)
-    first, last = _reduction_edges(bz, depth)
-
-    def _assemble(slot):
-        for i, b_ref in enumerate(b_refs):
-            if bz is None:
-                bpan_ref[slot, i, :] = b_ref[...].astype(bpan_ref.dtype)[0]
-            else:
-                bpan_ref[slot, :, i, :] = \
-                    b_ref[...][:, 0, :].astype(bpan_ref.dtype)
-
-    for s in (0, 1):
-        @pl.when(parity(jj) == s)
-        def _(s=s):
-            _assemble(s)
-
-    @pl.when(jj >= depth - 1)
-    def _compute():
-        @pl.when(first)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        def _contract(slot):
-            if bz is None:
-                acc_ref[...] += jax.lax.dot_general(
-                    dy_ref[...].astype(acc_ref.dtype),
-                    bpan_ref[slot].astype(acc_ref.dtype),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=acc_ref.dtype)       # (br, g)
-            else:
-                acc_ref[...] += jax.lax.dot_general(
-                    dy_ref[...].astype(acc_ref.dtype),
-                    bpan_ref[slot].astype(acc_ref.dtype),
-                    (((0, 2), (0, 2)), ((), ())),
-                    preferred_element_type=acc_ref.dtype)       # (br, g)
-
-        for s in (0, 1):
-            @pl.when(parity(jj + 1) == s)
-            def _(s=s):
-                _contract(s)
-
-        @pl.when(last)
-        def _flush():
-            o_ref[...] = acc_ref[...][None].astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("br", "bn", "interpret",
-                                             "pipeline_depth"))
-def bcsr_sdd_panels_pallas(panel_rows: jax.Array, panel_cols: jax.Array,
-                           dy_pad: jax.Array, b: jax.Array, *, br: int,
-                           bn: int | None = None,
-                           interpret: bool = True,
-                           pipeline_depth: int = 1) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("g", "br", "bn", "interpret",
+                                             "pipeline_depth",
+                                             "panels_per_call"))
+def bcsr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
+                           dy_pad: jax.Array, b: jax.Array, *, g: int,
+                           br: int, interpret: bool, bn: int | None = None,
+                           pipeline_depth: int = 1,
+                           panels_per_call: int | None = None) -> jax.Array:
     """Per-tile-element gradients for the BCSR part, in panel layout.
 
     Args:
       panel_rows: (P,) int32 — block-row per panel (``PanelBCSR`` order).
-      panel_cols: (P, G) int32 — gather rows of ``b`` per lane.
+      lane_cols:  (P·G,) int32 — gather rows of ``b``, lane ``i`` of panel
+                  ``p`` at ``p·G + i`` (``PanelBCSR.lane_cols``).
       dy_pad:     (nblocks * Br, N) or (batch, nblocks * Br, N) — the BCSR
                   region of the cotangent, zero-padded to full blocks
                   (trimmed rows ⇒ zero grad); batch summed.
       b:          (K, N) or (batch, K, N) the forward dense operand.
+      g:          panel width G (static).
+      interpret:  run the Pallas interpreter (CPU validation) or compile
+                  for the TPU; every caller states which.
+      panels_per_call: panels per ``pallas_call`` (default: the SMEM
+                  bound of ``panel_common.panels_per_call``).
     Returns:
       (P, Br, G) gradients in the accumulation dtype; padding lanes
       undefined — gather real slots with ``PanelBCSR.gather_values``.
     """
-    if dy_pad.ndim != b.ndim or b.ndim not in (2, 3):
-        raise ValueError(f"dy_pad/b must both be rank 2 or 3; got "
-                         f"{dy_pad.ndim} / {b.ndim}")
-    depth = check_pipeline_depth(pipeline_depth)
-    npanels, g = panel_cols.shape
-    n = b.shape[-1]
-    bn = bn or default_bn(n)
-    if n % bn:
-        raise ValueError(f"N={n} not divisible by bn={bn}")
-    acc_dtype = acc_dtype_for(b.dtype)
-    batch = b.shape[0] if b.ndim == 3 else None
-    nb = n // bn
-    lj, cj = _sdd_col_maps(depth, nb)
-    if batch is None:
-        bz = None
-        grid = (npanels, nb + depth - 1)
-        in_specs = [
-            pl.BlockSpec((br, bn),
-                         lambda p, j, rows, cols: (rows[p], cj(j))),
-            *[pl.BlockSpec((1, bn),
-                           lambda p, j, rows, cols, i=i: (cols[p, i], lj(j)))
-              for i in range(g)],
-        ]
-        out_specs = pl.BlockSpec((1, br, g),
-                                 lambda p, j, rows, cols: (p, 0, 0))
-        bpan_shape = (g, bn) if depth == 1 else (depth, g, bn)
-        scratch = [pltpu.VMEM(bpan_shape, b.dtype),     # B panel (packed)
-                   pltpu.VMEM((br, g), acc_dtype)]      # accumulator
-    else:
-        bz = batch_block(batch)
-        grid = (npanels, batch // bz, nb + depth - 1)
-        in_specs = [
-            pl.BlockSpec((bz, br, bn),
-                         lambda p, z, j, rows, cols: (z, rows[p], cj(j))),
-            *[pl.BlockSpec((bz, 1, bn),
-                           lambda p, z, j, rows, cols, i=i:
-                           (z, cols[p, i], lj(j)))
-              for i in range(g)],
-        ]
-        out_specs = pl.BlockSpec((1, br, g),
-                                 lambda p, z, j, rows, cols: (p, 0, 0))
-        bpan_shape = (bz, g, bn) if depth == 1 else (depth, bz, g, bn)
-        scratch = [pltpu.VMEM(bpan_shape, b.dtype),     # B panels (packed)
-                   pltpu.VMEM((br, g), acc_dtype)]
-    if depth > 1:
-        kernel = functools.partial(_piped_bcsr_sdd_kernel, g, bz, depth)
-    else:
-        kernel = functools.partial(_bcsr_sdd_kernel, g, bz)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # panel_rows, panel_cols
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((npanels, br, g), acc_dtype),
-        interpret=interpret,
-    )(panel_rows, panel_cols, dy_pad, *([b] * g))
+    return _sdd_panels(panel_rows, lane_cols, dy_pad, b, g=g, br=br, bn=bn,
+                       interpret=interpret, pipeline_depth=pipeline_depth,
+                       per_call=panels_per_call)
 
 
 register_kernel("csr", "sdd", "panels", csr_sdd_panels_pallas)

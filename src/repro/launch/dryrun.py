@@ -1,11 +1,9 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST stay first: jax locks the device count at first
-initialisation, and the production meshes need 512 placeholder host devices
-(single-pod cells use the first 256).
+Run as a program, this module forces 512 placeholder host devices before
+anything imports jax (jax locks the device count at first initialisation;
+single-pod cells use the first 256).  Imported, it leaves ``XLA_FLAGS``
+alone.
 
 The step functions come from ``repro.dist.step`` (built against abstract
 avals — nothing is allocated) with in/out shardings baked from
@@ -25,6 +23,12 @@ Usage:
   python -m repro.launch.dryrun --arch llama3.2-1b --shape train_4k --mesh single
   python -m repro.launch.dryrun --all --mesh both
 """
+import os
+
+if __name__ == "__main__":
+    # Must run before the jax import below.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+
 import argparse
 import json
 import math
@@ -106,8 +110,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
             rec["memory_analysis"] = {"error": str(e)}
         try:
             ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: per-device list
-                ca = ca[0]
             rec["cost_analysis"] = {k: float(v) for k, v in ca.items()
                                     if isinstance(v, (int, float))
                                     and ("flops" in k or "bytes" in k

@@ -48,6 +48,12 @@ from ..serve.scheduler import POLICIES, SchedulerConfig
 from .mesh import make_test_mesh
 
 
+# Largest error the warm-up SpMM may show against the segment-sum reference,
+# relative to |W|·|x| per output entry: fp32 differs only in summation
+# order (~1e-7), while a single bf16 MXU pass errs ~4e-3.
+WARM_SPMM_TOL = 1e-5
+
+
 def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
                          n_cols: int = 8, on_miss: str = "search",
                          pool=None):
@@ -56,13 +62,16 @@ def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
     The "warm plan-cache pool" prerequisite of continuous batching
     (ROADMAP item 1): magnitude-prune each layer's FFN weight, tune-or-
     fetch its execution plan through the persistent cache, and run one
-    engine SpMM per layer to validate the plan.  Same-shaped layers
-    fingerprint alike, so layer 0 pays the (budgeted) search and every
-    later layer is a cache hit — the hit rate lands in the obs capture's
-    ``tune.cache.*`` gauges, and each validation SpMM lands in the
-    ``engine.dispatch`` counters.  Families without a stacked dense FFN
-    (MoE/SSM variants) warm a synthetic ``(4*d_model, d_model)`` matrix of
-    the same sparsity instead.
+    engine SpMM per layer to validate the plan: it must match the
+    segment-sum reference within :data:`WARM_SPMM_TOL` (the largest error
+    lands in the ``serve.warm_spmm_max_err`` gauge; a breach raises).
+    Same-shaped layers fingerprint alike, so layer 0 pays the (budgeted)
+    search and every later layer is a cache hit — the hit rate lands in
+    the obs capture's ``tune.cache.*`` gauges, and each validation SpMM
+    lands in the ``engine.dispatch`` counters.  Plans are tuned on the
+    engine's platform default backend — the one that serves.  Families
+    without a stacked dense FFN (MoE/SSM variants) warm a synthetic
+    ``(4*d_model, d_model)`` matrix of the same sparsity instead.
 
     The tuned records are then bulk-installed into the serving ``pool``
     (default: a ``serve-pool`` cache beside the tuning store) in ONE atomic
@@ -80,10 +89,13 @@ def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
 
     Returns the warmed pool cache.
     """
+    import dataclasses
+
     import jax.numpy as jnp
 
     from ..core.formats import csr_from_dense
-    from ..core.spmm import loops_spmm
+    from ..core.spmm import loops_spmm, spmm_csr_baseline
+    from ..kernels.engine import default_backend
     from ..models.sparse_ffn import magnitude_prune
     from ..resilience.validate import validate_csr
     from ..tune import PlanCache, SearchBudget, autotune
@@ -104,7 +116,11 @@ def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
         d = cfg.d_model
         weights = [rng.standard_normal((4 * d, d)).astype(np.float32)]
 
+    # Tune on the backend that serves: the engine's platform default.
+    backend = default_backend()
     keys = []
+    max_err = 0.0
+    xrng = np.random.default_rng(0)
     for i, w in enumerate(weights):
         with obs.span("serve.warm_plan", cat="warm", layer=i):
             w = np.asarray(fault_point("ingest.serve.weights", w))
@@ -112,14 +128,25 @@ def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
             csr, _ = validate_csr(csr, repair="drop")
             misses0 = cache.stats.misses
             fmt, _plan = autotune(csr, n_cols=n_cols, cache=cache,
-                                  budget=budget, backend="jnp",
+                                  budget=budget, backend=backend,
                                   on_miss=on_miss)
             if on_miss == "model" and cache.stats.misses > misses0:
                 note_degraded("serve.degraded", reason="plan-cache-miss")
             keys.append(cache_key(fingerprint(csr), n_cols=n_cols,
-                                  dtype=csr.vals.dtype, backend="jnp"))
-            x = jnp.ones((csr.ncols, n_cols), jnp.float32)
-            jax.block_until_ready(loops_spmm(fmt, x))
+                                  dtype=csr.vals.dtype, backend=backend))
+            x = xrng.standard_normal((csr.ncols, n_cols)).astype(np.float32)
+            out = loops_spmm(fmt, jnp.asarray(x))
+            ref = spmm_csr_baseline(csr, jnp.asarray(x))
+            scale = spmm_csr_baseline(
+                dataclasses.replace(csr, vals=np.abs(csr.vals)),
+                jnp.asarray(np.abs(x)))
+            err = float(jnp.max(jnp.abs(out - ref)
+                                / jnp.maximum(scale, 1e-30)))
+            max_err = max(max_err, err)
+            if not err <= WARM_SPMM_TOL:
+                raise RuntimeError(
+                    f"warm-up SpMM of layer {i} is off the reference by "
+                    f"{err:.3g} > {WARM_SPMM_TOL:g}")
     # Hand the tuned plans to the serving pool in one bulk write.
     if pool is None:
         pool = PlanCache(os.path.join(cache.dir, "serve-pool"))
@@ -127,6 +154,7 @@ def warm_spmm_plan_cache(cfg, params, obs, *, sparsity: float = 0.9,
     records = [cache.peek(k) for k in dict.fromkeys(keys)]
     installed = pool.prewarm([r for r in records if r is not None])
     obs.gauge("serve.warm_layers").set(len(weights))
+    obs.gauge("serve.warm_spmm_max_err").set(max_err)
     obs.gauge("serve.prewarmed_plans").set(installed)
     return pool
 
@@ -184,6 +212,8 @@ def main():
                     help="per-request deadline across retries; exceeding it "
                          "raises DeadlineExceeded instead of sleeping past")
     args = ap.parse_args()
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
 
     # Chaos harness: honour REPRO_FAULT_PLAN so CI can inject failures into
     # a stock serving run (docs/robustness.md).

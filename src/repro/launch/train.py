@@ -80,6 +80,8 @@ def build_args():
 
 def main():
     args = build_args()
+    from .compile_cache import enable as enable_compile_cache
+    enable_compile_cache()
     # Chaos harness: honour REPRO_FAULT_PLAN (docs/robustness.md).
     from ..resilience.inject import install_from_env
     install_from_env()

@@ -46,12 +46,9 @@ def current_span() -> Optional["Span"]:
 
 
 def _tracing() -> bool:
-    """True while jax is abstractly tracing on this thread."""
-    try:
-        import jax.core
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    """True while jax is tracing on this thread (jit, grad, vmap, ...)."""
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class SpanSink:

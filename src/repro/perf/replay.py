@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..kernels.panel_common import default_bn
+from ..kernels.panel_common import CSR_WORDS, default_bn, panel_calls
 from .trace import fit_cost_model, load_traces
 
 __all__ = ["predict_grid_steps", "predict_part_steps", "TraceDB", "replay"]
@@ -55,8 +55,9 @@ def predict_part_steps(csr, plan, n_cols: int,
       * a part the executor skips entirely (``r_b == 0`` / ``r_b == nrows``)
         contributes zero;
       * ``macro_m > 1`` panelizes at the effective width ``panel_g·macro_m``
-        and ``pipeline_depth = d`` adds ``d - 1`` ramp steps per non-empty
-        part, exactly like the conversion;
+        and ``pipeline_depth = d`` adds ``d - 1`` ramp steps per
+        ``pallas_call`` (SMEM-sized panel chunk) of each non-empty part,
+        exactly like the conversion;
       * both counts scale by ``ceil(n_cols / bn)`` column blocks
         (``bn`` defaults to ``panel_common.default_bn(n_cols)`` like the
         executor).
@@ -94,9 +95,12 @@ def predict_part_steps(csr, plan, n_cols: int,
                                       minlength=nblocks)
         p_bcsr = int(np.maximum(-(-tiles_per_block // g), 1).sum())
 
-    ramp = depth - 1
-    s_csr = (p_csr + ramp) * col_blocks if p_csr > 0 else 0
-    s_bcsr = (p_bcsr + ramp) * col_blocks if p_bcsr > 0 else 0
+    def _steps(p, words):   # one ramp per SMEM-sized chunk launch
+        if not p:
+            return 0
+        return (p + panel_calls(p, g, words) * (depth - 1)) * col_blocks
+
+    s_csr, s_bcsr = _steps(p_csr, CSR_WORDS), _steps(p_bcsr, 1)
     return s_csr, s_bcsr
 
 

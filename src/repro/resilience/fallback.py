@@ -23,6 +23,11 @@ Kill switch: ``REPRO_NO_FALLBACK=1`` (or the :func:`disabled` context
 manager) makes every chain single-link so failures propagate — tests that
 assert error behaviour, and operators who prefer crash-fast, use this.
 
+On a TPU the chain never leaves ``pallas``: a refused or failing kernel
+raises (:func:`degrades`).  Degrading there would quietly swap the chip's
+kernels for the interpreter or the jnp oracle; the chains exist for the CPU
+and for the chaos tests.
+
 :func:`retry_with_backoff` is the host-side half: transient *step* failures
 (serving/training) retry with exponential backoff under an optional
 deadline.
@@ -39,8 +44,9 @@ from .inject import (InjectedFault, InjectedTimeout, fault_point,
                      note_degraded)
 
 __all__ = ["DEFAULT_CHAIN", "FallbackPolicy", "get_policy", "set_policy",
-           "disabled", "run_chain", "classify", "retry_with_backoff",
-           "DeadlineExceeded"]
+           "disabled", "degrades", "on_tpu", "pinned", "run_chain",
+           "classify",
+           "retry_with_backoff", "DeadlineExceeded"]
 
 # The canonical degradation order: fastest first, oracle last.
 DEFAULT_CHAIN: Tuple[str, ...] = ("pallas", "interpret", "jnp")
@@ -64,12 +70,30 @@ class FallbackPolicy:
     def chain_for(self, part: str, op: str, backend: str) -> Tuple[str, ...]:
         """The chain starting at the caller's resolved ``backend`` — a
         caller already on a degraded link never climbs back up."""
-        if not self.enabled:
+        if not self.enabled or pinned(backend):
             return (backend,)
         chain = self.chains.get((part, op), DEFAULT_CHAIN)
         if backend in chain:
             return chain[chain.index(backend):]
         return (backend,)
+
+
+def on_tpu() -> bool:
+    """The platform probe: is JAX's default backend a TPU?"""
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def pinned(backend: str) -> bool:
+    """Is ``backend`` the chip's own kernels — ``pallas`` on a TPU — whose
+    failures must raise rather than be skipped or degraded?"""
+    return backend == "pallas" and on_tpu()
+
+
+def degrades(backend: str) -> bool:
+    """May a failure on ``backend`` degrade to a slower path?  Not with
+    the kill switch on, and never when the backend is :func:`pinned`."""
+    return get_policy().enabled and not pinned(backend)
 
 
 _POLICY = FallbackPolicy(

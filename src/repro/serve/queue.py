@@ -288,7 +288,10 @@ class ServeQueue:
     def _sample_rows(self, logits: np.ndarray, group: Group,
                      index_of: Callable[[Request], int]) -> np.ndarray:
         """Next-token column for every slot; live rows sample per-request,
-        pad rows (whose outputs are discarded) take the argmax."""
+        pad rows (whose outputs are discarded) take the argmax.  Only the
+        first ``vocab_size`` logits are tokens: the unembedding is padded
+        to ``cfg.vocab_padded()`` and its padding rows are never sampled."""
+        logits = logits[:, :self.cfg.vocab_size]
         toks = np.zeros((logits.shape[0], 1), np.int32)
         for i in range(logits.shape[0]):
             if i < group.size:

@@ -32,7 +32,8 @@ import numpy as np
 from ..core.formats import CSR, LoopsFormat, loops_from_csr
 from ..core.partition import choose_r_boundary, regularity_boundary
 from ..core.perf_model import QuadraticPerfModel, fit_perf_model
-from ..core.spmm import SpmmPlan, loops_spmm
+from ..core.spmm import SpmmPlan, default_br, loops_spmm
+from ..resilience import fallback
 from ..resilience.fallback import classify
 from ..resilience.inject import fault_point, note_degraded
 
@@ -225,6 +226,13 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
             else (csr.ncols, n_cols)
         b = jnp.asarray(rng.standard_normal(shape).astype(dt))
     model = model or prior_model(total_workers)
+    if backend == "pallas":
+        # The chip's BCSR output block is (Br, bn): Br must fill whole
+        # sublane tiles (8 rows, 16 in half precision), which a shorter
+        # tile height cannot.
+        unit = default_br(csr.vals.dtype)
+        br_choices = tuple(br for br in br_choices if br % unit == 0) \
+            or (unit,)
     plans = enumerate_plans(csr, total_workers=total_workers,
                             br_choices=br_choices, g_choices=g_choices,
                             depth_choices=depth_choices,
@@ -338,12 +346,15 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
         # or, under ``trial_timeout_s``, grossly overrunning — must not
         # abort the whole search.  The failed trial is counted and skipped;
         # the surviving measurements still rank.  ``tune.trial`` is the
-        # chaos injection site.
+        # chaos injection site.  On a TPU a failed pallas trial raises: it
+        # is a kernel the chip refused, which skipping would hide.
         t0 = time.perf_counter()
         try:
             fault_point("tune.trial")
             fmt, g = meas(csr, p, b)
         except Exception as e:   # noqa: BLE001 - skipping IS the handler
+            if fallback.pinned(backend):
+                raise
             note_degraded("tune.search.trial_failed", reason=classify(e))
             continue
         if budget.trial_timeout_s is not None \

@@ -44,7 +44,7 @@ def _boundaries(m, br):
 def _count_pallas_calls(jaxpr) -> int:
     """Recursively count pallas_call equations, re-visiting shared
     sub-jaxprs per call site (= number of kernel dispatches)."""
-    import jax.core as core
+    import jax.extend.core as core
 
     def subjaxprs(v):
         if isinstance(v, core.ClosedJaxpr):

@@ -92,3 +92,70 @@ def test_coo_duplicate_accumulation():
     # tests/test_tune.py, which also runs in hypothesis-free environments).
     coords = list(zip(csr.row_ids.tolist(), csr.col_idx.tolist()))
     assert len(coords) == len(set(coords))
+
+
+def _bcsr_loop_reference(csr, start, stop, br, keep_zeros):
+    """The per-nonzero dict construction ``bcsr_from_csr_rows`` replaced:
+    the reference its vectorised form must reproduce exactly."""
+    nrows = stop - start
+    nblocks = max((nrows + br - 1) // br, 1)
+    tile_map, dest = {}, []
+    for i in range(start, stop):
+        tr, off = (i - start) // br, (i - start) % br
+        for k in range(int(csr.row_ptr[i]), int(csr.row_ptr[i + 1])):
+            j, v = int(csr.col_idx[k]), csr.vals[k]
+            if v == 0 and not keep_zeros:
+                dest.append(None)
+                continue
+            tile_map.setdefault((tr, j), np.zeros(br, csr.vals.dtype))
+            tile_map[(tr, j)][off] += v
+            dest.append((tr, j, off))
+    present = {tr for tr, _ in tile_map}
+    for tr in range(nblocks):
+        if tr not in present:
+            tile_map[(tr, 0)] = np.zeros(br, csr.vals.dtype)
+    keys = sorted(tile_map)
+    tile_of = {k: t for t, k in enumerate(keys)}
+    slot = np.array([-1 if d is None else tile_of[d[:2]] * br + d[2]
+                     for d in dest], np.int64)
+    return (np.array([k[0] for k in keys], np.int32),
+            np.array([k[1] for k in keys], np.int32),
+            np.stack([tile_map[k] for k in keys]), slot)
+
+
+@pytest.mark.parametrize("keep_zeros", [False, True])
+@pytest.mark.parametrize("kind", ["banded", "powerlaw", "block", "uniform"])
+def test_bcsr_vectorised_matches_loop_and_dense(kind, keep_zeros):
+    from repro.core import suite
+    gen = {"banded": lambda: suite.banded(200, 200, 5, fill=0.7, seed=1),
+           "powerlaw": lambda: suite.powerlaw(200, 200, 6.0, seed=2),
+           "block": lambda: suite.block_dense(192, 192, 16, 0.2, seed=3),
+           "uniform": lambda: suite.uniform(200, 160, 0.04, seed=4)}[kind]
+    csr = gen()
+    csr = csr.astype(np.float32)
+    vals = csr.vals.copy()
+    vals[::7] = 0.0              # zero-valued stored entries: dropped or kept
+    csr = type(csr)(row_ptr=csr.row_ptr, col_idx=csr.col_idx, vals=vals,
+                    row_ids=csr.row_ids, shape=csr.shape)
+    start, stop, br = 37, csr.nrows, 8
+    bc, slot = bcsr_from_csr_rows(csr, start, stop, br,
+                                  keep_zeros=keep_zeros, return_map=True)
+    rows, cols, tvals, slot_ref = _bcsr_loop_reference(csr, start, stop, br,
+                                                       keep_zeros)
+    np.testing.assert_array_equal(bc.tile_rows, rows)
+    np.testing.assert_array_equal(bc.tile_cols, cols)
+    np.testing.assert_array_equal(bc.tile_vals, tvals)
+    np.testing.assert_array_equal(slot, slot_ref)
+    # dense reconstruction of the tiles equals the sliced rows
+    dense = np.zeros((bc.nblocks * br, csr.ncols), np.float32)
+    for t in range(bc.ntiles):
+        r0 = int(bc.tile_rows[t]) * br
+        dense[r0:r0 + br, int(bc.tile_cols[t])] += bc.tile_vals[t]
+    np.testing.assert_array_equal(dense[:stop - start],
+                                  csr_to_dense(csr)[start:stop])
+    # the slot_map scatter carries the entries' values into the tiles
+    s, e = int(csr.row_ptr[start]), int(csr.row_ptr[stop])
+    flat = np.zeros(bc.ntiles * br, np.float32)
+    kept = slot >= 0
+    np.add.at(flat, slot[kept], csr.vals[s:e][kept])
+    np.testing.assert_array_equal(flat.reshape(bc.ntiles, br), bc.tile_vals)

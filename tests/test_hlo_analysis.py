@@ -29,8 +29,6 @@ def test_scan_flops_multiplied_by_trip_count():
     # cost_analysis undercounts (one body visit) — the reason this module
     # exists; guard the assumption so a jax upgrade that fixes it is noticed
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):  # jax <= 0.4.x: one dict per device
-        ca = ca[0]
     assert ca["flops"] < st.flops / 2
 
 

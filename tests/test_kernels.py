@@ -112,6 +112,7 @@ def test_out_dtype_override(rng):
 # ---------------------------------------------------------------------------
 
 import contextlib
+import functools
 
 from repro.core import loops_grid_steps, loops_spmm
 from repro.core.formats import panelize_bcsr, panelize_csr
@@ -173,9 +174,8 @@ def test_csr_panel_kernel_adversarial(rng, dtype, tol, g):
             assert set(p.panel_rows.tolist()) == set(range(m))
             assert int(p.panel_mask.sum()) == csr.nnz
             got = csr_panels_spmm_pallas(
-                jnp.asarray(p.panel_rows), jnp.asarray(p.panel_cols),
-                jnp.asarray(p.panel_vals), jnp.asarray(p.panel_mask), b,
-                nrows=m, interpret=True)
+                jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
+                jnp.asarray(p.lane_vals), b, g=g, nrows=m, interpret=True)
             want = ref.csr_spmm_ref(jnp.asarray(csr.row_ids),
                                     jnp.asarray(csr.col_idx),
                                     jnp.asarray(csr.vals), b, m)
@@ -196,9 +196,9 @@ def test_bcsr_panel_kernel_adversarial(rng, dtype, tol, g):
             assert (np.diff(p.panel_rows) >= 0).all()
             assert set(p.panel_rows.tolist()) == set(range(p.nblocks))
             got = bcsr_panels_spmm_pallas(
-                jnp.asarray(p.panel_rows), jnp.asarray(p.panel_cols),
-                jnp.asarray(p.panel_vals), jnp.asarray(p.panel_mask), b,
-                nblocks=p.nblocks, interpret=True)
+                jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
+                jnp.asarray(p.vals_window), b, g=g, nblocks=p.nblocks,
+                interpret=True)
             bc = fmt.bcsr_part
             want = ref.bcsr_spmm_ref(jnp.asarray(bc.tile_rows),
                                      jnp.asarray(bc.tile_cols),
@@ -263,3 +263,101 @@ def test_default_br_named_constants():
     assert default_br(jnp.float64) == SUBLANE_ROWS
     assert default_br(jnp.bfloat16) == HALF_PACKED_ROWS == 16
     assert default_br(jnp.float16) == HALF_PACKED_ROWS
+
+
+# ---------------------------------------------------------------------------
+# SMEM-sized chunks: rows spanning a chunk seam resume from the carry
+# ---------------------------------------------------------------------------
+
+def _hub_matrix(rng):
+    """Rows 0-2 short, row 3 a hub of 40 nonzeros (10 panels at G=4),
+    rows 4-7 short, an empty row 8: with 3 panels per call the hub spans
+    four chunks and every seam lands inside or beside it."""
+    a = np.zeros((9, 48), np.float32)
+    for r in (0, 1, 2, 4, 5, 6, 7):
+        a[r, rng.choice(48, 3, replace=False)] = rng.standard_normal(3)
+    a[3, rng.choice(48, 40, replace=False)] = rng.standard_normal(40)
+    return a
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("batched", [False, True])
+def test_csr_chunks_resume_rows_across_seams(rng, depth, batched):
+    a = _hub_matrix(rng)
+    csr = csr_from_dense(a)
+    p = panelize_csr(csr, 4)
+    shape = ((3, 48, 8) if batched else (48, 8))
+    b = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+    args = (jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
+            jnp.asarray(p.lane_vals), b)
+    whole = csr_panels_spmm_pallas(*args, g=4, nrows=9, interpret=True,
+                                   pipeline_depth=depth)
+    chunked = csr_panels_spmm_pallas(*args, g=4, nrows=9, interpret=True,
+                                     pipeline_depth=depth, panels_per_call=3)
+    want = a @ np.asarray(b)
+    np.testing.assert_allclose(np.asarray(chunked), want, rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(chunked), np.asarray(whole),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_bcsr_chunks_with_carry_keep_foreign_rows(rng, depth):
+    """The fused path's carry across chunk launches: rows the kernel never
+    visits keep the carry's values, visited block-rows spanning a seam sum
+    every panel."""
+    a = np.zeros((16, 40), np.float32)
+    a[4:8] = rng.standard_normal((4, 40))          # block-row 1: 40 tiles
+    a[0, 3], a[13, 7] = 1.0, -2.0
+    fmt = loops_from_csr(csr_from_dense(a), 0, 4, panel_g=4)
+    p = fmt.bcsr_panels
+    b = jnp.asarray(rng.standard_normal((40, 8)).astype(np.float32))
+    sentinel = jnp.full((24, 8), 7.0, jnp.float32)  # rows 16..23: foreign
+    got = bcsr_panels_spmm_pallas(
+        jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
+        jnp.asarray(p.vals_window), b, g=4, nblocks=p.nblocks, out_rows=24,
+        carry=sentinel, interpret=True, pipeline_depth=depth,
+        panels_per_call=5)
+    np.testing.assert_allclose(np.asarray(got)[:16], a @ np.asarray(b),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(got)[16:], 7.0)
+
+
+@pytest.mark.parametrize("part", ["csr", "bcsr"])
+def test_sdd_chunks_match_single_call(rng, part):
+    from repro.kernels.spmm_sdd import (bcsr_sdd_panels_pallas,
+                                        csr_sdd_panels_pallas)
+    a = _hub_matrix(rng)
+    dy = jnp.asarray(rng.standard_normal((12, 8)).astype(np.float32))
+    b = jnp.asarray(rng.standard_normal((48, 8)).astype(np.float32))
+    if part == "csr":
+        p = panelize_csr(csr_from_dense(a), 4)
+        run = functools.partial(csr_sdd_panels_pallas, jnp.asarray(
+            p.panel_rows), jnp.asarray(p.lane_cols), dy[:9], b, g=4,
+            interpret=True)
+    else:
+        p = loops_from_csr(csr_from_dense(a), 0, 4, panel_g=4).bcsr_panels
+        run = functools.partial(bcsr_sdd_panels_pallas, jnp.asarray(
+            p.panel_rows), jnp.asarray(p.lane_cols), dy, b, g=4, br=4,
+            interpret=True)
+    np.testing.assert_allclose(np.asarray(run(panels_per_call=3)),
+                               np.asarray(run()), rtol=1e-6, atol=1e-6)
+
+
+def test_grid_steps_count_one_ramp_per_chunk():
+    """Depth-2 grids pay their ramp once per launch: a part with more
+    panels than one SMEM chunk holds counts every launch's ramp, and the
+    replay predictor agrees exactly."""
+    from repro.core.spmm import SpmmPlan
+    from repro.kernels.panel_common import CSR_WORDS, panel_calls
+    from repro.perf.replay import predict_part_steps
+    a = np.zeros((30_000, 64), np.float32)
+    a[np.arange(30_000), np.arange(30_000) % 64] = 1.0
+    csr = csr_from_dense(a)
+    plan = SpmmPlan(r_boundary=30_000, t_vpu=1, t_mxu=0, br=8, panel_g=1,
+                    pipeline_depth=2)
+    fmt = loops_from_csr(csr, 30_000, 8, panel_g=1, pipeline_depth=2)
+    calls = panel_calls(fmt.csr_panels.npanels, 1, CSR_WORDS)
+    assert calls > 1
+    assert loops_grid_steps(fmt, 8) == 30_000 + calls
+    assert predict_part_steps(csr, plan, 8) == (30_000 + calls, 0)

@@ -144,6 +144,21 @@ def test_span_inside_jit_records_nothing_and_counts_drop():
     assert drops is not None and drops.value == 2   # once per compilation
 
 
+def test_trace_state_probe_flags_jit_tracing():
+    from repro.obs.spans import _tracing
+    seen = []
+
+    @jax.jit
+    def f(x):
+        seen.append(_tracing())
+        return x + 1.0
+
+    assert not _tracing()
+    f(jnp.ones(2)).block_until_ready()
+    assert seen == [True]
+    assert not _tracing()
+
+
 def test_span_records_on_host():
     obs = Obs(source="t")
     with obs.span("host.region", cat="test", k=1) as sp:

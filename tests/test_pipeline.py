@@ -175,19 +175,22 @@ else:
 # -- default_bn: the N=600 regression --------------------------------------
 
 def test_default_bn_units():
-    assert default_bn(600) == 200       # largest lane-aligned divisor <= 512
+    assert default_bn(600) == 600       # no 128-lane divisor: whole row
     assert default_bn(1024) == 512
+    assert default_bn(1536) == 512
+    assert default_bn(640) == 128
     assert default_bn(512) == 512
     assert default_bn(32) == 32         # n <= 512: whole operand, one block
     assert default_bn(1) == 1
-    for n in (600, 1000, 1536, 700):
+    for n in (600, 1000, 1536, 700, 640):
         bn = default_bn(n)
-        assert n % bn == 0 and bn <= 512
+        # TPU tiling: a block's last dim is a 128-lane multiple or the row
+        assert n % bn == 0 and (bn == n or bn % 128 == 0)
 
 
 def test_wide_operand_n600_regression(rng):
     """N=600 used to raise (600 % min(600, 512) != 0); default_bn now picks
-    a clean divisor and the kernels execute end to end."""
+    a legal block (the whole row) and the kernels execute end to end."""
     a = _sparse(rng, 16, 12, 0.3, jnp.float32)
     csr = csr_from_dense(a)
     b = jnp.asarray(rng.standard_normal((12, 600)).astype(np.float32))

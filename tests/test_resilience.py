@@ -225,6 +225,35 @@ def test_no_fallback_env_kill_switch():
         == ("pallas", "interpret")
 
 
+@pytest.mark.parametrize("fmt_kind", ["csr", "fused"])
+def test_tpu_pallas_failure_raises_and_never_degrades(rng, monkeypatch,
+                                                     fmt_kind):
+    """On a TPU a failing pallas kernel raises: neither the per-part chain
+    nor the fused catch in ``core.spmm`` may swap in the interpreter or the
+    oracle.  The platform probe is steered to ``tpu`` inside the test; the
+    fault fires at the pallas link's own fault point, before any lowering."""
+    monkeypatch.setattr(fallback, "on_tpu", lambda: True)
+    assert fallback.FallbackPolicy().chain_for("csr", "spmm", "pallas") == \
+        ("pallas",)
+    assert not fallback.degrades("pallas")
+    csr = csr_from_dense(random_sparse(rng, 32, 24))
+    r_b = csr.nrows if fmt_kind == "csr" else 16       # pure CSR / hybrid
+    fmt = loops_from_csr(csr, r_b, 4)
+    b = jnp.asarray(rng.standard_normal((24, 8)).astype(np.float32))
+    obs = Obs(source="t")
+    set_active(obs)
+    inject.set_plan(FaultPlan.parse(
+        f"engine.{fmt_kind}.spmm.pallas:raise:0:0"))
+    with pytest.raises(InjectedFault):
+        loops_spmm(fmt, b, backend="pallas")
+    assert _counter_total(obs, "engine.fallback") == 0
+    # the CPU keeps its chains: the same fault degrades off the TPU
+    monkeypatch.setattr(fallback, "on_tpu", lambda: False)
+    assert fallback.degrades("pallas")
+    assert fallback.FallbackPolicy().chain_for("csr", "spmm", "pallas") == \
+        ("pallas", "interpret", "jnp")
+
+
 # ---------------------------------------------------------------------------
 # Plan-cache resilience: quarantine, read-retry, merge-on-save
 # ---------------------------------------------------------------------------
@@ -318,6 +347,21 @@ def test_search_skips_failed_trial_and_counts_it(rng):
     assert res.gflops > 0 and res.measured >= 1
     assert _counter_total(obs, "tune.search.trial_failed") == 1
     assert _counter_total(obs, "tune.search.degraded") == 0
+
+
+def test_search_trial_failure_raises_on_tpu_pallas(rng, monkeypatch):
+    """A pallas trial that fails on a TPU is a refused kernel: the search
+    raises instead of skipping it.  The platform probe is steered to
+    ``tpu`` inside the test."""
+    monkeypatch.setattr(fallback, "on_tpu", lambda: True)
+    csr = csr_from_dense(random_sparse(rng, 32, 16))
+    obs = Obs(source="t")
+    set_active(obs)
+    inject.set_plan(FaultPlan.parse("tune.trial:raise:0"))
+    with pytest.raises(InjectedFault):
+        search(csr, n_cols=8, budget=SearchBudget(top_k=3),
+               backend="pallas", measure=_cheap_measure)
+    assert _counter_total(obs, "tune.search.trial_failed") == 0
 
 
 def test_search_all_trials_failed_degrades_to_model_plan(rng):

@@ -150,6 +150,29 @@ def test_batched_engine_calls_never_exceed_sequential(serving):
     assert calls(batched) == 1 + 2           # one prefill + max(gen)-1 steps
 
 
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_padded_vocab_rows_are_never_sampled(temperature):
+    # A vocab that is not a multiple of 256 pads the unembedding; give the
+    # padding rows logits that dominate every real token (±1e4 along the
+    # first hidden axes) — the sampler must still only emit real tokens.
+    import dataclasses
+    cfg = dataclasses.replace(REDUCED[ARCH](), vocab_size=250)
+    assert cfg.vocab_padded() == 256 and cfg.tie_embeddings
+    params = api.init_params(cfg, jax.random.key(TEST_SEED))
+    pad = jnp.zeros((6, cfg.d_model), params["embed"].dtype)
+    for k in range(6):
+        pad = pad.at[k, k // 2].set(1e4 * (-1) ** k)
+    params["embed"] = params["embed"].at[cfg.vocab_size:].set(pad)
+    queue = ServeQueue(cfg, make_test_mesh(1, 1), params,
+                       temperature=temperature, seed=TEST_SEED)
+    rng = np.random.default_rng(TEST_SEED + 17)
+    prompts = [rng.integers(0, cfg.vocab_size, 8).tolist() for _ in range(2)]
+    reqs = _drive(queue, prompts, [3, 3], [1200, 1201])
+    for r in reqs:
+        assert r.tokens_generated == 3
+        assert all(0 <= t < cfg.vocab_size for t in r.tokens), r.tokens
+
+
 # ---------------------------------------------------------------------------
 # batch-axis padding never changes a live row's logits
 # ---------------------------------------------------------------------------

@@ -171,6 +171,25 @@ def test_search_matches_exhaustive_on_tiny_space():
     assert res.gflops == pytest.approx(max(g for _, g in res.trials))
 
 
+@pytest.mark.parametrize("dtype,unit", [(np.float32, 8),
+                                        (jnp.bfloat16, 16)])
+def test_search_on_pallas_keeps_tile_heights_the_chip_takes(dtype, unit):
+    """On the pallas backend every candidate's Br fills whole sublane
+    tiles: the chip refuses a (2, bn) or (4, bn) BCSR output block."""
+    csr = csr_from_dense(_dense(3, 40, 16, 0.15)).astype(dtype)
+    seen = []
+
+    def measure(c, plan, b):
+        from repro.core import loops_from_csr
+        seen.append(plan.br)
+        return loops_from_csr(c, plan.r_boundary, plan.br), 1.0
+
+    res = search(csr, n_cols=8, total_workers=4, backend="pallas",
+                 budget=SearchBudget(top_k=4, max_trials=4), measure=measure)
+    assert seen and all(br % unit == 0 for br in seen)
+    assert res.plan.br % unit == 0
+
+
 def test_search_prunes_to_budget():
     csr = csr_from_dense(_dense(3, 40, 16, 0.15))
     calls = []
