@@ -29,6 +29,8 @@ from typing import Tuple
 
 import numpy as np
 
+from ..obs.runtime import span
+
 __all__ = [
     "CSR",
     "VectorBCSR",
@@ -308,11 +310,13 @@ class LoopsFormat:
 
     @functools.cached_property
     def csr_panels(self) -> "PanelCSR":
-        return panelize_csr(self.csr_part, self.panel_g_eff)
+        with span("loops.panelize", part="csr"):
+            return panelize_csr(self.csr_part, self.panel_g_eff)
 
     @functools.cached_property
     def bcsr_panels(self) -> "PanelBCSR":
-        return panelize_bcsr(self.bcsr_part, self.panel_g_eff)
+        with span("loops.panelize", part="bcsr"):
+            return panelize_bcsr(self.bcsr_part, self.panel_g_eff)
 
     @functools.cached_property
     def nnz(self) -> int:
@@ -420,26 +424,29 @@ def csr_from_coo(rows, cols, vals, shape, *,
     classified ``SparseInputError``; ``"drop"``/``"clip"`` repair instead
     (drop the entry, or clip it into range), recording ``validate.repaired``
     counters; ``None`` skips the gate (trusted internal callers only).
+    Runs under the program span ``loops.coo_to_csr``.
     """
-    if validate is not None:
-        from ..resilience.validate import validate_coo
-        rows, cols, vals, _ = validate_coo(
-            rows, cols, vals, shape,
-            repair=None if validate == "strict" else validate)
-    rows = np.asarray(rows, np.int64)
-    cols = np.asarray(cols, np.int64)
-    vals = np.asarray(vals)
-    # np.unique on the linearised coordinate both dedups and (row, col)-sorts.
-    lin = rows * int(shape[1]) + cols
-    uniq, inv = np.unique(lin, return_inverse=True)
-    summed = np.zeros(len(uniq), vals.dtype)
-    np.add.at(summed, inv, vals)
-    rows = uniq // int(shape[1])
-    cols = uniq % int(shape[1])
-    counts = np.bincount(rows, minlength=shape[0])
-    row_ptr = np.zeros(shape[0] + 1, np.int32)
-    np.cumsum(counts, out=row_ptr[1:])
-    return _csr_from_arrays(row_ptr, cols, summed, shape)
+    with span("loops.coo_to_csr"):
+        if validate is not None:
+            from ..resilience.validate import validate_coo
+            rows, cols, vals, _ = validate_coo(
+                rows, cols, vals, shape,
+                repair=None if validate == "strict" else validate)
+        rows = np.asarray(rows, np.int64)
+        cols = np.asarray(cols, np.int64)
+        vals = np.asarray(vals)
+        # np.unique on the linearised coordinate both dedups and
+        # (row, col)-sorts.
+        lin = rows * int(shape[1]) + cols
+        uniq, inv = np.unique(lin, return_inverse=True)
+        summed = np.zeros(len(uniq), vals.dtype)
+        np.add.at(summed, inv, vals)
+        rows = uniq // int(shape[1])
+        cols = uniq % int(shape[1])
+        counts = np.bincount(rows, minlength=shape[0])
+        row_ptr = np.zeros(shape[0] + 1, np.int32)
+        np.cumsum(counts, out=row_ptr[1:])
+        return _csr_from_arrays(row_ptr, cols, summed, shape)
 
 
 def csr_to_dense(csr: CSR) -> np.ndarray:

@@ -37,6 +37,7 @@ import numpy as np
 
 from ..kernels import engine, ref
 from ..kernels.panel_common import CSR_WORDS, default_bn, panel_calls
+from ..obs.runtime import span
 from ..resilience import fallback as _resilience
 from . import partition
 from .formats import (CSR, DEFAULT_PANEL_G, HALF_PACKED_ROWS, LoopsFormat,
@@ -99,28 +100,32 @@ def plan_and_convert(csr: CSR, *, total_workers: int = 8,
     input before Algorithm 1 can index with it; ``"drop"``/``"clip"`` repair
     instead (recording ``validate.repaired`` counters); ``None`` trusts the
     caller (hot inner loops that already validated).
+
+    Runs under the program span ``loops.format``.
     """
-    if validate is not None:
-        from ..resilience.validate import validate_csr
-        csr, _ = validate_csr(
-            csr, repair=None if validate == "strict" else validate)
-    if tuner is not None:
-        return tuner.tune(csr)
-    br = br or default_br(csr.vals.dtype)
-    panel_g = panel_g or DEFAULT_PANEL_G
-    if model is not None:
-        t_vpu, t_mxu = model.best_allocation(total_workers)
-    else:
-        t_mxu = max(int(round(total_workers * tp_mxu / (tp_vpu + tp_mxu))), 1)
-        t_vpu = max(total_workers - t_mxu, 1)
-    r_b = partition.choose_r_boundary(
-        csr.nrows, tp_vpu, tp_mxu, t_vpu, t_mxu, br=br,
-        paper_literal=paper_literal)
-    fmt = loops_from_csr(csr, r_b, br, panel_g=panel_g,
-                         macro_m=macro_m, pipeline_depth=pipeline_depth)
-    return fmt, SpmmPlan(
-        r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br, panel_g=panel_g,
-        pipeline_depth=pipeline_depth, macro_m=macro_m)
+    with span("loops.format"):
+        if validate is not None:
+            from ..resilience.validate import validate_csr
+            csr, _ = validate_csr(
+                csr, repair=None if validate == "strict" else validate)
+        if tuner is not None:
+            return tuner.tune(csr)
+        br = br or default_br(csr.vals.dtype)
+        panel_g = panel_g or DEFAULT_PANEL_G
+        if model is not None:
+            t_vpu, t_mxu = model.best_allocation(total_workers)
+        else:
+            t_mxu = max(int(round(total_workers * tp_mxu
+                                  / (tp_vpu + tp_mxu))), 1)
+            t_vpu = max(total_workers - t_mxu, 1)
+        r_b = partition.choose_r_boundary(
+            csr.nrows, tp_vpu, tp_mxu, t_vpu, t_mxu, br=br,
+            paper_literal=paper_literal)
+        fmt = loops_from_csr(csr, r_b, br, panel_g=panel_g,
+                             macro_m=macro_m, pipeline_depth=pipeline_depth)
+        return fmt, SpmmPlan(
+            r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br, panel_g=panel_g,
+            pipeline_depth=pipeline_depth, macro_m=macro_m)
 
 
 def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,

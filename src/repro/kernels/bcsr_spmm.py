@@ -290,6 +290,7 @@ def bcsr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
             out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
             input_output_aliases=aliases,
             interpret=interpret,
+            name="loops_bcsr_spmm",
         )(prev, rows, cols, *args)
 
     return run_panel_chunks(

@@ -293,6 +293,7 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                 tuple(out_shape[:-1]) + (1, n), out_dtype),
             input_output_aliases=aliases,
             interpret=interpret,
+            name="loops_csr_spmm",
         )(prev, rows, cols, vals.astype(acc_dtype), *args)
         return out.reshape(out_shape)
 

@@ -43,6 +43,7 @@ from typing import Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..resilience import fallback as _fallback
 
@@ -201,7 +202,8 @@ def _empty_batch(b) -> bool:
 # A single process-wide tracer hook.  The entry points below call
 # ``_note(part, op, ...)`` with STRUCTURAL dispatch facts (which kernel
 # flavour ran, how many panels/nonzeros the grid walks, the flat batch and
-# column extents).  The calls fire at trace time — under ``jax.jit`` that is
+# column extents, the bytes of host arrays it turns into program
+# constants).  The calls fire at trace time — under ``jax.jit`` that is
 # once per compilation, not once per execution — so a tracer must never
 # record wall-clock here; timing belongs at blocking call sites
 # (``repro.perf.trace.TraceRecorder``'s timed wrappers).
@@ -235,6 +237,15 @@ def _note(part: str, op: str, **fields) -> None:
         else:
             fields["steps"] = units * max(-(-nb // batch_block(nb)), 1)
     _TRACER.on_dispatch(part=part, op=op, **fields)
+
+
+def _const_bytes(*arrays) -> int:
+    """Bytes of the host (numpy) arrays among ``arrays``: inside a trace
+    each becomes a constant of the program being compiled.  0 outside one,
+    where an eager call passes them as arguments."""
+    if jax.core.trace_ctx.is_top_level():
+        return 0
+    return int(sum(a.nbytes for a in arrays if isinstance(a, np.ndarray)))
 
 
 def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
@@ -307,6 +318,19 @@ def get_kernel(part: str, op: str, impl: str = "panels") -> Callable:
 # panel-value scatter (the ONE copy)
 # ---------------------------------------------------------------------------
 
+def _packed_values(panels):
+    """The host-packed panel values in the kernels' lane layout."""
+    return (panels.vals_window if panels.panel_vals.ndim == 3
+            else panels.lane_vals)
+
+
+def _panel_const_bytes(panels, vals) -> int:
+    """:func:`_const_bytes` of one panel dispatch's metadata and, unless
+    ``vals`` replaces them, its host-packed values."""
+    return _const_bytes(panels.panel_rows, panels.lane_cols,
+                        _packed_values(panels) if vals is None else None)
+
+
 def panel_values(panels, vals):
     """Panel values in the kernels' lane layout (flat ``(P·G,)`` for CSR
     panels, the ``(Br, L)`` window for BCSR panels): the host-packed
@@ -315,8 +339,7 @@ def panel_values(panels, vals):
     layer ride the static structure)."""
     from .panel_common import values_window
     if vals is None:
-        return jnp.asarray(panels.vals_window if panels.panel_vals.ndim == 3
-                           else panels.lane_vals)
+        return jnp.asarray(_packed_values(panels))
     live = panels.scatter_values(jnp.asarray(vals))
     return values_window(live) if live.ndim == 3 else live.reshape(-1)
 
@@ -343,11 +366,14 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
     if _empty_batch(b):
         _, out = resolve_dtypes(v.dtype, out_dtype)
         return jnp.zeros(b.shape[:-2] + (csr.nrows, b.shape[-1]), out)
+    flat_arrays = (csr.row_ids, csr.col_idx, csr.vals if vals is None
+                   else None)
 
     def attempt(bk: str) -> jax.Array:
         if bk == "jnp":
             _note("csr", "spmm", backend=bk, impl="ref", units=csr.nnz,
-                  batch=1, n=int(b.shape[-1]))
+                  batch=1, n=int(b.shape[-1]),
+                  const_bytes=_const_bytes(*flat_arrays))
             return get_kernel("csr", "spmm", "ref")(
                 jnp.asarray(csr.row_ids), jnp.asarray(csr.col_idx), v, b,
                 csr.nrows, out_dtype=out_dtype)
@@ -364,7 +390,10 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
               impl="panels" if panels is not None else "flat",
               units=int(panels.npanels) if panels is not None
               else int(csr.nnz),
-              batch=nb, n=int(b.shape[-1]), **extra)
+              batch=nb, n=int(b.shape[-1]),
+              const_bytes=_panel_const_bytes(panels, vals)
+              if panels is not None else _const_bytes(*flat_arrays),
+              **extra)
         if panels is not None:
             out = get_kernel("csr", "spmm", "panels")(
                 jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
@@ -399,11 +428,14 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
     if _empty_batch(b):
         _, out = resolve_dtypes(v.dtype, out_dtype)
         return jnp.zeros(b.shape[:-2] + (bcsr.nrows, b.shape[-1]), out)
+    flat_arrays = (bcsr.tile_rows, bcsr.tile_cols, bcsr.tile_vals
+                   if vals is None else None)
 
     def attempt(bk: str) -> jax.Array:
         if bk == "jnp":
             _note("bcsr", "spmm", backend=bk, impl="ref",
-                  units=int(bcsr.ntiles), batch=1, n=int(b.shape[-1]))
+                  units=int(bcsr.ntiles), batch=1, n=int(b.shape[-1]),
+                  const_bytes=_const_bytes(*flat_arrays))
             padded = get_kernel("bcsr", "spmm", "ref")(
                 jnp.asarray(bcsr.tile_rows), jnp.asarray(bcsr.tile_cols), v,
                 b, bcsr.nblocks, out_dtype=out_dtype)
@@ -421,7 +453,10 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
               impl="panels" if panels is not None else "flat",
               units=int(panels.npanels) if panels is not None
               else int(bcsr.ntiles),
-              batch=nb, n=int(b.shape[-1]), **extra)
+              batch=nb, n=int(b.shape[-1]),
+              const_bytes=_panel_const_bytes(panels, vals)
+              if panels is not None else _const_bytes(*flat_arrays),
+              **extra)
         if panels is not None:
             padded = get_kernel("bcsr", "spmm", "panels")(
                 jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
@@ -483,12 +518,14 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
         vdt = fmt.csr_part.vals.dtype
         _note("csr", "spmm", backend=bk, impl="panels", fused=True,
               units=int(cp.npanels), batch=nb, n=int(b.shape[-1]),
+              const_bytes=_panel_const_bytes(cp, csr_vals),
               **_panel_note_fields(
                   part="csr", depth=depth, npanels=int(cp.npanels), nb=nb,
                   n=int(b.shape[-1]), bn=bn, g=int(cp.g), br=1,
                   b_dtype=b.dtype, value_dtype=vdt))
         _note("bcsr", "spmm", backend=bk, impl="panels", fused=True,
               units=int(bp.npanels), batch=nb, n=int(b.shape[-1]),
+              const_bytes=_panel_const_bytes(bp, bcsr_vals),
               **_panel_note_fields(
                   part="bcsr", depth=depth, npanels=int(bp.npanels), nb=nb,
                   n=int(b.shape[-1]), bn=bn, g=int(bp.g), br=int(bp.br),

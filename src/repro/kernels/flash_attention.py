@@ -127,5 +127,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     return out.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)

@@ -273,6 +273,7 @@ def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
             out_shape=jax.ShapeDtypeStruct((npanels, rows_out, g),
                                            acc_dtype),
             interpret=interpret,
+            name="loops_csr_sdd" if br is None else "loops_bcsr_sdd",
         )(rows, cols, dy, *([b_view] * g))
 
     out = _run_chunks(call, panel_rows, lane_cols, g,

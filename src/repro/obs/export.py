@@ -60,7 +60,8 @@ def obs_records(obs) -> List[Dict]:
         recs.append(_stamp("span", src, {
             "name": ev["name"], "cat": ev["cat"], "ts": float(ev["ts"]),
             "dur": float(ev["dur"]), "tid": int(ev["tid"]),
-            "depth": int(ev["depth"]),
+            "depth": int(ev["depth"]), "id": int(ev["id"]),
+            "parent": ev["parent"],
             "args": {k: v for k, v in ev["args"].items()}}))
     for rec in obs.metrics.as_records():
         recs.append(_stamp(rec.pop("kind"), src, rec))

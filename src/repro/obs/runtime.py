@@ -7,12 +7,14 @@ owns the built-in instrumentation seams:
   * :meth:`Obs.attach_engine` — hooks the execution engine's dispatch
     tracer (``repro.kernels.engine.set_tracer``) and turns every kernel
     dispatch into ``engine.dispatch`` counters (labeled ``part``/``op``/
-    ``backend``/``impl``) plus ``engine.grid_steps`` gauges.  Dispatches
-    fire at *trace* time — once per jit compilation — so these are
-    compilation-workload counters, deliberately not per-execution (the
-    per-execution signal is the step/request latency recorded on the
-    host).  The hook **chains**: a previously installed tracer (e.g. a
-    ``repro.perf.trace.TraceRecorder``) keeps receiving every dispatch.
+    ``backend``/``impl``), ``engine.grid_steps_compiled`` and
+    ``engine.const_bytes`` (host arrays turned into program constants)
+    counters.  Dispatches fire at *trace* time — once per jit compilation
+    — so these are compilation-workload counters, deliberately not
+    per-execution (the per-execution signal is the step/request latency
+    recorded on the host).  The hook **chains**: a previously installed
+    tracer (e.g. a ``repro.perf.trace.TraceRecorder``) keeps receiving
+    every dispatch.
   * :meth:`Obs.watch_cache` — registers a ``repro.tune.PlanCache`` whose
     ``CacheStats`` are exported as ``tune.cache.*`` gauges at snapshot
     time (pull model: the cache is read when records are exported, so the
@@ -21,18 +23,30 @@ owns the built-in instrumentation seams:
     ``dist/step.py`` builder products take ``obs=``): each call runs under
     a span, blocks on its outputs, and lands one observation in the
     ``step.wall_us`` histogram for its ``op``.
+
+and the rendezvous of the *active* capture (:func:`set_active`), which code
+far from any ``Obs`` reports to without holding one:
+
+  * :func:`span` — a program span (``loops.coo_to_csr``, ``loops.format``,
+    ``loops.panelize``) on the active capture; a shared no-op without one;
   * collective accounting — ``repro.dist.compress.compressed_psum``
-    reports its per-call wire bytes to the *active* capture
-    (:func:`set_active`; trace-time, so the gauge is bytes-per-call and
-    the counter counts compiled call sites).
+    reports its per-call wire bytes (trace-time, so the gauge is
+    bytes-per-call and the counter counts compiled call sites);
+  * compile accounting — one process-wide ``jax.monitoring`` listener,
+    registered when a capture is first made active, turns jax's compile
+    and persistent-cache events into ``compile.*`` counters;
+  * degraded paths — ``repro.resilience.inject.note_degraded`` counts
+    fallbacks (``engine.fallback``, ``dist.fallback``, ...).
 
 ``save()`` writes both serialisations (JSONL + Chrome trace) next to each
 other under ``benchmarks/results/obs/`` by default.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import pathlib
+import threading
 import time
 from typing import Dict, Optional, Tuple
 
@@ -41,7 +55,7 @@ from .export import (chrome_trace, default_obs_dir, obs_records, write_chrome_tr
 from .metrics import MetricsRegistry
 from .spans import SpanSink
 
-__all__ = ["Obs", "set_active", "get_active", "note_collective"]
+__all__ = ["Obs", "set_active", "get_active", "span", "note_collective"]
 
 # Process-wide active capture (the collective hook's rendezvous; launch
 # drivers install their Obs here for the duration of a run).
@@ -50,14 +64,140 @@ _ACTIVE: Optional["Obs"] = None
 
 def set_active(obs: Optional["Obs"]) -> Optional["Obs"]:
     """Install ``obs`` as the process-wide capture (None detaches);
-    returns the previous one so callers can restore it."""
+    returns the previous one so callers can restore it.  The first capture
+    made active registers the compile listener (:class:`_CompileListener`)."""
     global _ACTIVE
+    if obs is not None:
+        _CompileListener.install()
     prev, _ACTIVE = _ACTIVE, obs
     return prev
 
 
 def get_active() -> Optional["Obs"]:
     return _ACTIVE
+
+
+class _NoSpan:
+    """What :func:`span` gives without an active capture: records nothing."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **args) -> None:
+        pass
+
+    def fence(self, *values) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **args):
+    """A program span named ``name`` on the active capture (category
+    ``program``), or the shared no-op :class:`_NoSpan` without one."""
+    obs = _ACTIVE
+    if obs is None:
+        return _NO_SPAN
+    return obs.span(name, cat="program", **args)
+
+
+class _CompileListener:
+    """jax's compile events as ``compile.*`` counters of the active capture.
+
+    jax times each stage of a compile in a region that opens with a scalar
+    event (its start time, with ``fun_name``) and closes with a duration
+    event of the same name:
+
+    * ``jaxpr_trace_duration`` and ``jaxpr_to_mlir_module_duration`` add to
+      ``compile.trace_lower_s{fun_name}``.  Tracing an outer function
+      traces the jitted functions it calls inside its own region; only
+      the outermost region of a thread counts, so nothing counts twice;
+    * ``backend_compile_duration`` encloses the persistent-cache lookup.
+      A hit inside it records ``cache_retrieval_time_sec``, which goes to
+      ``compile.cache_load_s{fun_name}`` and is taken off the region:
+      ``compile.xla_s{fun_name}`` is XLA compile alone, so a hit reads as
+      a load and not as a compile;
+    * ``cache_hits`` and ``cache_misses`` (jax counts a miss when it writes
+      the compiled program to the cache) count into
+      ``compile.cache_hits{fun_name}`` and ``compile.cache_misses{fun_name}``,
+      labelled by the compile they happen in.
+    """
+
+    TRACE_LOWER = ("/jax/core/compile/jaxpr_trace_duration",
+                   "/jax/core/compile/jaxpr_to_mlir_module_duration")
+    XLA = "/jax/core/compile/backend_compile_duration"
+    CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+    CACHE_COUNTS = {"/jax/compilation_cache/cache_hits": "compile.cache_hits",
+                    "/jax/compilation_cache/cache_misses":
+                        "compile.cache_misses"}
+    _installed = False
+    _lock = threading.Lock()
+
+    @classmethod
+    def install(cls) -> None:
+        """Register one listener for the process (idempotent)."""
+        with cls._lock:
+            if cls._installed:
+                return
+            from jax import monitoring
+            listener = cls()
+            monitoring.register_scalar_listener(listener.on_start)
+            monitoring.register_event_duration_secs_listener(
+                listener.on_duration)
+            monitoring.register_event_listener(listener.on_event)
+            cls._installed = True
+
+    def __init__(self):
+        self._local = threading.local()
+
+    def _state(self):
+        st = self._local
+        if not hasattr(st, "depth"):
+            st.depth = collections.Counter()
+            st.compiling = "?"     # fun_name of the open backend compile
+            st.loaded = 0.0        # cache load seconds inside it
+        return st
+
+    def on_start(self, event: str, value, fun_name: str = "?", **_) -> None:
+        if event in self.TRACE_LOWER or event == self.XLA:
+            st = self._state()
+            st.depth[event] += 1
+            if event == self.XLA:
+                st.compiling, st.loaded = fun_name, 0.0
+
+    def on_duration(self, event: str, secs: float, fun_name: str = "?",
+                    **_) -> None:
+        st = self._state()
+        if event == self.CACHE_LOAD:
+            st.loaded += secs
+            self._count("compile.cache_load_s", st.compiling, secs)
+            return
+        if event not in self.TRACE_LOWER and event != self.XLA:
+            return
+        depth = st.depth[event] - 1
+        st.depth[event] = max(depth, 0)
+        if depth > 0:
+            return          # nested in an outer region of the same stage
+        if event == self.XLA:
+            self._count("compile.xla_s", fun_name, max(secs - st.loaded, 0.0))
+            st.loaded = 0.0
+        else:
+            self._count("compile.trace_lower_s", fun_name, secs)
+
+    def on_event(self, event: str, **_) -> None:
+        name = self.CACHE_COUNTS.get(event)
+        if name is not None:
+            self._count(name, self._state().compiling, 1.0)
+
+    @staticmethod
+    def _count(name: str, fun_name: str, value: float) -> None:
+        obs = _ACTIVE
+        if obs is not None:
+            obs.metrics.counter(name, fun_name=fun_name).inc(value)
 
 
 def note_collective(nbytes: int, *, kind: str, precision: str) -> None:
@@ -90,9 +230,11 @@ class _EngineTracer:
                   impl=fields.get("impl", "?")).inc()
         steps = fields.get("steps")
         if steps is not None:
-            m.gauge("engine.grid_steps", part=part, op=op).set(float(steps))
             m.counter("engine.grid_steps_compiled", part=part,
                       op=op).inc(float(steps))
+        cb = fields.get("const_bytes")
+        if cb:
+            m.counter("engine.const_bytes", part=part).inc(float(cb))
         sb = fields.get("scratch_bytes")
         if sb is not None:
             m.gauge("kernel.scratch_bytes", part=part, op=op).set(float(sb))
@@ -179,8 +321,6 @@ class Obs:
             # (note_degraded): the registry forbids one name in two kinds
             self.metrics.gauge("tune.cache.quarantined_files",
                                cache=name).set(st.quarantined)
-            self.metrics.gauge("tune.cache.prewarmed",
-                               cache=name).set(getattr(st, "prewarmed", 0))
 
     # -- step seam ---------------------------------------------------------
 

@@ -11,7 +11,13 @@ category, start timestamp (µs since the recorder's epoch), duration, thread
 id and a free-form ``args`` dict.  Nesting is structural — each thread keeps
 its own span stack, a child opened under a parent always closes before it —
 so the exported events are properly nested per thread and Perfetto renders
-them as a flame graph without any reparenting pass.
+them as a flame graph without any reparenting pass.  Each event also
+carries a process-wide ``id`` and the ``parent`` id of the span open around
+it on its thread (None at the top), so a reader can follow cause to effect.
+
+While it is open a span also holds a ``jax.profiler.TraceAnnotation`` of its
+name: inside a ``jax.profiler.trace`` it appears as a host event on the
+profiler's clock, in the same trace as the device ops it caused.
 
 jit-safety: a ``with span(...)`` placed *inside* a jitted function's Python
 body executes while jax is abstractly tracing — the timed interval would be
@@ -23,6 +29,7 @@ the async dispatch tail into the measured interval.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Dict, List, Optional
@@ -30,6 +37,7 @@ from typing import Dict, List, Optional
 __all__ = ["Span", "SpanSink", "current_span"]
 
 _LOCAL = threading.local()
+_IDS = itertools.count(1)
 
 
 def _stack() -> List["Span"]:
@@ -55,8 +63,9 @@ class SpanSink:
     """Collects completed span events against one perf_counter epoch.
 
     ``events`` is append-only; each event is a plain dict with ``name``,
-    ``cat``, ``ts`` (µs since epoch), ``dur`` (µs), ``tid``, ``depth`` and
-    ``args`` — the exporter's native unit (Chrome traces are µs-based).
+    ``cat``, ``ts`` (µs since epoch), ``dur`` (µs), ``tid``, ``depth``,
+    ``id``, ``parent`` and ``args`` — the exporter's native unit (Chrome
+    traces are µs-based).
     """
 
     def __init__(self, on_drop=None):
@@ -83,7 +92,8 @@ class SpanSink:
 class Span:
     """Context manager for one timed region (see module docstring)."""
 
-    __slots__ = ("sink", "name", "cat", "args", "_t0", "_fences", "_live")
+    __slots__ = ("sink", "name", "cat", "args", "_t0", "_fences", "_live",
+                 "_annotation", "id", "parent")
 
     def __init__(self, sink: SpanSink, name: str, cat: str = "obs",
                  args: Optional[Dict] = None):
@@ -111,8 +121,14 @@ class Span:
             self.sink.dropped(self.name)
             self._live = False
             return self
+        import jax
         self._live = True
-        _stack().append(self)
+        st = _stack()
+        self.id = next(_IDS)
+        self.parent = st[-1].id if st else None
+        st.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -123,6 +139,7 @@ class Span:
             import jax
             jax.block_until_ready(self._fences)
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         # Tolerate exceptions unwinding several spans at once: pop until us.
         while st and st[-1] is not self:
@@ -137,6 +154,7 @@ class Span:
             "ts": (self._t0 - self.sink.epoch) * 1e6,
             "dur": (t1 - self._t0) * 1e6,
             "tid": threading.get_ident(), "depth": depth,
+            "id": self.id, "parent": self.parent,
             "args": self.args,
         })
         return False

@@ -13,7 +13,9 @@ The load-bearing claims:
   * **seams** — the engine dispatch hook feeds per-(part, op) counters and
     chains to an already-installed `TraceRecorder`; a watched `PlanCache`
     exports `tune.cache.*` gauges; `compressed_psum` reports wire bytes;
-    `wrap_step` lands per-call latency histograms.
+    `wrap_step` lands per-call latency histograms;
+  * **the active capture** — program spans on conversion and jax's compile
+    events land in the active capture, and nowhere without one.
 """
 from __future__ import annotations
 
@@ -29,9 +31,10 @@ import numpy as np
 import pytest
 
 from repro.core import csr_from_dense, loops_spmm, plan_and_convert
+from repro.core.formats import csr_from_coo
 from repro.obs import (OBS_SCHEMA_VERSION, Histogram, MetricsRegistry, Obs,
                        SpanSink, current_span, get_active, load_obs,
-                       set_active)
+                       set_active, span)
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 
@@ -183,6 +186,20 @@ def test_span_nesting_depth_and_order():
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
 
 
+def test_span_ids_name_the_enclosing_span():
+    obs = Obs(source="t")
+    with obs.span("outer"):
+        with obs.span("inner"):
+            pass
+        with obs.span("sibling"):
+            pass
+    ev = {e["name"]: e for e in obs.sink.events}
+    assert len({e["id"] for e in ev.values()}) == 3
+    assert ev["outer"]["parent"] is None
+    outer = ev["outer"]["id"]
+    assert ev["inner"]["parent"] == ev["sibling"]["parent"] == outer
+
+
 def test_span_exception_unwind_records_error():
     obs = Obs(source="t")
     with pytest.raises(RuntimeError):
@@ -237,6 +254,9 @@ def test_jsonl_round_trip(tmp_path):
     assert kinds == {"meta", "span", "counter", "gauge", "hist"}
     assert all(r["schema"] == OBS_SCHEMA_VERSION for r in recs)
     assert all(r["source"] == "t" for r in recs)
+    spans = {r["name"]: r for r in recs if r["kind"] == "span"}
+    assert spans["inner"]["parent"] == spans["outer"]["id"]
+    assert spans["outer"]["parent"] is None
     hist = next(r for r in recs if r["kind"] == "hist")
     assert hist["count"] == 1 and hist["p50"] == 10.0
     assert sum(hist["counts"]) == 1
@@ -299,6 +319,8 @@ def test_attach_engine_counts_dispatches(rng):
     steps = [inst for kind, inst in obs.metrics.instruments()
              if inst.name == "engine.grid_steps_compiled"]
     assert steps and all(inst.value > 0 for inst in steps)
+    assert not any(inst.name == "engine.grid_steps"
+                   for kind, inst in obs.metrics.instruments())
     assert obs.summary()["engine_dispatches"] == int(total)
 
 
@@ -346,6 +368,7 @@ def test_watch_cache_exports_hit_rate(tmp_path):
     assert gauges[("tune.cache.hits", "test")] == 1.0
     assert gauges[("tune.cache.misses", "test")] == 1.0
     assert gauges[("tune.cache.hit_rate", "test")] == 0.5
+    assert ("tune.cache.prewarmed", "test") not in gauges
 
 
 # ---------------------------------------------------------------------------
@@ -402,6 +425,131 @@ def test_active_capture_set_and_restore():
     assert prev is None and get_active() is obs
     set_active(prev)
     assert get_active() is None
+
+
+# ---------------------------------------------------------------------------
+# Program spans, compile counters and constant bytes (the active capture)
+# ---------------------------------------------------------------------------
+
+def _coo(rng, m=128, k=48, nnz=800):
+    return (rng.integers(0, m, nnz), rng.integers(0, k, nnz),
+            rng.standard_normal(nnz).astype(np.float32), (m, k))
+
+
+def _convert(rng):
+    fmt, _ = plan_and_convert(csr_from_coo(*_coo(rng)), total_workers=4)
+    fmt.csr_panels, fmt.bcsr_panels
+    return fmt
+
+
+def _compile_counters(obs):
+    return {(inst.name, inst.labels.get("fun_name")): inst.value
+            for kind, inst in obs.metrics.instruments()
+            if kind == "counter" and inst.name.startswith("compile.")}
+
+
+def test_nothing_is_recorded_without_an_active_capture(rng):
+    assert get_active() is None
+    idle = Obs(source="idle")
+    assert span("loops.format") is span("loops.coo_to_csr")   # shared no-op
+    with span("loops.format", part="csr") as sp:
+        sp.set(k=1)
+    _convert(rng)
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(5)).block_until_ready()
+    assert idle.sink.events == [] and idle.metrics.instruments() == []
+
+
+def test_conversion_opens_program_spans(rng):
+    obs = Obs(source="t")
+    prev = set_active(obs)
+    try:
+        with obs.span("bench.convert"):
+            _convert(rng)
+    finally:
+        set_active(prev)
+    ev = {(e["name"], e["args"].get("part")): e for e in obs.sink.events}
+    assert set(ev) == {("loops.coo_to_csr", None), ("loops.format", None),
+                       ("loops.panelize", "csr"), ("loops.panelize", "bcsr"),
+                       ("bench.convert", None)}
+    outer = ev[("bench.convert", None)]["id"]
+    for key, e in ev.items():
+        if key[0].startswith("loops."):
+            assert e["cat"] == "program" and e["parent"] == outer
+
+
+def test_a_fresh_jit_counts_its_compile_once():
+    f = jax.jit(lambda x: jnp.sin(x) * 7.0 - 2.0)
+    x = jnp.ones(11)
+    obs = Obs(source="t")
+    prev = set_active(obs)
+    try:
+        f(x).block_until_ready()
+        first = _compile_counters(obs)
+        f(x).block_until_ready()
+        second = _compile_counters(obs)
+    finally:
+        set_active(prev)
+    assert first[("compile.trace_lower_s", "<lambda>")] > 0
+    assert first[("compile.trace_lower_s", "jit(<lambda>)")] > 0
+    assert first[("compile.xla_s", "jit(<lambda>)")] > 0
+    assert second == first          # a cached call compiles nothing
+
+
+def test_nested_jits_trace_inside_the_outer_count():
+    inner = jax.jit(lambda x: x * 2.0)
+    x = jnp.ones(13)
+    obs = Obs(source="t")
+    prev = set_active(obs)
+    try:
+        jax.jit(lambda x: inner(x) + 1.0)(x).block_until_ready()
+    finally:
+        set_active(prev)
+    traced = {fun for name, fun in _compile_counters(obs)
+              if name == "compile.trace_lower_s"}
+    assert traced == {"<lambda>", "jit(<lambda>)"}
+
+
+def test_const_bytes_are_the_panel_arrays(rng):
+    fmt = _convert(rng)
+    cp, bp = fmt.csr_panels, fmt.bcsr_panels
+    assert fmt.r_boundary % bp.br == 0 and 0 < fmt.r_boundary < fmt.nrows
+    obs = Obs(source="t")
+    with obs.attach_engine():
+        jax.jit(lambda b: loops_spmm(fmt, b, backend="interpret")).lower(
+            jnp.ones((fmt.ncols, 8), jnp.float32))
+    got = {inst.labels["part"]: inst.value
+           for kind, inst in obs.metrics.instruments()
+           if inst.name == "engine.const_bytes"}
+    assert got == {
+        "csr": cp.panel_rows.nbytes + cp.lane_cols.nbytes
+        + cp.lane_vals.nbytes,
+        "bcsr": bp.panel_rows.nbytes + bp.lane_cols.nbytes
+        + bp.vals_window.nbytes}
+
+
+def test_eager_dispatch_embeds_no_constants(rng):
+    fmt = _convert(rng)
+    obs = Obs(source="t")
+    with obs.attach_engine():
+        loops_spmm(fmt, jnp.ones((fmt.ncols, 4), jnp.float32), backend="jnp")
+    assert obs.metrics.find("counter", "engine.const_bytes",
+                            part="csr") is None
+
+
+def test_program_span_is_a_host_event_of_the_profiler(tmp_path, rng):
+    from jax.profiler import ProfileData
+    obs = Obs(source="t")
+    prev = set_active(obs)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            csr_from_coo(*_coo(rng))
+    finally:
+        set_active(prev)
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(str(path)).planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events}
+    assert "loops.coo_to_csr" in names
 
 
 # ---------------------------------------------------------------------------

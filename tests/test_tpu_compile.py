@@ -13,12 +13,16 @@ all import this file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.formats import csr_from_dense
+from repro.core.spmm import loops_spmm, plan_and_convert
 from repro.kernels.bcsr_spmm import bcsr_panels_spmm_pallas
 from repro.kernels.csr_spmm import csr_panels_spmm_pallas
 from repro.kernels.panel_common import panels_per_call
@@ -126,3 +130,28 @@ def test_batched_kernel_compiles(one_chip, op):
                            batch=4)
     hlo = _compile(_kernel("csr", op, br=br, depth=1), one_chip, *shapes)
     assert "tpu_custom_call" in hlo
+
+
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*custom-call\(.*"
+                          r"custom_call_target=\"tpu_custom_call\"")
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_fused_kernels_carry_their_names(one_chip, depth):
+    """The fused ``loops_spmm`` names every kernel instruction after its
+    part, so that a device trace tells the CSR kernel from the BCSR one;
+    the BCSR part here has more panels than one call's SMEM holds, so its
+    chunk loop and remainder are named too."""
+    rng = np.random.default_rng(0)
+    a = ((rng.random((8192, 256)) < 0.05)
+         * rng.standard_normal((8192, 256))).astype(np.float32)
+    fmt, _ = plan_and_convert(csr_from_dense(a), pipeline_depth=depth)
+    assert fmt.bcsr_panels.npanels > panels_per_call(fmt.panel_g_eff)
+    hlo = _compile(lambda b: loops_spmm(fmt, b, backend="pallas"), one_chip,
+                   ((256, 32), jnp.float32))
+    names = [m.group(1) for m in map(_CUSTOM_CALL.match, hlo.splitlines())
+             if m]
+    assert len(names) == hlo.count('custom_call_target="tpu_custom_call"')
+    parts = {re.sub(r"\.\d+$", "", n) for n in names}
+    assert parts == {"loops_csr_spmm", "loops_bcsr_spmm"}
+    assert sum(n.startswith("loops_bcsr_spmm") for n in names) >= 2
