@@ -205,11 +205,13 @@ class PanelBCSR:
 
     Panel ``p`` stacks up to ``G`` of block-row ``panel_rows[p]``'s
     ``Br x 1`` column tiles side by side: ``panel_vals[p]`` is a real
-    ``(Br, G)`` operand and the kernel's contraction becomes one
-    ``(Br, G) @ (G, bn)`` MXU matmul per grid step instead of a chain of
-    G rank-1 updates — the multi-round fmopa batching of paper Figure 2.
-    Block-rows never share a panel; the trailing panel of each block-row
-    is padded (mask 0, zero columns).  ``panel_rows`` is nondecreasing.
+    ``(Br, G)`` operand.  Laid side by side in the lane-dense values window
+    (:attr:`vals_window`), a block row's panels are contiguous, and the
+    SpMM kernel contracts one 128-lane window of one block row per grid
+    step (:attr:`steps`) instead of a chain of rank-1 updates — the
+    multi-round fmopa batching of paper Figure 2.  Block-rows never share
+    a panel; the trailing panel of each block-row is padded (mask 0, zero
+    columns).  ``panel_rows`` is nondecreasing.
     """
 
     panel_rows: np.ndarray  # (P,) int32 block-row per panel, nondecreasing
@@ -260,6 +262,14 @@ class PanelBCSR:
         (``kernels.panel_common.values_window``)."""
         from ..kernels.panel_common import values_window
         return values_window(self.panel_vals)
+
+    @functools.cached_property
+    def steps(self) -> Tuple[np.ndarray, np.ndarray]:
+        """The SpMM kernel's step table ``(step_rows, step_starts)``: one
+        step per 128-lane window of a block row
+        (``kernels.panel_common.window_steps``)."""
+        from ..kernels.panel_common import window_steps
+        return window_steps(self.panel_rows, self.g)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -36,7 +36,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import engine, ref
-from ..kernels.panel_common import CSR_WORDS, default_bn, panel_calls
+from ..kernels.panel_common import (CSR_WORDS, default_bn, gather_bn,
+                                   panel_calls)
 from ..obs.runtime import span
 from ..resilience import fallback as _resilience
 from . import partition
@@ -360,33 +361,30 @@ def loops_grid_steps(fmt: LoopsFormat, n_cols: int,
     """Total Pallas grid steps to execute ``fmt`` against an (K, n_cols)
     operand — the hardware-independent cost proxy the benchmarks track.
 
-    With G-wide panels the inner grid walks panels, not nonzeros, so the
-    count drops from ``(nnz_csr + ntiles) * col_blocks`` at G=1 towards a
-    ``~G``-fold reduction (padding at row/block-row boundaries is the gap
-    from the ideal).  ``macro_m > 1`` widens the effective panels (the
-    cached panel views are built at ``panel_g_eff``), shrinking the count
-    a further ``~macro_m``-fold; ``pipeline_depth = d`` adds ``d - 1``
-    fill/drain ramp steps per ``pallas_call`` of each *executed*
-    (non-empty) part — one call per SMEM-sized panel chunk
-    (``panel_common.panel_calls``).
+    The CSR grid walks panels, not nonzeros, so its count drops from
+    ``nnz_csr * col_blocks`` at G=1 towards a ``~G``-fold reduction
+    (padding at row boundaries is the gap from the ideal); ``macro_m > 1``
+    widens the effective panels (the cached panel views are built at
+    ``panel_g_eff``), and ``pipeline_depth = d`` adds ``d - 1`` fill/drain
+    ramp steps per ``pallas_call`` — one call per SMEM-sized panel chunk
+    (``panel_common.panel_calls``).  The BCSR grid walks window steps,
+    one per 128-lane window of a block row (``PanelBCSR.steps``), once per
+    column block of ``panel_common.gather_bn``, with no ramp.  A part the
+    executor skips contributes nothing.
     """
     bn = bn or default_bn(n_cols)
-    col_blocks = -(-n_cols // bn)
     depth = max(int(getattr(fmt, "pipeline_depth", 1)), 1)
-    p_csr = fmt.csr_panels.npanels
-    p_bcsr = fmt.bcsr_panels.npanels
-    # A part that loops_spmm skips contributes nothing — the empty BCSR part
-    # is not inherently zero-count (``bcsr_from_csr_rows`` keeps >= 1
-    # structural pad tile even for zero rows).
-    if fmt.r_boundary == 0:
-        p_csr = 0
-    if fmt.r_boundary == fmt.nrows:
-        p_bcsr = 0
-    g = fmt.panel_g_eff
     steps = 0
-    for p, words in ((p_csr, CSR_WORDS), (p_bcsr, 1)):
-        if p > 0:
-            steps += (p + panel_calls(p, g, words) * (depth - 1)) * col_blocks
+    # The empty BCSR part is not inherently zero-count
+    # (``bcsr_from_csr_rows`` keeps >= 1 structural pad tile even for zero
+    # rows), so a skipped part is read off the boundary.
+    if fmt.r_boundary > 0:
+        p = fmt.csr_panels.npanels
+        steps += ((p + panel_calls(p, fmt.panel_g_eff, CSR_WORDS)
+                   * (depth - 1)) * -(-n_cols // bn))
+    if fmt.r_boundary < fmt.nrows:
+        steps += (fmt.bcsr_panels.steps[0].shape[0]
+                  * (n_cols // gather_bn(n_cols, bn)))
     return steps
 
 

@@ -251,13 +251,14 @@ def _const_bytes(*arrays) -> int:
 def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
                        n: int, bn: int | None, g: int, br: int,
                        b_dtype, value_dtype) -> dict:
-    """Pipeline observability fields for a G-wide panel dispatch.
+    """Pipeline observability fields for a G-wide CSR panel dispatch.
 
     ``steps`` — grid steps including the ``depth - 1`` fill/drain ramp of
     each ``pallas_call`` (one per SMEM-sized panel chunk)
     (× batch groups, matching ``_note``'s default accounting at depth 1);
     ``scratch_bytes`` — VMEM scratch footprint (accumulator + the packed
-    ping-pong B-panel buffer, which stays in B's storage dtype);
+    ping-pong B-panel buffer of depth 2, which stays in B's storage
+    dtype);
     ``prefetch_overlap`` — fraction of grid steps whose B-row gathers
     overlap a contraction (0.0 for the serial depth-1 kernels).
     """
@@ -266,13 +267,10 @@ def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
     bz = batch_block(nb)
     bn_eff = bn or default_bn(n)
     acc = acc_dtype_for(value_dtype)
-    acc_rows = br if part == "bcsr" else 1
-    scratch = bz * acc_rows * bn_eff * jnp.dtype(acc).itemsize
+    scratch = bz * br * bn_eff * jnp.dtype(acc).itemsize
     b_item = jnp.dtype(b_dtype).itemsize
-    if part == "bcsr":
-        bpan_elems = max(depth, 1) * g * bn_eff * bz
-    else:   # depth-1 CSR reads gathered B rows directly (no staging buffer)
-        bpan_elems = depth * g * bn_eff * bz if depth > 1 else 0
+    # depth-1 CSR reads gathered B rows directly (no staging buffer)
+    bpan_elems = depth * g * bn_eff * bz if depth > 1 else 0
     # Each SMEM-sized chunk is its own launch with its own ramp.
     calls = panel_calls(npanels, g, CSR_WORDS if part == "csr" else 1)
     steps = npanels + calls * (depth - 1)
@@ -281,6 +279,35 @@ def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
             "steps": steps * groups,
             "scratch_bytes": int(scratch + bpan_elems * b_item),
             "prefetch_overlap": float(overlap)}
+
+
+def _bcsr_note_fields(panels, *, nb: int, n: int, bn: int | None,
+                      value_dtype) -> dict:
+    """Dispatch facts of the BCSR SpMM kernel, whose grid walks the window
+    steps of ``panels.steps`` once per column block and batch group.
+
+    ``gathers`` — B rows the step table fetches (valid lanes, the rest are
+    padding), so ``gathers / steps`` is the rows one grid step gathers;
+    ``scratch_bytes`` — the accumulator, the two-slot 32-bit B stage and
+    the lane-group column; ``prefetch_overlap`` — steps whose rows the
+    step before fetched (all but the first of each call's sweep)."""
+    from .bcsr_spmm import gather_layout, steps_per_call
+    from .panel_common import LANES
+    rows, starts = panels.steps
+    bz = batch_block(nb)
+    bn, width, _ = gather_layout(n, bn)
+    sweeps = (n // bn) * max(-(-nb // bz), 1)
+    nsteps = int(rows.shape[0])
+    calls = -(-nsteps // steps_per_call(starts)[0])
+    acc = jnp.dtype(acc_dtype_for(value_dtype)).itemsize
+    scratch = (bz * panels.br * width * acc + 2 * bz * LANES * width * 4
+               + 2 * LANES * 4)
+    return {"pipeline_depth": 1,
+            "units": nsteps,
+            "steps": nsteps * sweeps,
+            "gathers": int(np.count_nonzero(panels.lane_cols >= 0)) * sweeps,
+            "scratch_bytes": int(scratch),
+            "prefetch_overlap": max(nsteps - calls, 0) / max(nsteps, 1)}
 
 
 # ---------------------------------------------------------------------------
@@ -325,9 +352,12 @@ def _packed_values(panels):
 
 
 def _panel_const_bytes(panels, vals) -> int:
-    """:func:`_const_bytes` of one panel dispatch's metadata and, unless
+    """:func:`_const_bytes` of one SpMM panel dispatch's metadata (the BCSR
+    kernel reads its step table in place of ``panel_rows``) and, unless
     ``vals`` replaces them, its host-packed values."""
-    return _const_bytes(panels.panel_rows, panels.lane_cols,
+    meta = (panels.steps if panels.panel_vals.ndim == 3
+            else (panels.panel_rows,))
+    return _const_bytes(*meta, panels.lane_cols,
                         _packed_values(panels) if vals is None else None)
 
 
@@ -444,28 +474,24 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
         b3, batch = flatten_batch(b)
         b3p = _pad_flat_batch(b3)
         nb = int(b3p.shape[0]) if b3p.ndim == 3 else 1
-        depth = int(pipeline_depth) if panels is not None else 1
-        extra = _panel_note_fields(
-            part="bcsr", depth=depth, npanels=int(panels.npanels), nb=nb,
-            n=int(b.shape[-1]), bn=bn, g=int(panels.g), br=int(panels.br),
-            b_dtype=b.dtype, value_dtype=v.dtype) if panels is not None else {}
-        _note("bcsr", "spmm", backend=bk,
-              impl="panels" if panels is not None else "flat",
-              units=int(panels.npanels) if panels is not None
-              else int(bcsr.ntiles),
-              batch=nb, n=int(b.shape[-1]),
-              const_bytes=_panel_const_bytes(panels, vals)
-              if panels is not None else _const_bytes(*flat_arrays),
-              **extra)
         if panels is not None:
+            _note("bcsr", "spmm", backend=bk, impl="panels", batch=nb,
+                  n=int(b.shape[-1]),
+                  const_bytes=_panel_const_bytes(panels, vals),
+                  **_bcsr_note_fields(panels, nb=nb, n=int(b.shape[-1]),
+                                      bn=bn, value_dtype=v.dtype))
             padded = get_kernel("bcsr", "spmm", "panels")(
-                jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
+                panels.panel_rows, jnp.asarray(panels.lane_cols),
                 panel_values(panels, vals), b3p, g=panels.g,
                 nblocks=panels.nblocks, bn=bn, out_dtype=out_dtype,
-                interpret=interpret, pipeline_depth=depth)
+                interpret=interpret, pipeline_depth=pipeline_depth,
+                steps=panels.steps)
         else:
+            _note("bcsr", "spmm", backend=bk, impl="flat",
+                  units=int(bcsr.ntiles), batch=nb, n=int(b.shape[-1]),
+                  const_bytes=_const_bytes(*flat_arrays))
             padded = get_kernel("bcsr", "spmm", "flat")(
-                jnp.asarray(bcsr.tile_rows), jnp.asarray(bcsr.tile_cols), v,
+                bcsr.tile_rows, jnp.asarray(bcsr.tile_cols), v,
                 b3p, nblocks=bcsr.nblocks, bn=bn, out_dtype=out_dtype,
                 interpret=interpret)
         if b3p is not b3:
@@ -524,12 +550,10 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
                   n=int(b.shape[-1]), bn=bn, g=int(cp.g), br=1,
                   b_dtype=b.dtype, value_dtype=vdt))
         _note("bcsr", "spmm", backend=bk, impl="panels", fused=True,
-              units=int(bp.npanels), batch=nb, n=int(b.shape[-1]),
+              batch=nb, n=int(b.shape[-1]),
               const_bytes=_panel_const_bytes(bp, bcsr_vals),
-              **_panel_note_fields(
-                  part="bcsr", depth=depth, npanels=int(bp.npanels), nb=nb,
-                  n=int(b.shape[-1]), bn=bn, g=int(bp.g), br=int(bp.br),
-                  b_dtype=b.dtype, value_dtype=vdt))
+              **_bcsr_note_fields(bp, nb=nb, n=int(b.shape[-1]), bn=bn,
+                                  value_dtype=vdt))
         r_pad = r_b + bp.nblocks * br
         out = get_kernel("csr", "spmm", "panels")(
             jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols),
@@ -537,11 +561,11 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
             out_rows=r_pad, bn=bn, out_dtype=out_dtype,
             interpret=interpret, pipeline_depth=depth)
         out = get_kernel("bcsr", "spmm", "panels")(
-            jnp.asarray(bp.panel_rows), jnp.asarray(bp.lane_cols),
+            bp.panel_rows, jnp.asarray(bp.lane_cols),
             panel_values(bp, bcsr_vals), b3p, g=bp.g, nblocks=bp.nblocks,
             row_block_offset=r_b // br, out_rows=r_pad, bn=bn,
             out_dtype=out_dtype, interpret=interpret, carry=out,
-            pipeline_depth=depth)
+            steps=bp.steps)
         if b3p is not b3:
             out = out[:b3.shape[0]]
         if r_pad != fmt.nrows:

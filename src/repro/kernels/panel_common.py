@@ -1,14 +1,19 @@
 """Shared scaffolding for the G-wide panel kernels.
 
-Both panel kernels (``csr_spmm``, ``bcsr_spmm``) speak the same operand
-protocol: scalar-prefetched ``(prev_row, panel_rows, signed panel_cols[,
-panel_vals])``, then the tensor train ``[vals_window?, carry?, B x G]``,
-then outputs and scratch.  The operand ORDER is load-bearing —
-``input_output_aliases`` is positional — so it is defined here exactly once
-and both kernels assemble their specs and unpack their refs through these
-helpers.  ``panel_cols`` arrive flattened (lane ``i`` of panel ``p`` at
-``p * G + i``) with padding lanes set to -1 (:func:`signed_cols`): the
-mask rides in the column index, and gathers clamp it to row 0.
+The CSR SpMM panel kernel (``csr_spmm``) speaks one operand protocol:
+scalar-prefetched ``(prev_row, panel_rows, signed panel_cols,
+panel_vals)``, then the tensor train ``[carry?, B x G]``, then outputs and
+scratch.  The operand ORDER is load-bearing — ``input_output_aliases`` is
+positional — so it is defined here exactly once and the kernel assembles
+its specs and unpacks its refs through these helpers.  ``panel_cols``
+arrive flattened (lane ``i`` of panel ``p`` at ``p * G + i``) with padding
+lanes set to -1 (:func:`lane_cols`): the mask rides in the column index,
+and gathers clamp it to row 0.
+
+The BCSR SpMM kernel (``bcsr_spmm``) walks window steps instead
+(:func:`window_steps`) and gathers B rows by its own DMAs; it shares the
+layouts here (:func:`lane_cols`, :func:`values_window`) and the revisit
+predicates; the SDD kernels share the gathers and the chunk bound.
 
 TPU tiling: the last two dimensions of every block must be divisible by
 (8, 128) or equal the array's own, and HBM stores every array in such tiles.
@@ -18,8 +23,8 @@ dimensions ``(1, N)`` the ``(1, bn)`` block matches; the leading row
 dimension is squeezed (``None``) so kernels still see ``(1, bn)`` refs.
 Per-panel values would pad each ``(1, G)`` or ``(Br, G)`` panel to a whole
 tile, so they travel lane-dense instead: the CSR part's as SMEM scalars,
-the BCSR part's as a ``(Br, P·G)`` array read one ``(Br, W)`` window
-(``W = lcm(G, 128)``) per ``W / G`` panels (:func:`values_window`).
+the BCSR part's as a ``(Br, P·G)`` array read one 128-lane window per step
+(:func:`values_window`).
 
 Chunked metadata: the scalar-prefetched metadata lives in SMEM, which holds
 about 1 MiB per ``pallas_call``.  :func:`run_panel_chunks` splits the panel
@@ -61,13 +66,14 @@ __all__ = ["CARRY_OPERAND_INDEX", "CSR_WORDS", "PIPELINE_DEPTHS",
            "check_pipeline_depth", "default_bn", "dot_precision",
            "first_last", "first_last_at", "gather_col", "grid_dims",
            "init_acc", "panel_calls", "panel_operands", "panels_per_call",
-           "lane_cols", "pad_window", "parity", "pipeline_index", "row_view",
+           "lane_cols", "parity", "pipeline_index", "row_view",
            "run_panel_chunks", "split_panel_refs", "values_window",
-           "window_lanes", "window_panel", "LANES"]
+           "window_lanes", "window_steps", "block_row_steps", "gather_bn",
+           "LANES"]
 
-# Position of the fused-path carry among ALL pallas_call operands (scalar
-# prefetch included): CSR prev_row(0), rows(1), cols(2), vals(3), carry(4);
-# BCSR prev_row(0), rows(1), cols(2), vals_window(3), carry(4).
+# Position of the fused-path carry among ALL pallas_call operands of the CSR
+# kernel (scalar prefetch included): prev_row(0), rows(1), cols(2), vals(3),
+# carry(4).
 CARRY_OPERAND_INDEX = 4
 
 # Supported software-pipeline depths: 1 = today's serial gather->contract
@@ -166,26 +172,48 @@ def values_window(vals):
     return xp.pad(flat, ((0, 0), (0, pad))) if pad else flat
 
 
+def window_steps(panel_rows, g: int):
+    """The BCSR kernel's step table: ``(step_rows (T,), step_starts (T+1,))``
+    int32 host arrays.
+
+    One step is one aligned :data:`LANES`-lane window of the values
+    window intersected with one block row: a block row's panels are
+    contiguous in lane order, so its lanes ``[first·G, (last+1)·G)`` split
+    at every window boundary.  Steps follow lane order (so ``step_rows``
+    is nondecreasing); step ``k`` covers lanes ``[step_starts[k],
+    step_starts[k+1])``, all in window ``step_starts[k] // LANES``, and
+    ``step_starts[T]`` is the lane count ``P·G``."""
+    rows = np.asarray(panel_rows).astype(np.int64)
+    lanes = rows.shape[0] * int(g)
+    if not lanes:
+        return np.zeros(0, np.int32), np.zeros(1, np.int32)
+    opens = np.flatnonzero(np.r_[True, rows[1:] != rows[:-1]]) * int(g)
+    starts = np.union1d(opens, np.arange(0, lanes, LANES))
+    return (rows[starts // int(g)].astype(np.int32),
+            np.append(starts, lanes).astype(np.int32))
+
+
+def block_row_steps(lanes_per_row) -> int:
+    """Steps of :func:`window_steps` from the lanes of each block row (in
+    panel order) alone: each block row takes one step, plus one for every
+    window boundary inside it."""
+    lanes = np.asarray(lanes_per_row, np.int64)
+    ends = np.cumsum(lanes)
+    return int(((ends - 1) // LANES - (ends - lanes) // LANES + 1).sum())
+
+
+def gather_bn(n: int, bn: int | None = None) -> int:
+    """Column-block width of the BCSR kernel, whose gathers copy whole
+    rows: ``bn`` (default :func:`default_bn`) where it and ``n`` are
+    lane-aligned, else all of ``n`` in one block."""
+    bn = bn or default_bn(n)
+    return bn if n % LANES == 0 and bn % LANES == 0 else int(n)
+
+
 def gather_col(cols, lane):
     """Row of B that lane ``lane`` gathers (padding lanes, at column -1,
     read row 0)."""
     return jnp.maximum(cols[lane], 0)
-
-
-def window_panel(window, c, g: int):
-    """Panel ``c``'s ``(Br, G)`` values out of its ``(Br, W)`` window.
-
-    Mosaic has no dynamic lane slice, so each of the G columns is a masked
-    lane reduction of the window: exactly one lane survives the mask, so
-    the result is exact in any dtype (half precision selects in fp32; v5e's
-    VPU has no bf16 arithmetic)."""
-    w = window.shape[-1]
-    off = jax.lax.rem(c, jnp.asarray(w // g, c.dtype)) * g
-    sel = (jax.lax.broadcasted_iota(jnp.int32, (1, g, w), 2)
-           == off + jax.lax.broadcasted_iota(jnp.int32, (1, g, w), 1))
-    wide = window.astype(jnp.promote_types(window.dtype, jnp.float32))
-    picked = jnp.where(sel, wide[:, None, :], jnp.zeros_like(wide)[:, None, :])
-    return jnp.sum(picked, axis=-1).astype(window.dtype)
 
 
 def row_view(x, axis: int):
@@ -279,8 +307,7 @@ def split_panel_refs(refs, g: int, n_scalar: int, n_lead: int,
                      has_carry: bool):
     """Unpack a panel kernel's ref train into ``(scalars, lead, carry,
     b_refs, tail)``: the ``n_scalar`` scalar-prefetch refs, the
-    ``n_lead`` tensor refs before the carry (the BCSR values window),
-    the carry (None without one), the G gathered B rows and the
+    ``n_lead`` tensor refs before the carry, the carry (None without one), the G gathered B rows and the
     kernel-specific (outputs + scratch) remainder.  The carry is read only
     to resume a row that spans two chunks (:func:`init_acc`); aliasing
     preserves every other row."""
@@ -293,8 +320,7 @@ def split_panel_refs(refs, g: int, n_scalar: int, n_lead: int,
 
 def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
                    row_map=None, bz: int | None = None,
-                   pipeline_depth: int = 1, npanels: int | None = None,
-                   window=None):
+                   pipeline_depth: int = 1, npanels: int | None = None):
     """Assemble the tensor-operand train shared by both panel kernels.
 
     Index maps receive the grid indices, then the scalar-prefetch refs
@@ -312,12 +338,9 @@ def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
                    ``lidx(k)``) ``depth - 1`` steps ahead of the compute
                    stream (values window + carry, indexed at ``cidx(k)``).
                    ``depth=1`` keeps both at ``k`` — today's layout.
-      window:      the BCSR part's ``(Br, L)`` lane-dense values
-                   (:func:`values_window`), read one ``(Br, W)`` window per
-                   ``W / G`` panels; None for the CSR part (values in SMEM).
 
-    Returns ``(in_specs, args, input_output_aliases)``: the values window
-    if any, the optional aliased carry, then G gathers of ``b`` indexed by
+    Returns ``(in_specs, args, input_output_aliases)``: the optional
+    aliased carry, then G gathers of ``b`` indexed by
     the scalar-prefetched, signed ``panel_cols`` — one DMA stream per panel
     lane, ``bz`` batch slices wide when batched.
     """
@@ -334,11 +357,6 @@ def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
             return pl.BlockSpec(block, lambda z, j, k, *s: index(z, j, k, s))
 
     in_specs, args, aliases = [], [], {}
-    if window is not None:
-        br, w = window.shape[0], window_lanes(g)
-        in_specs.append(spec((br, w), lambda z, j, k, s:
-                             (0, cidx(k) // (w // g))))
-        args.append(window)
     if carry is not None:
         lead = () if bz is None else (bz,)
         in_specs.append(spec(lead + tuple(carry_block), lambda z, j, k, s:
@@ -354,19 +372,13 @@ def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
     return in_specs, args, aliases
 
 
-def pad_window(win, g: int):
-    """Right-pad a ``(Br, n)`` slice of a values window to whole windows."""
-    pad = -win.shape[-1] % window_lanes(g)
-    return jnp.pad(win, ((0, 0), (0, pad))) if pad else win
-
-
 def run_panel_chunks(call, rows, lanes, *, g: int, per_call: int,
                      carry=None, out_shape=None):
     """Run a panel kernel over its panel axis in SMEM-sized chunks.
 
     ``rows`` is ``panel_rows`` (P,); ``lanes`` are ``(array, axis)`` pairs
-    whose ``axis`` holds G entries per panel (the flat columns and values,
-    the BCSR values window — padded beyond P·G).  ``call(prev_row, rows,
+    whose ``axis`` holds G entries per panel (the flat columns and
+    values).  ``call(prev_row, rows,
     *lane_arrays, carry)`` launches one ``pallas_call`` over a chunk and
     returns the full output.  Up to ``per_call`` panels run as one launch
     exactly as before (no carry unless the caller passed one).  Beyond

@@ -7,7 +7,8 @@ owns the built-in instrumentation seams:
   * :meth:`Obs.attach_engine` — hooks the execution engine's dispatch
     tracer (``repro.kernels.engine.set_tracer``) and turns every kernel
     dispatch into ``engine.dispatch`` counters (labeled ``part``/``op``/
-    ``backend``/``impl``), ``engine.grid_steps_compiled`` and
+    ``backend``/``impl``), ``engine.grid_steps_compiled``,
+    ``engine.gathers`` (B rows the BCSR kernel's steps fetch) and
     ``engine.const_bytes`` (host arrays turned into program constants)
     counters.  Dispatches fire at *trace* time — once per jit compilation
     — so these are compilation-workload counters, deliberately not
@@ -235,6 +236,9 @@ class _EngineTracer:
         cb = fields.get("const_bytes")
         if cb:
             m.counter("engine.const_bytes", part=part).inc(float(cb))
+        gathers = fields.get("gathers")
+        if gathers is not None:
+            m.counter("engine.gathers", part=part).inc(float(gathers))
         sb = fields.get("scratch_bytes")
         if sb is not None:
             m.gauge("kernel.scratch_bytes", part=part, op=op).set(float(sb))

@@ -30,7 +30,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..kernels.panel_common import CSR_WORDS, default_bn, panel_calls
+from ..kernels.panel_common import (CSR_WORDS, block_row_steps, default_bn,
+                                   gather_bn, panel_calls)
 from .trace import fit_cost_model, load_traces
 
 __all__ = ["predict_grid_steps", "predict_part_steps", "TraceDB", "replay"]
@@ -47,18 +48,20 @@ def predict_part_steps(csr, plan, n_cols: int,
       * CSR-part: rows ``[0, r_b)`` contribute ``max(ceil(c_i / g), 1)``
         panels each (``csr_slice_rows`` pads empty rows, ``panelize_csr``
         floors at one panel per row);
-      * BCSR-part: block-rows of ``br`` rows contribute
-        ``max(ceil(u_b / g), 1)`` panels, where ``u_b`` counts distinct
-        columns among *nonzero-valued* entries in the block
-        (``bcsr_from_csr_rows`` drops zero-valued structural pads and keeps
-        ≥ 1 pad tile per empty block-row);
+      * BCSR-part: block-rows of ``br`` rows take ``max(ceil(u_b / g),
+        1)`` panels, where ``u_b`` counts distinct columns among
+        *nonzero-valued* entries in the block (``bcsr_from_csr_rows`` drops
+        zero-valued structural pads and keeps ≥ 1 pad tile per empty
+        block-row); their lanes give the kernel's window steps
+        (``panel_common.block_row_steps``), with no ramp, once per column
+        block of ``panel_common.gather_bn``;
       * a part the executor skips entirely (``r_b == 0`` / ``r_b == nrows``)
         contributes zero;
       * ``macro_m > 1`` panelizes at the effective width ``panel_g·macro_m``
         and ``pipeline_depth = d`` adds ``d - 1`` ramp steps per
-        ``pallas_call`` (SMEM-sized panel chunk) of each non-empty part,
+        ``pallas_call`` (SMEM-sized panel chunk) of a non-empty CSR part,
         exactly like the conversion;
-      * both counts scale by ``ceil(n_cols / bn)`` column blocks
+      * the CSR count scales by ``ceil(n_cols / bn)`` column blocks
         (``bn`` defaults to ``panel_common.default_bn(n_cols)`` like the
         executor).
     """
@@ -79,9 +82,9 @@ def predict_part_steps(csr, plan, n_cols: int,
         c = counts[:r_b]
         p_csr = int(np.maximum(-(-c // g), 1).sum())
 
-    # BCSR-part panels over rows [r_b, n).
+    # BCSR-part window steps over rows [r_b, n).
     if r_b >= n:
-        p_bcsr = 0
+        w_bcsr = 0
     else:
         s, e = int(csr.row_ptr[r_b]), int(csr.row_ptr[n])
         rows = csr.row_ids[s:e].astype(np.int64) - r_b
@@ -93,15 +96,12 @@ def predict_part_steps(csr, plan, n_cols: int,
         lin = np.unique(blocks * int(csr.ncols) + cols[nzmask])
         tiles_per_block = np.bincount((lin // int(csr.ncols)).astype(np.int64),
                                       minlength=nblocks)
-        p_bcsr = int(np.maximum(-(-tiles_per_block // g), 1).sum())
+        w_bcsr = block_row_steps(np.maximum(-(-tiles_per_block // g), 1) * g)
 
-    def _steps(p, words):   # one ramp per SMEM-sized chunk launch
-        if not p:
-            return 0
-        return (p + panel_calls(p, g, words) * (depth - 1)) * col_blocks
-
-    s_csr, s_bcsr = _steps(p_csr, CSR_WORDS), _steps(p_bcsr, 1)
-    return s_csr, s_bcsr
+    # One ramp per SMEM-sized chunk launch of the CSR part.
+    s_csr = ((p_csr + panel_calls(p_csr, g, CSR_WORDS) * (depth - 1))
+             * col_blocks if p_csr else 0)
+    return s_csr, w_bcsr * (int(n_cols) // gather_bn(int(n_cols), bn))
 
 
 def predict_grid_steps(csr, plan, n_cols: int, bn: int | None = None) -> int:

@@ -305,22 +305,25 @@ def test_csr_chunks_resume_rows_across_seams(rng, depth, batched):
 def test_bcsr_chunks_with_carry_keep_foreign_rows(rng, depth):
     """The fused path's carry across chunk launches: rows the kernel never
     visits keep the carry's values, visited block-rows spanning a seam sum
-    every panel."""
-    a = np.zeros((16, 40), np.float32)
-    a[4:8] = rng.standard_normal((4, 40))          # block-row 1: 40 tiles
+    every step.  Block-row 1 (300 tiles) spans three 128-lane windows, so
+    at 1 or 2 steps per call both its window seams are call seams."""
+    a = np.zeros((16, 300), np.float32)
+    a[4:8] = rng.standard_normal((4, 300))         # block-row 1: 300 tiles
     a[0, 3], a[13, 7] = 1.0, -2.0
     fmt = loops_from_csr(csr_from_dense(a), 0, 4, panel_g=4)
     p = fmt.bcsr_panels
-    b = jnp.asarray(rng.standard_normal((40, 8)).astype(np.float32))
+    assert p.steps[0].tolist() == [0, 1, 1, 1, 2, 3]
+    b = jnp.asarray(rng.standard_normal((300, 8)).astype(np.float32))
     sentinel = jnp.full((24, 8), 7.0, jnp.float32)  # rows 16..23: foreign
-    got = bcsr_panels_spmm_pallas(
-        jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
-        jnp.asarray(p.vals_window), b, g=4, nblocks=p.nblocks, out_rows=24,
-        carry=sentinel, interpret=True, pipeline_depth=depth,
-        panels_per_call=5)
-    np.testing.assert_allclose(np.asarray(got)[:16], a @ np.asarray(b),
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(got)[16:], 7.0)
+    for per_call in (1, 2, 4):
+        got = bcsr_panels_spmm_pallas(
+            p.panel_rows, jnp.asarray(p.lane_cols),
+            jnp.asarray(p.vals_window), b, g=4, nblocks=p.nblocks,
+            out_rows=24, carry=sentinel, interpret=True,
+            pipeline_depth=depth, per_call=per_call)
+        np.testing.assert_allclose(np.asarray(got)[:16], a @ np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(got)[16:], 7.0)
 
 
 @pytest.mark.parametrize("part", ["csr", "bcsr"])

@@ -523,7 +523,7 @@ def test_const_bytes_are_the_panel_arrays(rng):
     assert got == {
         "csr": cp.panel_rows.nbytes + cp.lane_cols.nbytes
         + cp.lane_vals.nbytes,
-        "bcsr": bp.panel_rows.nbytes + bp.lane_cols.nbytes
+        "bcsr": sum(a.nbytes for a in bp.steps) + bp.lane_cols.nbytes
         + bp.vals_window.nbytes}
 
 

@@ -9,9 +9,9 @@ The contracts under test (docs/architecture.md §"Pipelined panels"):
     (XLA contracts multiply-adds differently across the two graphs);
   * ``macro_m`` panelizes at the effective width ``panel_g·macro_m`` and
     agrees with the oracle to dtype tolerance;
-  * grid steps = ``(panels_at_g_eff + depth - 1) × col_blocks`` per
-    non-empty part, and ``perf.replay.predict_part_steps`` replicates the
-    conversion exactly;
+  * grid steps = ``(panels_at_g_eff + depth - 1) × col_blocks`` for the
+    CSR part plus the BCSR part's window steps, and
+    ``perf.replay.predict_part_steps`` replicates the conversion exactly;
   * ``default_bn`` picks the largest lane-aligned divisor ≤ 512 (the
     ``N=600`` ValueError regression);
   * plans round-trip the knobs through the v4 tuner cache, and dispatch
@@ -202,15 +202,16 @@ def test_wide_operand_n600_regression(rng):
 # -- grid-step accounting ---------------------------------------------------
 
 def test_grid_steps_ramp_and_macro(rng):
-    """Steps = (panels_at_g_eff + depth - 1) x col_blocks per non-empty
-    part; macro_m shrinks the panel count, depth adds the ramp."""
+    """Steps = (panels_at_g_eff + depth - 1) x col_blocks for the CSR part
+    plus the BCSR part's window steps, which have no ramp; macro_m shrinks
+    the panel count, depth adds the CSR ramp."""
     a = _sparse(rng, 24, 20, 0.4, jnp.float32)
     csr = csr_from_dense(a)
     base = loops_grid_steps(_fmt(csr, 4, 1, 1), 16)
     fused = loops_grid_steps(_fmt(csr, 4, 1, 4), 16)
     piped = loops_grid_steps(_fmt(csr, 4, 2, 1), 16)
     assert fused < base                  # macro fusion shrinks the grid
-    assert piped == base + 2             # one ramp step per non-empty part
+    assert piped == base + 1             # one ramp step, the CSR part's
 
 
 @pytest.mark.parametrize("depth", [1, 2])
@@ -291,15 +292,17 @@ def test_obs_gauges_scratch_and_overlap(rng):
     ov = [r for r in recs if r.get("metric") == "engine.prefetch_overlap"]
     assert sb and all(r["value"] > 0 for r in sb)
     assert ov and any(r["value"] > 0 for r in ov)   # depth 2 => overlap
-    # serial execution reports zero overlap
+    # serial execution reports zero overlap for the CSR part; the BCSR
+    # kernel prefetches the next step's rows at every depth
     obs2 = Obs(source="pipeline-test-serial")
     fmt1 = _fmt(csr_from_dense(a), 4, 1, 1)
     with obs2.attach_engine():
         loops_spmm(fmt1, jnp.ones((20, 16), jnp.float32),
                    backend="interpret")
-    ov1 = [r for r in obs2.records()
-           if r.get("metric") == "engine.prefetch_overlap"]
-    assert ov1 and all(r["value"] == 0.0 for r in ov1)
+    ov1 = {r["labels"]["part"]: r["value"] for r in obs2.records()
+           if r.get("metric") == "engine.prefetch_overlap"}
+    assert ov1["csr"] == 0.0
+    assert 0.0 <= ov1["bcsr"] < 1.0
 
 
 def test_packed_halfprec_scratch_and_accumulate(rng):

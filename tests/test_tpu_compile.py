@@ -23,9 +23,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.formats import csr_from_dense
 from repro.core.spmm import loops_spmm, plan_and_convert
-from repro.kernels.bcsr_spmm import bcsr_panels_spmm_pallas
+from repro.kernels.bcsr_spmm import bcsr_panels_spmm_pallas, steps_per_call
 from repro.kernels.csr_spmm import csr_panels_spmm_pallas
-from repro.kernels.panel_common import panels_per_call
+from repro.kernels.panel_common import panels_per_call, window_steps
 from repro.kernels.spmm_sdd import (bcsr_sdd_panels_pallas,
                                     csr_sdd_panels_pallas)
 
@@ -81,14 +81,22 @@ def _operands(part, op, *, npanels, n, dtype, batch=None):
     return meta + [(lead + (rows, n), dtype), b], br
 
 
-def _kernel(part, op, *, br, depth, nrows=M):
+def _block_rows(npanels, nblocks):
+    """Host ``panel_rows`` spreading ``npanels`` over ``nblocks`` block
+    rows: the BCSR SpMM kernel builds its step table from them, so they
+    are data, not an operand."""
+    return (np.arange(npanels) * nblocks // npanels).astype(np.int32)
+
+
+def _kernel(part, op, *, br, depth, nrows=M, npanels=P):
     if (part, op) == ("csr", "spmm"):
         return lambda r, c, v, b: csr_panels_spmm_pallas(
             r, c, v, b, g=G, nrows=nrows, interpret=False,
             pipeline_depth=depth)
     if (part, op) == ("bcsr", "spmm"):
+        rows = _block_rows(npanels, nrows // br)
         return lambda r, c, v, b: bcsr_panels_spmm_pallas(
-            r, c, v, b, g=G, nblocks=nrows // br, interpret=False,
+            rows, c, v, b, g=G, nblocks=nrows // br, interpret=False,
             pipeline_depth=depth)
     if (part, op) == ("csr", "sdd"):
         return lambda r, c, dy, b: csr_sdd_panels_pallas(
@@ -116,8 +124,30 @@ def test_chunked_metadata_compiles(one_chip, part):
     npanels = 2 * panels_per_call(G) + 1000
     shapes, br = _operands(part, "spmm", npanels=npanels, n=32,
                            dtype=jnp.float32)
-    hlo = _compile(_kernel(part, "spmm", br=br, depth=1), one_chip, *shapes)
+    hlo = _compile(_kernel(part, "spmm", br=br, depth=1, npanels=npanels),
+                   one_chip, *shapes)
     assert hlo.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("layout", ["chunked", "batched"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("n", [32, 128])
+def test_bcsr_window_steps_compile(one_chip, n, dtype, layout):
+    """The BCSR kernel's window steps and manual DMA gather: B rows from
+    HBM into a two-slot VMEM stage, at N below and at one lane tile, in
+    more calls than one SMEM holds or over a batched operand."""
+    br = 16 if dtype == jnp.bfloat16 else 8
+    npanels = 2 * panels_per_call(G) + 1000 if layout == "chunked" else P
+    rows = _block_rows(npanels, M // br)
+    steps = window_steps(rows, G)
+    assert len(steps[0]) > steps_per_call(steps[1])[0] or layout != "chunked"
+    shapes, _ = _operands("bcsr", "spmm", npanels=npanels, n=n, dtype=dtype,
+                          batch=4 if layout == "batched" else None)
+    hlo = _compile(lambda r, c, v, b: bcsr_panels_spmm_pallas(
+        rows, c, v, b, g=G, nblocks=M // br, interpret=False, steps=steps),
+        one_chip, *shapes)
+    assert hlo.count("tpu_custom_call") >= (2 if layout == "chunked" else 1)
 
 
 @pytest.mark.parametrize("op", ["spmm", "sdd"])
