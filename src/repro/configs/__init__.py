@@ -1,13 +1,15 @@
-"""Architecture registry: one module per assigned architecture.
+"""Architecture registry: one module per assigned architecture, and the
+full-graph GNN configurations (``gcn_arxiv``; not among ``ALL_ARCHS``, the
+language models the train, serve and dry-run launchers take).
 
 ``get_config(name)`` / ``--arch <id>`` resolve through here; each module also
 provides ``reduced()`` — the same family at smoke-test scale.
 """
-from .base import (SHAPES, ModelConfig, ShapeConfig, applicable_shapes,
-                   get_config, list_configs, register)
-from . import (granite_34b, hymba_1_5b, internlm2_20b, llama3_2_1b,
-               phi3_vision_4_2b, qwen2_moe_a2_7b, qwen3_32b, qwen3_moe_30b,
-               rwkv6_3b, whisper_small)
+from .base import (SHAPES, GNNConfig, ModelConfig, ShapeConfig,
+                   applicable_shapes, get_config, list_configs, register)
+from . import (gcn_arxiv, granite_34b, hymba_1_5b, internlm2_20b,
+               llama3_2_1b, phi3_vision_4_2b, qwen2_moe_a2_7b, qwen3_32b,
+               qwen3_moe_30b, rwkv6_3b, whisper_small)
 
 ALL_ARCHS = (
     "qwen3-moe-30b-a3b", "qwen2-moe-a2.7b", "qwen3-32b", "granite-34b",
@@ -28,5 +30,6 @@ REDUCED = {
     "hymba-1.5b": hymba_1_5b.reduced,
 }
 
-__all__ = ["ALL_ARCHS", "REDUCED", "SHAPES", "ModelConfig", "ShapeConfig",
-           "applicable_shapes", "get_config", "list_configs", "register"]
+__all__ = ["ALL_ARCHS", "REDUCED", "SHAPES", "GNNConfig", "ModelConfig",
+           "ShapeConfig", "applicable_shapes", "get_config", "list_configs",
+           "register"]

@@ -6,8 +6,8 @@ from typing import Any, Dict, Tuple
 
 import jax.numpy as jnp
 
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "register", "get_config",
-           "list_configs"]
+__all__ = ["ModelConfig", "GNNConfig", "ShapeConfig", "SHAPES", "register",
+           "get_config", "list_configs"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -80,6 +80,27 @@ class ModelConfig:
     @property
     def rwkv_heads(self) -> int:
         return self.d_model // self.rwkv_head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class GNNConfig:
+    """Full-graph GNN description (family ``gnn``): ``num_layers`` graph
+    convolutions ``in_dim -> hidden -> ... -> num_classes``, each hidden one
+    followed by batch norm, ReLU and dropout, trained by Adam at ``lr``.
+    ``precision`` is the dense matmuls' (fp32 throughout)."""
+
+    name: str
+    num_layers: int
+    in_dim: int
+    hidden: int
+    num_classes: int
+    dropout: float = 0.5
+    bn_eps: float = 1e-5
+    lr: float = 0.01
+    weight_decay: float = 0.0
+    precision: str = "highest"
+    family: str = "gnn"
+    notes: str = ""
 
 
 @dataclasses.dataclass(frozen=True)

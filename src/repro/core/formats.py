@@ -29,7 +29,7 @@ from typing import Tuple
 
 import numpy as np
 
-from ..obs.runtime import span
+from ..obs.runtime import count, span
 
 __all__ = [
     "CSR",
@@ -116,6 +116,9 @@ class VectorBCSR:
     br: int                # tile height (paper: cntd / cntf / cnth)
     nrows: int             # logical row count covered (<= nblocks * br)
     shape: Tuple[int, int]  # (nrows, ncols)
+    # (ntiles, Br) bool: the slots a stored entry of the source landed in;
+    # the rest of a tile is padding.
+    stored: np.ndarray
 
     @property
     def nblocks(self) -> int:
@@ -343,28 +346,44 @@ class LoopsFormat:
             self, csr_part=self.csr_part.astype(dtype),
             bcsr_part=self.bcsr_part.astype(dtype))
 
-    def transposed(self, *, plan=None, tuner=None,
-                   total_workers: int = 8) -> "TransposedLoops":
+    def transposed(self, *, plan=None, tuner=None, total_workers: int = 8,
+                   live_values: bool = False) -> "TransposedLoops":
         """Aᵀ as a LOOPS format plus the value-linear maps from A's stored
         values — the backward-pass operand of the custom VJP (``dB = Aᵀ·dY``
         runs through the same panel kernels, just on this format).
+
+        Aᵀ holds A's stored entries and none of its tile padding.
+        ``live_values`` is for trainable values
+        (:func:`repro.core.spmm.loops_spmm_values`): every tile slot is then
+        a parameter that training may move off zero, so Aᵀ keeps every slot
+        of A's rows.
 
         ``plan`` pins the transposed execution plan (a
         :class:`repro.core.spmm.SpmmPlan`); otherwise it is resolved through
         ``tuner`` (the measured plan cache) or the model-only
         ``plan_and_convert`` with ``total_workers``.  The result is cached on
-        this instance per ``(plan, tuner, total_workers)``, so repeated
-        backward passes — every training step — pay the O(nnz) transpose
-        conversion exactly once.
+        this instance per ``(plan, tuner, total_workers, live_values)``, and
+        under the plan it resolved to, which a later call may pin; so
+        repeated backward passes — every training step — pay the O(nnz)
+        transpose conversion exactly once.  The build runs under the program
+        span ``loops.transpose`` and adds Aᵀ's stored entries to the counter
+        ``loops.transpose_entries``.
         """
-        key = (plan, id(tuner) if tuner is not None else None, total_workers)
+        key = (plan, id(tuner) if tuner is not None else None, total_workers,
+               live_values)
         cache = self.__dict__.setdefault("_transposed_cache", {})
         if key not in cache:
             # The entry pins the tuner: id() is only a safe key while the
             # object is alive (a freed address can be recycled by a new
             # tuner, which must not hit this entry).
-            cache[key] = (tuner, _build_transposed(
-                self, plan=plan, tuner=tuner, total_workers=total_workers))
+            with span("loops.transpose"):
+                tl = _build_transposed(self, plan=plan, tuner=tuner,
+                                       total_workers=total_workers,
+                                       live_values=live_values)
+            count("loops.transpose_entries", tl.n_slots)
+            cache[key] = (tuner, tl)
+            cache.setdefault((tl.plan, None, total_workers, live_values),
+                             (None, tl))
         return cache[key][1]
 
 
@@ -522,12 +541,15 @@ def bcsr_from_csr_rows(csr: CSR, start: int, stop: int, br: int, *,
     tile_vals = np.zeros((ntiles, br), csr.vals.dtype)
     # Duplicate (row, col) entries sum into one slot, in entry order.
     np.add.at(tile_vals, (tile_of, off), vals[keep])
+    stored = np.zeros((ntiles, br), bool)
+    stored[tile_of, off] = True
     counts = np.bincount(tile_rows, minlength=nblocks)
     block_ptr = np.zeros(nblocks + 1, np.int32)
     np.cumsum(counts, out=block_ptr[1:])
     bcsr = VectorBCSR(tile_rows=tile_rows, tile_cols=tile_cols,
                       tile_vals=tile_vals, block_ptr=block_ptr, br=br,
-                      nrows=nrows, shape=(nrows, csr.shape[1]))
+                      nrows=nrows, shape=(nrows, csr.shape[1]),
+                      stored=stored)
     if not return_map:
         return bcsr
     slot_map = np.full(e - s, -1, np.int64)
@@ -683,7 +705,9 @@ class TransposedLoops:
     see :func:`transposed_values`.  A's "flat value vector" is
     ``concat(csr_part.vals, bcsr_part.tile_vals.ravel())``; BCSR tile slots
     on padding rows (``row >= nrows``) are excluded (the forward pass trims
-    those rows, so they carry no gradient and contribute nothing to Aᵀ).
+    those rows, so they carry no gradient and contribute nothing to Aᵀ), and
+    so are the slots no stored entry of A landed in, unless the format was
+    built for live values (:meth:`LoopsFormat.transposed`).
     """
 
     fmt: LoopsFormat        # Aᵀ, converted under the resolved plan
@@ -726,12 +750,18 @@ def loops_from_csr_mapped(csr: CSR, r_boundary: int, br: int,
     return fmt, csr_len, bcsr_slot
 
 
-def _transposed_csr(fmt: LoopsFormat) -> Tuple[CSR, np.ndarray, np.ndarray]:
+def _transposed_csr(fmt: LoopsFormat, live_values: bool = False
+                    ) -> Tuple[CSR, np.ndarray, np.ndarray]:
     """Aᵀ as a (row, col)-sorted CSR with *every* row populated, plus the
     entry maps ``(csr_t, entry_src, entry_slot)``: A's flat stored entry
     ``entry_src[e]`` contributes (additively — duplicate coordinates
     coalesce) to ``csr_t.vals[entry_slot[e]]``.  Empty rows of Aᵀ get an
     explicit zero pad at column 0 with no source entry.
+
+    A's entries are its CSR part's and the BCSR tile slots a stored entry
+    landed in (``VectorBCSR.stored``), explicit zeros included; the other
+    slots of a tile are padding and stay out, unless ``live_values`` keeps
+    every slot.
     """
     csr, bc = fmt.csr_part, fmt.bcsr_part
     m, k = fmt.shape
@@ -744,6 +774,8 @@ def _transposed_csr(fmt: LoopsFormat) -> Tuple[CSR, np.ndarray, np.ndarray]:
     cols = np.concatenate([csr.col_idx.astype(np.int64),
                            np.repeat(bc.tile_cols.astype(np.int64), br)])
     keep = rows < m          # BCSR padding rows never reach the output
+    if not live_values:
+        keep &= np.concatenate([np.ones(csr.nnz, bool), bc.stored.ravel()])
     entry_src = np.nonzero(keep)[0].astype(np.int64)
     # Transposed coordinate, linearised in Aᵀ's (row, col) = (col, row) order.
     lin = cols[keep] * m + rows[keep]
@@ -766,14 +798,15 @@ def _transposed_csr(fmt: LoopsFormat) -> Tuple[CSR, np.ndarray, np.ndarray]:
 
 
 def _build_transposed(fmt: LoopsFormat, *, plan=None, tuner=None,
-                      total_workers: int = 8) -> TransposedLoops:
+                      total_workers: int = 8,
+                      live_values: bool = False) -> TransposedLoops:
     """Materialise :class:`TransposedLoops` (cached by
     ``LoopsFormat.transposed``).  Plan resolution goes through the same
     front door as the forward format — ``plan_and_convert`` / the tuner —
     so the backward SpMM is scheduled for Aᵀ's own row statistics, not A's.
     """
     from .spmm import plan_and_convert  # lazy: formats <- spmm at import time
-    csr_t, entry_src, entry_slot = _transposed_csr(fmt)
+    csr_t, entry_src, entry_slot = _transposed_csr(fmt, live_values)
     if plan is None:
         _, plan = plan_and_convert(csr_t, total_workers=total_workers,
                                    panel_g=fmt.panel_g or None, tuner=tuner,

@@ -130,14 +130,17 @@ def plan_and_convert(csr: CSR, *, total_workers: int = 8,
 
 
 def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
-                   out_dtype, csr_vals=None, bcsr_vals=None) -> jax.Array:
+                   out_dtype, csr_vals=None, bcsr_vals=None,
+                   transposed: bool = False) -> jax.Array:
     """Backend dispatch for one hybrid SpMM (no differentiation rule).
 
     ``b`` may carry leading batch dims (the engine folds them into the
     kernels' native batch grid).  ``csr_vals``/``bcsr_vals`` optionally
     substitute traced live values for the format's host-packed constants
     (learned-sparse-weight layers and the transposed backward pass both need
-    this); the structure stays static.
+    this); the structure stays static.  ``transposed`` marks ``fmt`` as a
+    custom VJP's Aᵀ, whose kernels carry the ``_t`` names
+    (``engine.spmm_kernel_name``).
     """
     has_csr = fmt.r_boundary > 0
     has_bcsr = fmt.r_boundary < fmt.nrows
@@ -149,7 +152,7 @@ def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
             return engine.loops_spmm_fused(
                 fmt, b, backend=backend, bn=bn, out_dtype=out_dtype,
                 csr_vals=csr_vals, bcsr_vals=bcsr_vals,
-                pipeline_depth=depth)
+                pipeline_depth=depth, transposed=transposed)
         except Exception as e:   # noqa: BLE001 - the parts path IS the handler
             # The fused chain (pallas → interpret) is exhausted: degrade to
             # the two-pass parts path below, whose per-part chains reach the
@@ -165,12 +168,12 @@ def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
         parts.append(engine.csr_spmm(
             fmt.csr_part, b, backend=backend, bn=bn, out_dtype=out_dtype,
             panels=fmt.csr_panels if pallas else None, vals=csr_vals,
-            pipeline_depth=depth))
+            pipeline_depth=depth, transposed=transposed))
     if has_bcsr:
         parts.append(engine.bcsr_spmm(
             fmt.bcsr_part, b, backend=backend, bn=bn, out_dtype=out_dtype,
             panels=fmt.bcsr_panels if pallas else None, vals=bcsr_vals,
-            pipeline_depth=depth))
+            pipeline_depth=depth, transposed=transposed))
     if not parts:
         _, out = engine.resolve_dtypes(fmt.csr_part.vals.dtype, out_dtype)
         return jnp.zeros(b.shape[:-2] + (fmt.nrows, b.shape[-1]), out)
@@ -182,7 +185,8 @@ def _index_maybe(x, batched: bool, i):
 
 
 def _execute_engine(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
-                    out_dtype, csr_vals=None, bcsr_vals=None) -> jax.Array:
+                    out_dtype, csr_vals=None, bcsr_vals=None,
+                    transposed: bool = False) -> jax.Array:
     """Pallas-path executor with a custom batching rule.
 
     ``jax.vmap`` over the dense operand folds the mapped axis into the
@@ -196,7 +200,8 @@ def _execute_engine(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
     @jax.custom_batching.custom_vmap
     def call(b_, cv, bv):
         return _loops_execute(fmt, b_, backend, bn, out_dtype,
-                              csr_vals=cv, bcsr_vals=bv)
+                              csr_vals=cv, bcsr_vals=bv,
+                              transposed=transposed)
 
     @call.def_vmap
     def _batch_rule(axis_size, in_batched, b_, cv, bv):
@@ -207,12 +212,13 @@ def _execute_engine(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
             outs = [_execute_engine(
                 fmt, _index_maybe(b_, b_batched, i), backend, bn, out_dtype,
                 _index_maybe(cv, any(jax.tree.leaves(in_batched[1])), i),
-                _index_maybe(bv, any(jax.tree.leaves(in_batched[2])), i))
+                _index_maybe(bv, any(jax.tree.leaves(in_batched[2])), i),
+                transposed)
                 for i in range(axis_size)]
             return jnp.stack(outs), True
         lead = b_.shape[:-2]
         out = _execute_engine(fmt, b_.reshape((-1,) + b_.shape[-2:]),
-                              backend, bn, out_dtype, cv, bv)
+                              backend, bn, out_dtype, cv, bv, transposed)
         return out.reshape(lead + out.shape[-2:]), True
 
     return call(b, csr_vals, bcsr_vals)
@@ -225,9 +231,12 @@ def _backward_db(fmt: LoopsFormat, dy: jax.Array, backend: str, bn,
     batch dims.  The cotangent is cast to the format's value dtype first —
     the backward matmuls honour the forward kernels' precision contract
     (bf16 operands, fp32 accumulation) instead of silently running a wider
-    product."""
+    product.  With live values every tile slot of A is a parameter, so Aᵀ
+    keeps them all; with A's own values it holds A's stored entries only
+    (``LoopsFormat.transposed``)."""
     from .formats import transposed_values
-    tl = fmt.transposed(plan=transpose_plan)
+    tl = fmt.transposed(plan=transpose_plan,
+                        live_values=csr_vals is not None)
     dy = dy.astype(tl.fmt.csr_part.vals.dtype)
     cv = bv = None
     if csr_vals is not None:
@@ -235,7 +244,8 @@ def _backward_db(fmt: LoopsFormat, dy: jax.Array, backend: str, bn,
     if backend == "jnp":
         return _loops_execute(tl.fmt, dy, backend, bn, None,
                               csr_vals=cv, bcsr_vals=bv)
-    return _execute_engine(tl.fmt, dy, backend, bn, None, cv, bv)
+    return _execute_engine(tl.fmt, dy, backend, bn, None, cv, bv,
+                           transposed=True)
 
 
 def loops_spmm(fmt: LoopsFormat, b: jax.Array, *, backend: str | None = None,

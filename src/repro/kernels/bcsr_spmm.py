@@ -301,12 +301,12 @@ def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
     jax.jit,
     static_argnames=("nblocks", "row_block_offset", "out_rows", "bn",
                      "out_dtype", "interpret", "per_call", "call_lanes",
-                     "tail_window"))
+                     "tail_window", "name"))
 def _bcsr_steps_spmm(step_rows, step_starts, lane_cols, vals_window, b,
                      carry, *, nblocks: int, interpret: bool,
                      row_block_offset: int, out_rows: int | None,
                      bn: int | None, out_dtype, per_call: int,
-                     call_lanes: int, tail_window: int):
+                     call_lanes: int, tail_window: int, name: str):
     br = vals_window.shape[0]
     n = b.shape[-1]
     bn, width, groups = gather_layout(n, bn)
@@ -372,7 +372,7 @@ def _bcsr_steps_spmm(step_rows, step_starts, lane_cols, vals_window, b,
             out_shape=jax.ShapeDtypeStruct(out_shape, out_dtype),
             input_output_aliases=aliases,
             interpret=interpret,
-            name="loops_bcsr_spmm",
+            name=name,
         )(prev, base, rows, starts, cols, *args)
 
     nsteps = step_rows.shape[0]
@@ -408,7 +408,8 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
                             bn: int | None = None, out_dtype=None,
                             carry: jax.Array | None = None,
                             pipeline_depth: int = 1, steps=None,
-                            per_call: int | None = None) -> jax.Array:
+                            per_call: int | None = None,
+                            name: str = "loops_bcsr_spmm") -> jax.Array:
     """Panelized vector-wise BCSR SpMM over window steps.
 
     Args:
@@ -443,6 +444,8 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
       per_call:   steps per ``pallas_call`` (default
                   :func:`steps_per_call`, the SMEM bound); more steps run
                   as chained calls.
+      name:       the kernel's name, which a device trace shows
+                  (``loops_bcsr_spmm_t`` on a custom VJP's Aᵀ).
     """
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
@@ -458,7 +461,7 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
         jnp.asarray(lane_cols), vals_window, b, carry, nblocks=nblocks,
         interpret=interpret, row_block_offset=row_block_offset,
         out_rows=out_rows, bn=bn, out_dtype=out_dtype, per_call=per,
-        call_lanes=lanes, tail_window=tail // LANES)
+        call_lanes=lanes, tail_window=tail // LANES, name=name)
 
 
 def bcsr_spmm_pallas(tile_rows, tile_cols, tile_vals, b: jax.Array, *,

@@ -171,14 +171,16 @@ def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
 @functools.partial(
     jax.jit,
     static_argnames=("g", "nrows", "out_rows", "bn", "out_dtype",
-                     "interpret", "pipeline_depth", "panels_per_call"))
+                     "interpret", "pipeline_depth", "panels_per_call",
+                     "name"))
 def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                            lane_vals: jax.Array, b: jax.Array, *, g: int,
                            nrows: int, interpret: bool,
                            out_rows: int | None = None, bn: int | None = None,
                            out_dtype=None, carry: jax.Array | None = None,
                            pipeline_depth: int = 1,
-                           panels_per_call: int | None = None) -> jax.Array:
+                           panels_per_call: int | None = None,
+                           name: str = "loops_csr_spmm") -> jax.Array:
     """C[r] += sum_i vals[p,i] * B[cols[p,i], :] over the real lanes of
     every panel p.
 
@@ -216,6 +218,8 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                   ``panel_common.panels_per_call(G, CSR_WORDS)``, the SMEM
                   bound for prefetched columns and values); more panels
                   run as chained chunks.
+      name:       the kernel's name, which a device trace shows
+                  (``loops_csr_spmm_t`` on a custom VJP's Aᵀ).
     """
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
@@ -293,7 +297,7 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                 tuple(out_shape[:-1]) + (1, n), out_dtype),
             input_output_aliases=aliases,
             interpret=interpret,
-            name="loops_csr_spmm",
+            name=name,
         )(prev, rows, cols, vals.astype(acc_dtype), *args)
         return out.reshape(out_shape)
 

@@ -378,9 +378,17 @@ def panel_values(panels, vals):
 # dispatch entry points
 # ---------------------------------------------------------------------------
 
+def spmm_kernel_name(part: str, transposed: bool) -> str:
+    """The name a part's SpMM panel kernel carries in a device trace:
+    ``loops_csr_spmm``/``loops_bcsr_spmm``, with ``_t`` where the operand
+    is a custom VJP's Aᵀ."""
+    return f"loops_{part}_spmm" + ("_t" if transposed else "")
+
+
 def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
              bn: int | None = None, out_dtype=None, panels=None,
-             vals=None, pipeline_depth: int = 1) -> jax.Array:
+             vals=None, pipeline_depth: int = 1,
+             transposed: bool = False) -> jax.Array:
     """SpMM of a ``repro.core.formats.CSR`` against dense ``b`` (..., K, N).
 
     ``panels`` — a ``repro.core.formats.PanelCSR`` view of the same matrix —
@@ -388,7 +396,8 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
     optional traced (nnz,) values replacing ``csr.vals``.  Leading batch
     dims of ``b`` execute as the kernels' native batch grid dimension.
     ``pipeline_depth=2`` double-buffers the B-row gathers on the panel
-    kernel (ignored by the flat and jnp paths).
+    kernel (ignored by the flat and jnp paths).  ``transposed`` names the
+    panel kernel for a custom VJP's Aᵀ (:func:`spmm_kernel_name`).
     """
     backend = resolve_backend(backend)
     check_rhs(csr.ncols, b)
@@ -429,7 +438,8 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
                 jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
                 panel_values(panels, vals), b3p, g=panels.g,
                 nrows=csr.nrows, bn=bn, out_dtype=out_dtype,
-                interpret=interpret, pipeline_depth=depth)
+                interpret=interpret, pipeline_depth=depth,
+                name=spmm_kernel_name("csr", transposed))
         else:
             out = get_kernel("csr", "spmm", "flat")(
                 jnp.asarray(csr.row_ids), jnp.asarray(csr.col_idx), v, b3p,
@@ -444,13 +454,15 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
 
 def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
               bn: int | None = None, out_dtype=None, panels=None,
-              vals=None, pipeline_depth: int = 1) -> jax.Array:
+              vals=None, pipeline_depth: int = 1,
+              transposed: bool = False) -> jax.Array:
     """SpMM of a ``repro.core.formats.VectorBCSR`` against dense ``b``.
 
     Returns the *logical* (..., bcsr.nrows, N) result (padding rows
     trimmed).  ``panels`` — a ``repro.core.formats.PanelBCSR`` — routes the
     Pallas backends through the G-wide kernel; ``vals`` — optional traced
-    (ntiles, Br) tile values replacing ``bcsr.tile_vals``.
+    (ntiles, Br) tile values replacing ``bcsr.tile_vals``; ``transposed``
+    as in :func:`csr_spmm`.
     """
     backend = resolve_backend(backend)
     check_rhs(bcsr.ncols, b)
@@ -485,7 +497,7 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
                 panel_values(panels, vals), b3p, g=panels.g,
                 nblocks=panels.nblocks, bn=bn, out_dtype=out_dtype,
                 interpret=interpret, pipeline_depth=pipeline_depth,
-                steps=panels.steps)
+                steps=panels.steps, name=spmm_kernel_name("bcsr", transposed))
         else:
             _note("bcsr", "spmm", backend=bk, impl="flat",
                   units=int(bcsr.ntiles), batch=nb, n=int(b.shape[-1]),
@@ -504,7 +516,8 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
 def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
                      bn: int | None = None, out_dtype=None,
                      csr_vals=None, bcsr_vals=None,
-                     pipeline_depth: int = 1) -> jax.Array:
+                     pipeline_depth: int = 1,
+                     transposed: bool = False) -> jax.Array:
     """Single-pass hybrid SpMM into ONE preallocated output.
 
     Pass 1 (CSR panels) allocates the full ``(..., r_boundary + nblocks*Br,
@@ -519,7 +532,9 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
     Requires both parts non-empty, panel views present, and ``r_boundary``
     aligned to ``Br`` (planners guarantee the alignment; ``loops_spmm``
     falls back to the two-output path otherwise).  ``csr_vals``/``bcsr_vals``
-    optionally substitute traced live values for the host-packed constants.
+    optionally substitute traced live values for the host-packed constants;
+    ``transposed`` names the kernels for a custom VJP's Aᵀ
+    (:func:`spmm_kernel_name`).
     """
     backend = resolve_backend(backend)
     if backend == "jnp":
@@ -559,13 +574,14 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
             jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols),
             panel_values(cp, csr_vals), b3p, g=cp.g, nrows=r_b,
             out_rows=r_pad, bn=bn, out_dtype=out_dtype,
-            interpret=interpret, pipeline_depth=depth)
+            interpret=interpret, pipeline_depth=depth,
+            name=spmm_kernel_name("csr", transposed))
         out = get_kernel("bcsr", "spmm", "panels")(
             bp.panel_rows, jnp.asarray(bp.lane_cols),
             panel_values(bp, bcsr_vals), b3p, g=bp.g, nblocks=bp.nblocks,
             row_block_offset=r_b // br, out_rows=r_pad, bn=bn,
             out_dtype=out_dtype, interpret=interpret, carry=out,
-            steps=bp.steps)
+            steps=bp.steps, name=spmm_kernel_name("bcsr", transposed))
         if b3p is not b3:
             out = out[:b3.shape[0]]
         if r_pad != fmt.nrows:

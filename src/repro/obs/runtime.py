@@ -29,7 +29,9 @@ and the rendezvous of the *active* capture (:func:`set_active`), which code
 far from any ``Obs`` reports to without holding one:
 
   * :func:`span` — a program span (``loops.coo_to_csr``, ``loops.format``,
-    ``loops.panelize``) on the active capture; a shared no-op without one;
+    ``loops.panelize``, ``loops.transpose``) on the active capture; a
+    shared no-op without one; :func:`count` — a counter on it
+    (``loops.transpose_entries``), nothing without one;
   * collective accounting — ``repro.dist.compress.compressed_psum``
     reports its per-call wire bytes (trace-time, so the gauge is
     bytes-per-call and the counter counts compiled call sites);
@@ -56,7 +58,8 @@ from .export import (chrome_trace, default_obs_dir, obs_records, write_chrome_tr
 from .metrics import MetricsRegistry
 from .spans import SpanSink
 
-__all__ = ["Obs", "set_active", "get_active", "span", "note_collective"]
+__all__ = ["Obs", "set_active", "get_active", "span", "count",
+           "note_collective"]
 
 # Process-wide active capture (the collective hook's rendezvous; launch
 # drivers install their Obs here for the duration of a run).
@@ -104,6 +107,13 @@ def span(name: str, **args):
     if obs is None:
         return _NO_SPAN
     return obs.span(name, cat="program", **args)
+
+
+def count(name: str, n: float = 1.0, **labels) -> None:
+    """Add ``n`` to the counter ``name`` of the active capture; nothing
+    without one."""
+    if _ACTIVE is not None:
+        _ACTIVE.counter(name, **labels).inc(n)
 
 
 class _CompileListener:

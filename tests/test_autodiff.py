@@ -147,6 +147,43 @@ def test_transpose_cache_reused_across_backwards(rng, monkeypatch):
                                atol=0)
 
 
+def test_tuned_transpose_is_built_once(rng, monkeypatch):
+    """A GCN graph prepared with a tuner builds Aᵀ once: the build is
+    cached under the plan it resolved to, which the backward pass pins
+    (``Graph.transpose_plan``), so tracing the gradient converts nothing."""
+    from repro.models import gcn
+
+    class Stub:                       # a tuner: .tune(csr) -> (fmt, plan)
+        def tune(self, csr):
+            return plan_and_convert(csr, total_workers=4)
+
+    m, n = 40, 8
+    rows, cols = rng.integers(0, m, 120), rng.integers(0, m, 120)
+    adj = gcn.normalized_adjacency(rows, cols, m)
+    calls = {"n": 0}
+    real = formats_lib._build_transposed
+
+    def counting(*args, **kwargs):
+        calls["n"] += 1
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(formats_lib, "_build_transposed", counting)
+    graph = gcn.prepare_graph(adj, backend="interpret", tuner=Stub())
+    assert calls["n"] == 1 and graph.transpose_plan is not None
+    b = jnp.asarray(rng.standard_normal((m, n)), jnp.float32)
+
+    def loss(bb):
+        return jnp.sum(loops_spmm(graph.adj, bb, backend="interpret",
+                                  transpose_plan=graph.transpose_plan) ** 2)
+
+    got = jax.grad(loss)(b)
+    assert calls["n"] == 1
+    dense = np.zeros((m, m))
+    dense[adj.row_ids, adj.col_idx] = adj.vals
+    want = 2 * dense.T @ (dense @ np.asarray(b, np.float64))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+
+
 def test_transposed_structure_roundtrip(rng):
     """Aᵀ's LOOPS format densifies back to the dense transpose, and the
     value-linear maps reproduce the converted parts from A's flat values
@@ -227,3 +264,74 @@ def test_gcn_hybrid_grad_end_to_end(rng):
     g_dense = jax.grad(lambda w_: loss(w_, lambda h: dense @ h))(w)
     np.testing.assert_allclose(np.asarray(g_loops), np.asarray(g_dense),
                                rtol=1e-4, atol=1e-4)
+
+
+def _stored(fmt):
+    """(row, col) of the stored entries of a LOOPS format: every CSR-part
+    entry and the BCSR tile slots a stored entry landed in."""
+    csr, bc = fmt.csr_part, fmt.bcsr_part
+    coords = set(zip(csr.row_ids.tolist(), csr.col_idx.tolist()))
+    t, off = np.nonzero(bc.stored)
+    rows = fmt.r_boundary + bc.tile_rows[t] * bc.br + off
+    return coords | set(zip(rows.tolist(), bc.tile_cols[t].tolist()))
+
+
+def _with_zeros_and_empty_rows(rng, m, k, symmetric):
+    """COO with explicit zeros, two empty rows and an empty column."""
+    a = (rng.random((m, k)) < 0.25) * rng.standard_normal((m, k))
+    if symmetric:
+        a = np.triu(a) + np.triu(a, 1).T
+    a[[3, m - 2]] = 0
+    a[:, [3, m - 2]] = 0
+    rows, cols = np.nonzero(a)
+    zr, zc = [0, 5, 9, 9], [1, 0, 4, 9]        # stored zeros
+    rows, cols = np.r_[rows, zr], np.r_[cols, zc]
+    return formats_lib.csr_from_coo(rows, cols, a[rows, cols], (m, k))
+
+
+@pytest.mark.parametrize("case,r_b", [
+    ("nonsymmetric", 8), ("symmetric", 8), ("nonsymmetric", 0),
+    ("symmetric", 24), ("transpose_of_transpose", 8)])
+def test_transpose_holds_stored_entries_only(rng, case, r_b):
+    """Aᵀ stores exactly A's stored entries, transposed — explicit zeros
+    included, BCSR tile padding left out — plus one pad per empty row of
+    Aᵀ, and densifies to Aᵀ; live values keep every tile slot of A's
+    rows."""
+    m = 24
+    csr = _with_zeros_and_empty_rows(rng, m, m, case == "symmetric")
+    fmt = loops_from_csr(csr, r_b, 8, panel_g=4)
+    if case == "transpose_of_transpose":
+        fmt = fmt.transposed().fmt         # a BCSR part that keeps zeros
+    tl = fmt.transposed()
+    want = {(c, r) for r, c in _stored(fmt)}
+    empty = set(range(fmt.ncols)) - {r for r, _ in want}
+    assert empty                               # Aᵀ has empty rows here
+    assert _stored(tl.fmt) == want | {(r, 0) for r in empty}
+    assert tl.n_slots == len(want) + len(empty)
+    bc = fmt.bcsr_part
+    assert bc.stored.sum() < bc.ntiles * bc.br  # A has tile padding
+    np.testing.assert_allclose(_densify(tl.fmt), _densify(fmt).T)
+    live = fmt.transposed(live_values=True)
+    rows = fmt.r_boundary + np.repeat(bc.tile_rows, bc.br) * bc.br \
+        + np.tile(np.arange(bc.br), bc.ntiles)
+    slots = set(zip(np.repeat(bc.tile_cols, bc.br)[rows < m].tolist(),
+                    rows[rows < m].tolist()))
+    csr_t = {(c, r) for r, c in zip(fmt.csr_part.row_ids.tolist(),
+                                    fmt.csr_part.col_idx.tolist())}
+    live_want = slots | csr_t
+    live_empty = set(range(fmt.ncols)) - {r for r, _ in live_want}
+    assert live.n_slots == len(live_want) + len(live_empty)
+    assert live.n_slots > tl.n_slots or fmt.r_boundary == m
+
+
+def _densify(fmt):
+    from repro.core import csr_to_dense
+    out = np.zeros(fmt.shape, np.float64)
+    out[:fmt.r_boundary] = csr_to_dense(fmt.csr_part)
+    bc = fmt.bcsr_part
+    for t in range(bc.ntiles):
+        r0 = fmt.r_boundary + int(bc.tile_rows[t]) * bc.br
+        for off in range(bc.br):
+            if r0 + off < fmt.nrows:
+                out[r0 + off, bc.tile_cols[t]] += bc.tile_vals[t, off]
+    return out

@@ -185,3 +185,25 @@ def test_fused_kernels_carry_their_names(one_chip, depth):
     parts = {re.sub(r"\.\d+$", "", n) for n in names}
     assert parts == {"loops_csr_spmm", "loops_bcsr_spmm"}
     assert sum(n.startswith("loops_bcsr_spmm") for n in names) >= 2
+
+
+@pytest.mark.parametrize("n", [256, 40])
+def test_backward_kernels_carry_transposed_names(one_chip, n):
+    """The gradient of ``loops_spmm`` runs the forward kernels on A and the
+    same kernels on Aᵀ, named ``loops_csr_spmm_t``/``loops_bcsr_spmm_t``,
+    at a GCN's widths: 256 (two 128-lane pieces of the BCSR gather) and 40
+    (the lane-dense view at a width that does not divide 128), fp32."""
+    rng = np.random.default_rng(0)
+    a = ((rng.random((4096, 4096)) < 0.002)
+         * rng.standard_normal((4096, 4096))).astype(np.float32)
+    fmt, _ = plan_and_convert(csr_from_dense(a))
+    assert 0 < fmt.r_boundary < fmt.nrows
+    hlo = _compile(jax.grad(lambda b: jnp.sum(
+        loops_spmm(fmt, b, backend="pallas") ** 2)), one_chip,
+        ((4096, n), jnp.float32))
+    names = [m.group(1) for m in map(_CUSTOM_CALL.match, hlo.splitlines())
+             if m]
+    assert len(names) == hlo.count('custom_call_target="tpu_custom_call"')
+    parts = {re.sub(r"\.\d+$", "", x) for x in names}
+    assert parts == {"loops_csr_spmm", "loops_bcsr_spmm", "loops_csr_spmm_t",
+                     "loops_bcsr_spmm_t"}
