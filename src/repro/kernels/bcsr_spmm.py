@@ -26,27 +26,37 @@ hi)`` masked, and each step makes one
 MXU contraction — up to 128 fmopa rounds per ZA-tile visit instead of G.
 
 B stays in HBM.  Step ``k`` starts one DMA per lane of step ``k + 1`` into
-the other slot of a two-slot ``(128, ·)`` VMEM stage, then waits for its
-own slot with one wait per power-of-two block of rows: the gathers of the
-next step overlap this step's contraction, and the first step of each
-sweep fetches its own rows.  The issue loop has no branch (a padding lane,
-column -1, fetches row 0) and is unrolled by four; the DMAs, about 23 ns
-each on a v5e, set the kernel's pace.  Stage rows outside the step and
-padding rows are masked by a select (stale VMEM or row 0 may hold NaN),
-never multiplied by a zero.
+the other slot of a two-slot VMEM stage, then waits for its own slot with
+one wait per power-of-two block of 128-lane rows: the gathers of the next
+step overlap this step's contraction, and the first step of each sweep
+fetches its own rows.  The issue loop has no branch (a padding lane,
+column -1, fetches row 0) and is unrolled by four; the DMA descriptors,
+about 23 ns each on a v5e, set the kernel's pace, not their bytes, so
+each lane takes exactly one.  Stage rows outside the step and padding
+rows are masked by a select (stale VMEM or row 0 may hold NaN), never
+multiplied by a zero.
 
-A DMA copies whole 128-lane rows.  For ``N < 128`` the operand is read
-through a lane-dense ``(K·N'/128, 128)`` view (``N'`` the power of two
-``>= N``): lane ``c`` fetches view row ``c // q`` (``q = 128 / N'``), keeps
-the ``N'`` lanes of group ``c % q`` by mask, and the flush folds the ``q``
-lane groups of the ``(Br, 128)`` accumulator into ``(Br, N)``.  Other
-widths not a multiple of 128 are padded to one.
+A DMA copies whole 128-lane rows of B's view.  For ``N > 128`` the view is
+``(K·pieces, 128)``, a plain reshape of the row-major (padded) operand:
+row ``r``'s piece ``c`` (its lanes ``[128·c, 128·c + 128)``) is view row
+``r·pieces + c``, so a B row's pieces are adjacent in HBM and one DMA
+copies all of a column block's, into stage rows ``[t·pieces, (t+1)·
+pieces)`` of lane ``t``.  The contraction of piece ``c`` reads stage rows
+``c, c + pieces, ...`` (a strided sublane load), the ``(128, 128)`` operand
+of lanes in order.  For ``N < 128`` the operand is read through a
+lane-dense ``(K·N'/128, 128)`` view (``N'`` the power of two ``>= N``):
+lane ``c`` fetches view row ``c // q`` (``q = 128 / N'``), keeps the
+``N'`` lanes of group ``c % q`` by mask, and the flush folds the ``q`` lane
+groups of the ``(Br, 128)`` accumulator into ``(Br, N)``.  Other widths
+not a multiple of 128 are padded to one.  At one piece (``N <= 128``) the
+stage is ``(128, 128)`` and the load plain.
 
 Batched execution (multi-RHS)
 -----------------------------
 A rank-3 dense operand ``(batch, K, N)`` adds a leading batch-block grid
-axis: each lane's DMA copies the row of ``bz`` batch slices, and each step
-issues ``bz`` contractions sharing the A operand.
+axis and the view ``(batch, K·pieces, 128)``: each lane's DMA copies the
+row of ``bz`` batch slices, and each step issues ``bz`` contractions per
+piece sharing the A operand.
 
 Precision (§3.3 FP16 path, Algorithm 3): the TPU MXU natively multiplies
 bf16 operands and accumulates in fp32 (``preferred_element_type``), which
@@ -90,7 +100,7 @@ _CARRY_INDEX = 6
 
 def gather_layout(n: int, bn: int | None = None):
     """``(bn, width, groups)`` of the gathers against an ``N``-wide operand:
-    a lane gathers ``width / 128`` pieces of 128 lanes, one DMA each, which
+    a lane gathers ``width / 128`` pieces of 128 lanes by one DMA, which
     hold ``groups`` lane groups of which one is the wanted B row
     (``groups > 1`` only for ``N < 128``, read through the lane-dense
     view)."""
@@ -104,13 +114,15 @@ def gather_layout(n: int, bn: int | None = None):
 
 
 def _gather_view(b, n: int, groups: int):
-    """The operand as the kernel's DMAs read it: ``(pieces, rows, 128)``
-    per batch slice, a piece being 128 lanes of every row.  Mosaic refuses
-    a DMA of one row out of an (8, 128)-tiled array wider than 128 lanes,
-    and one of a single packed half-precision row, hence the pieces and
-    the 32-bit carrier.  Columns are zero-padded to a multiple of 128 or,
-    when ``groups > 1``, to ``128 / groups`` with ``groups`` B rows folded
-    into each 128-lane row."""
+    """The operand as the kernel's DMAs read it: ``(rows·pieces, 128)`` per
+    batch slice, row ``r``'s piece ``c`` (its lanes ``[128·c, 128·c +
+    128)``) at view row ``r·pieces + c`` — a plain reshape of the row-major
+    operand, so a B row's pieces are adjacent sublanes and one DMA fetches
+    them all.  Mosaic refuses a DMA of one row out of an (8, 128)-tiled
+    array wider than 128 lanes, and one of a single packed half-precision
+    row, hence the 128-lane view and the 32-bit carrier.  Columns are
+    zero-padded to a multiple of 128 or, when ``groups > 1``, to ``128 /
+    groups`` with ``groups`` B rows folded into each 128-lane row."""
     b = b.astype(jnp.promote_types(b.dtype, jnp.float32))
     lead = b.shape[:-2]
     sub = LANES // groups if groups > 1 else -(-n // LANES) * LANES
@@ -118,10 +130,7 @@ def _gather_view(b, n: int, groups: int):
     kp = max(-(-k // groups), LANES) * groups    # a wait names 128 rows
     if (kp, sub) != (k, n):
         b = jnp.pad(b, [(0, 0)] * len(lead) + [(0, kp - k), (0, sub - n)])
-    rows, pieces = kp // groups, sub * groups // LANES
-    b = b.reshape(lead + (rows, pieces, LANES))
-    return jnp.moveaxis(b, -2, -3) if pieces > 1 else b.reshape(
-        lead + (1, rows, LANES))
+    return b.reshape(lead + (kp * sub // LANES, LANES))
 
 
 def steps_per_call(step_starts, per_call: int | None = None,
@@ -168,11 +177,13 @@ def _contract(acc_ref, lanes, a, x, bz: int | None):
             preferred_element_type=acc_ref.dtype)
 
 
-def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
-                 bz: int | None, *refs):
-    """One grid step: start the next step's B-row DMAs, wait for this
-    step's, then one masked ``(Br, 128) @ (128, 128)`` contraction per
-    128-lane piece of the column block."""
+def _step_kernel(groups: int, bn: int, row_pieces: int, cdt,
+                 has_carry: bool, bz: int | None, *refs):
+    """One grid step: start the next step's B-row DMAs, one per lane, wait
+    for this step's, then one masked ``(Br, 128) @ (128, 128)``
+    contraction per 128-lane piece of the column block.  A B row is
+    ``row_pieces`` rows of the view, of which the column block takes
+    ``pieces``."""
     prev_ref, base_ref, rows_ref, starts_ref, cols_ref, win_ref = refs[:6]
     rest = refs[6:]
     carry_ref, rest = (rest[0], rest[1:]) if has_carry else (None, rest)
@@ -181,19 +192,22 @@ def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
     k = pl.program_id(axis)
     nsteps = pl.num_programs(axis)
     j = pl.program_id(axis - 1)
-    pieces = stage_ref.shape[1]
+    pieces = stage_ref.shape[-2] // LANES
+    first_piece = j * pieces
     base = base_ref[0]
     # Grid indices are read here: the interpreter cannot read them inside
     # the lane loops below.
     zs = None if bz is None else pl.ds(pl.program_id(0) * bz, bz)
 
-    def copy(row, lane, slot, c):
+    def copy(row, lane, slot):
+        """The DMA of B row ``row``'s pieces of this column block into
+        stage rows ``[lane·pieces, (lane+1)·pieces)``: adjacent in both."""
+        src = pl.ds(row * row_pieces + first_piece, pieces)
+        dst = pl.ds(lane * pieces, pieces)
         if bz is None:
-            src = b_hbm.at[j * pieces + c, pl.ds(row, 1)]
-            dst = stage_ref.at[slot, c, pl.ds(lane, 1), :]
+            src, dst = b_hbm.at[src], stage_ref.at[slot, dst]
         else:
-            src = b_hbm.at[zs, j * pieces + c, pl.ds(row, 1)]
-            dst = stage_ref.at[slot, c, :, pl.ds(lane, 1), :]
+            src, dst = b_hbm.at[zs, src], stage_ref.at[slot, :, dst]
         return pltpu.make_async_copy(src, dst, sem.at[slot])
 
     shift = groups.bit_length() - 1          # groups is a power of two
@@ -211,8 +225,7 @@ def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
             grp_ref[slot, pl.ds(t - w0, 1), :] = jnp.full(
                 (1, 1), jnp.where(col >= 0, col & (groups - 1), -1),
                 jnp.int32)
-            for c in range(pieces):
-                copy(jnp.maximum(col, 0) >> shift, t - w0, slot, c).start()
+            copy(jnp.maximum(col, 0) >> shift, t - w0, slot).start()
 
         def four(i, carry):
             t = t0 + 4 * i
@@ -230,15 +243,15 @@ def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
 
     def wait(step, slot):
         """Wait for ``step``'s DMAs into ``slot``: a DMA semaphore counts
-        what arrived, so one wait for a ``(rows, 128)`` block covers
-        ``rows`` of them."""
+        the bytes that arrived, so one wait for a ``(rows, 128)`` block
+        covers ``rows`` 128-lane rows, ``pieces`` to a lane."""
         def block(rows):
             if bz is None:
-                dst = stage_ref.at[slot, 0, pl.ds(0, rows), :]
-                src = b_hbm.at[0, pl.ds(0, rows)]
+                dst = stage_ref.at[slot, pl.ds(0, rows)]
+                src = b_hbm.at[pl.ds(0, rows)]
             else:
-                dst = stage_ref.at[slot, 0, :, pl.ds(0, rows), :]
-                src = b_hbm.at[zs, 0, pl.ds(0, rows)]
+                dst = stage_ref.at[slot, :, pl.ds(0, rows)]
+                src = b_hbm.at[zs, pl.ds(0, rows)]
             pltpu.make_async_copy(src, dst, sem.at[slot]).wait()
 
         count = (starts_ref[step + 1] - starts_ref[step]) * pieces
@@ -284,7 +297,9 @@ def _step_kernel(groups: int, bn: int, cdt, has_carry: bool,
     keep = (row >= lo) & (row < hi) & (grp_ref[slot] == grp)
     a = a.astype(cdt)
     for c in range(pieces):
-        x = stage_ref[slot, c]
+        # Piece ``c`` of every lane: stage rows ``c, c + pieces, ...``.
+        rows = pl.ds(c, LANES, stride=pieces)
+        x = stage_ref[slot, rows] if bz is None else stage_ref[slot, :, rows]
         x = jnp.where(keep, x, jnp.zeros_like(x)).astype(cdt)
         _contract(acc_ref, slice(c * LANES, (c + 1) * LANES), a, x, bz)
 
@@ -319,6 +334,7 @@ def _bcsr_steps_spmm(step_rows, step_starts, lane_cols, vals_window, b,
     out_shape = (out_rows, n) if batch is None else (batch, out_rows, n)
     view = _gather_view(b, n, groups)
     pieces = width // LANES
+    row_pieces = 1 if groups > 1 else -(-n // LANES)
     cdt = jnp.promote_types(vals_window.dtype, b.dtype)
     pad = -vals_window.shape[-1] % LANES
     if pad:
@@ -351,15 +367,15 @@ def _bcsr_steps_spmm(step_rows, step_starts, lane_cols, vals_window, b,
             aliases = {_CARRY_INDEX: 0}
         in_specs.append(pl.BlockSpec(memory_space=pltpu.HBM))
         args.append(view)
-        kernel = functools.partial(_step_kernel, groups, bn, cdt,
-                                   acc_in is not None, bz)
+        kernel = functools.partial(_step_kernel, groups, bn, row_pieces,
+                                   cdt, acc_in is not None, bz)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=grid,
             in_specs=in_specs,
             out_specs=spec(lead + (br, bn), out_block),
             scratch_shapes=[
-                pltpu.VMEM((2, pieces) + lead + (LANES, LANES),
+                pltpu.VMEM((2,) + lead + (pieces * LANES, LANES),
                            view.dtype),
                 pltpu.VMEM((2, LANES, 1), jnp.int32),      # lane groups
                 pltpu.VMEM(lead + (br, width), acc_dtype),  # accumulator

@@ -288,6 +288,9 @@ def _bcsr_note_fields(panels, *, nb: int, n: int, bn: int | None,
 
     ``gathers`` — B rows the step table fetches (valid lanes, the rest are
     padding), so ``gathers / steps`` is the rows one grid step gathers;
+    ``dmas`` — the DMA descriptors it issues, one per lane (padding lanes
+    included) whatever the width, so ``dmas / gathers`` is a little
+    above 1;
     ``scratch_bytes`` — the accumulator, the two-slot 32-bit B stage and
     the lane-group column; ``prefetch_overlap`` — steps whose rows the
     step before fetched (all but the first of each call's sweep)."""
@@ -306,6 +309,7 @@ def _bcsr_note_fields(panels, *, nb: int, n: int, bn: int | None,
             "units": nsteps,
             "steps": nsteps * sweeps,
             "gathers": int(np.count_nonzero(panels.lane_cols >= 0)) * sweeps,
+            "dmas": int(starts[-1]) * sweeps,
             "scratch_bytes": int(scratch),
             "prefetch_overlap": max(nsteps - calls, 0) / max(nsteps, 1)}
 

@@ -8,7 +8,8 @@ owns the built-in instrumentation seams:
     tracer (``repro.kernels.engine.set_tracer``) and turns every kernel
     dispatch into ``engine.dispatch`` counters (labeled ``part``/``op``/
     ``backend``/``impl``), ``engine.grid_steps_compiled``,
-    ``engine.gathers`` (B rows the BCSR kernel's steps fetch) and
+    ``engine.gathers`` (B rows the BCSR kernel's steps fetch),
+    ``engine.gather_dmas`` (the DMA descriptors that fetch them) and
     ``engine.const_bytes`` (host arrays turned into program constants)
     counters.  Dispatches fire at *trace* time — once per jit compilation
     — so these are compilation-workload counters, deliberately not
@@ -249,6 +250,9 @@ class _EngineTracer:
         gathers = fields.get("gathers")
         if gathers is not None:
             m.counter("engine.gathers", part=part).inc(float(gathers))
+        dmas = fields.get("dmas")
+        if dmas is not None:
+            m.counter("engine.gather_dmas", part=part).inc(float(dmas))
         sb = fields.get("scratch_bytes")
         if sb is not None:
             m.gauge("kernel.scratch_bytes", part=part, op=op).set(float(sb))

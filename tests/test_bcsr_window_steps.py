@@ -7,9 +7,12 @@ Every case compares ``loops_spmm`` or the kernel itself with
 step table at one of its edges: block rows spanning windows, block rows
 sharing one, padding lanes, chunk seams inside a block row (carry resume),
 the fused and parts paths, the batched grid, depths 1 and 2, packed half
-precision, N of 32 and 128, a B row no tile reads holding inf/NaN, and a
+precision, N of 32, 128 and above one lane tile (a B row's 128-lane
+pieces fetched by one DMA), a B row no tile reads holding inf/NaN, and a
 wide operand.
 """
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -99,10 +102,10 @@ def case_fused_and_parts(rng):
                1e-4)
 
 
-def case_batched(rng):
+def case_batched(rng, n=32):
     a = _matrix(rng, 40, 200, 0.3)
     fmt = loops_from_csr(csr_from_dense(a), 16, 8, panel_g=8)
-    b = jnp.asarray(rng.standard_normal((3, 200, 32)).astype(np.float32))
+    b = jnp.asarray(rng.standard_normal((3, 200, n)).astype(np.float32))
     got = np.asarray(loops_spmm(fmt, b, backend="interpret"))
     for z in range(3):
         _check(got[z], a @ np.asarray(b[z], np.float64), 1e-4)
@@ -128,21 +131,23 @@ def case_packed_bf16(rng):
     _check(got, np.asarray(a, np.float64) @ np.asarray(b, np.float64), 3e-2)
 
 
-def case_n128(rng):
+def case_width(rng, n):
+    """One column block of ``n`` lanes: at N 200 padded to two pieces, at
+    N 256 and 384 two and three, each lane's pieces one DMA."""
     a = _matrix(rng, 40, 200, 0.3)
     fmt = loops_from_csr(csr_from_dense(a), 0, 8, panel_g=8)
-    b = jnp.asarray(rng.standard_normal((200, 128)).astype(np.float32))
+    b = jnp.asarray(rng.standard_normal((200, n)).astype(np.float32))
     _check(_parts(fmt, b), a @ np.asarray(b, np.float64), 1e-4)
 
 
-def case_unread_row_nonfinite(rng):
+def case_unread_row_nonfinite(rng, n=32):
     """B rows no tile reads hold inf/NaN: row 7, and row 0, which padding
     lanes fetch and the mask drops."""
     a = _matrix(rng, 40, 64, 0.3)
     a[:, [0, 7]] = 0.0
     fmt = loops_from_csr(csr_from_dense(a), 0, 8, panel_g=8)
     assert (fmt.bcsr_panels.lane_cols < 0).any()
-    b = rng.standard_normal((64, 32)).astype(np.float32)
+    b = rng.standard_normal((64, n)).astype(np.float32)
     b[[0, 7], ::2], b[[0, 7], 1::2] = np.inf, np.nan
     got = _parts(fmt, jnp.asarray(b))
     assert np.isfinite(got).all()
@@ -162,10 +167,22 @@ def case_wide_operand(rng):
     _check(_parts(fmt, b), _ref(fmt, b), 1e-4)
 
 
+def _at(case, n, name):
+    """``case`` at N ``n``, as the entry ``name`` of :data:`CASES`."""
+    entry = functools.partial(case, n=n)
+    entry.__name__ = name
+    return entry
+
+
 CASES = [case_row_spans_windows, case_rows_share_a_window,
          case_padding_lanes, case_chunk_seams_resume, case_fused_and_parts,
-         case_batched, case_depths_bitwise, case_packed_bf16, case_n128,
-         case_unread_row_nonfinite, case_wide_operand]
+         case_batched, case_depths_bitwise, case_packed_bf16,
+         _at(case_width, 128, "case_n128"), case_unread_row_nonfinite,
+         case_wide_operand, _at(case_width, 200, "case_n200"),
+         _at(case_width, 256, "case_n256"), _at(case_width, 384, "case_n384"),
+         _at(case_batched, 256, "case_batched_n256"),
+         _at(case_unread_row_nonfinite, 256,
+             "case_unread_row_nonfinite_n256")]
 
 
 @pytest.mark.parametrize("case", CASES,
@@ -186,9 +203,28 @@ def test_window_steps_table():
     assert block_row_steps([16, 160, 8]) == len(rows)
 
 
+def test_wide_operand_is_its_halves_bitwise(rng):
+    """At N 256 a lane's two 128-lane pieces arrive by one DMA and are
+    contracted one by one, in the order and arithmetic of N 128: each half
+    of the result is bitwise the result on that half of B."""
+    a = _matrix(rng, 48, 300, 0.3)
+    fmt = loops_from_csr(csr_from_dense(a), 0, 8, panel_g=8)
+    b = jnp.asarray(rng.standard_normal((300, 256)).astype(np.float32))
+    whole = np.asarray(engine.bcsr_spmm(
+        fmt.bcsr_part, b, backend="interpret", panels=fmt.bcsr_panels))
+    for h in range(2):
+        half = np.asarray(engine.bcsr_spmm(
+            fmt.bcsr_part, b[:, h * LANES:(h + 1) * LANES],
+            backend="interpret", panels=fmt.bcsr_panels))
+        np.testing.assert_array_equal(whole[:, h * LANES:(h + 1) * LANES],
+                                      half)
+
+
 def test_engine_gathers_count_the_valid_tiles(rng):
     """``engine.gathers`` counts the B rows the step table fetches: one per
-    tile, none for padding lanes."""
+    tile, none for padding lanes; ``engine.gather_dmas`` the DMAs that
+    fetch them, one per lane (padding lanes included) at N 32 and at N 256,
+    where a row is two 128-lane pieces."""
     from repro.obs import Obs
     a = np.zeros((16, 12), np.float32)
     a[0, [1, 4, 9]] = 1.0          # block row 0: 3 tiles (rows 0-7)
@@ -196,12 +232,16 @@ def test_engine_gathers_count_the_valid_tiles(rng):
     a[12, [0, 2]] = 3.0            # block row 1: 2 tiles
     fmt = loops_from_csr(csr_from_dense(a), 0, 8, panel_g=8)
     assert fmt.bcsr_part.ntiles == 6
-    obs = Obs(source="t")
-    with obs.attach_engine():
-        out = loops_spmm(fmt, jnp.ones((12, 32), jnp.float32),
-                         backend="interpret")
-    _check(np.asarray(out), a @ np.ones((12, 32)))
-    counters = {inst.name: inst.value for _, inst in obs.metrics.instruments()
-                if inst.labels.get("part") == "bcsr"}
-    assert counters["engine.gathers"] == 6
-    assert counters["engine.grid_steps_compiled"] == 2   # one per block row
+    assert fmt.bcsr_panels.lane_cols.size == 16  # 2 panels of 8 lanes
+    for n in (32, 256):
+        obs = Obs(source="t")
+        with obs.attach_engine():
+            out = loops_spmm(fmt, jnp.ones((12, n), jnp.float32),
+                             backend="interpret")
+        _check(np.asarray(out), a @ np.ones((12, n)))
+        counters = {inst.name: inst.value
+                    for _, inst in obs.metrics.instruments()
+                    if inst.labels.get("part") == "bcsr"}
+        assert counters["engine.gathers"] == 6
+        assert counters["engine.gather_dmas"] == 16
+        assert counters["engine.grid_steps_compiled"] == 2  # one a block row

@@ -132,7 +132,7 @@ def test_chunked_metadata_compiles(one_chip, part):
 @pytest.mark.parametrize("layout", ["chunked", "batched"])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("n", [32, 128])
+@pytest.mark.parametrize("n", [32, 128, 256])
 def test_bcsr_window_steps_compile(one_chip, n, dtype, layout):
     """The BCSR kernel's window steps and manual DMA gather: B rows from
     HBM into a two-slot VMEM stage, at N below and at one lane tile, in
