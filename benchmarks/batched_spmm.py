@@ -63,9 +63,6 @@ def main(out=print, record=None, smoke: bool = False):
          * rng.standard_normal((scale, scale // 2))).astype(np.float32)
     csr = csr_from_dense(a)
     fmt, plan = plan_and_convert(csr, total_workers=8)
-    # Piped variant: same plan run macro-fused under the depth-2 pipeline.
-    fmt_piped, plan_piped = plan_and_convert(csr, total_workers=8,
-                                             pipeline_depth=2, macro_m=4)
     k = csr.shape[1]
 
     f_elem = jax.jit(lambda b2: loops_spmm(fmt, b2, backend=BACKEND))
@@ -77,17 +74,11 @@ def main(out=print, record=None, smoke: bool = False):
         steps_one = loops_grid_steps(fmt, N)
         steps = {"loop": batch * steps_one, "vmap": batch * steps_one,
                  "native": loops_batched_grid_steps(fmt, batch, N)}
-        steps_piped = loops_batched_grid_steps(fmt_piped, batch, N)
         fns = _strategies(fmt, batch)
-        f_piped = jax.jit(lambda b3_: loops_spmm(fmt_piped, b3_,
-                                                 backend=BACKEND))
 
-        # Parity: native batched == vmap-unrolled (the acceptance contract),
-        # and the macro-fused depth-2 pipeline must agree with both.
+        # Parity: native batched == vmap-unrolled (the acceptance contract).
         ref = np.asarray(fns["vmap"](b3))
         np.testing.assert_allclose(np.asarray(fns["native"](b3)), ref,
-                                   rtol=1e-4, atol=1e-4)
-        np.testing.assert_allclose(np.asarray(f_piped(b3)), ref,
                                    rtol=1e-4, atol=1e-4)
 
         times = {}
@@ -107,8 +98,6 @@ def main(out=print, record=None, smoke: bool = False):
             gfn = jax.jit(jax.grad(lambda bb, f=fn: jnp.sum(f(bb))))
             times[(name, "fwdbwd")] = time_fn(gfn, b3, repeats=repeats,
                                               warmup=warmup)
-        times[("piped", "fwd")] = time_fn(f_piped, b3, repeats=repeats,
-                                          warmup=warmup)
 
         for name in ("loop", "vmap", "native"):
             out(csv_row(
@@ -116,10 +105,6 @@ def main(out=print, record=None, smoke: bool = False):
                 f"grid_steps={steps[name]};"
                 f"fwdbwd_us={times[(name, 'fwdbwd')] * 1e6:.1f};"
                 f"steps_vs_loop={steps['loop'] / max(steps[name], 1):.2f}x"))
-        out(csv_row(
-            f"batched_b{batch}_piped", times[("piped", "fwd")] * 1e6,
-            f"grid_steps={steps_piped};pipeline_depth=2;macro_m=4;"
-            f"steps_vs_loop={steps['loop'] / max(steps_piped, 1):.2f}x"))
         if batch >= 4:
             assert steps["native"] < steps["loop"], \
                 (f"native batched must beat the per-element loop on grid "
@@ -129,12 +114,8 @@ def main(out=print, record=None, smoke: bool = False):
             record({
                 "suite": "batched", "batch": batch, "n_cols": N,
                 "panel_g": plan.panel_g,
-                "pipeline_depth": getattr(plan_piped, "pipeline_depth", 1),
-                "macro_m": getattr(plan_piped, "macro_m", 1),
                 "grid_steps_loop": steps["loop"],
                 "grid_steps_native": steps["native"],
-                "grid_steps_piped": steps_piped,
-                "fwd_us_piped": times[("piped", "fwd")] * 1e6,
                 "step_reduction_vs_loop":
                     steps["loop"] / max(steps["native"], 1),
                 "fwd_us_loop": times[("loop", "fwd")] * 1e6,

@@ -36,8 +36,6 @@ MATRICES = ["m6", "m8", "m9", "m10", "m12", "m13", "m14", "m16", "m17", "m19"]
 SMOKE_MATRICES = ["m6", "m12", "m13"]
 G_CHOICES = (4, 8)         # tuned-G candidates (G=1 is the baseline column)
 WALL_MATRICES = 3          # matrices that also get interpret wall-clock
-PIPE_DEPTH = 2             # piped column: double-buffered B-panel prefetch
-MACRO_M = 4                # piped column: same-row panels fused per step
 
 
 def calibrated_plan(csr, b, total: int = 4, deterministic: bool = False):
@@ -65,24 +63,9 @@ def calibrated_plan(csr, b, total: int = 4, deterministic: bool = False):
 
 def panel_comparison(csr, plan, b, *, mid: str, name_dt: str, out,
                      record=None, wall_clock: bool, smoke: bool):
-    """G=1 vs tuned-G vs piped column: grid-step cost proxy for every
-    matrix, plus interpret-mode (Pallas) wall-clock on a subset — the
-    panelization and pipeline speedups tracked in the perf trajectory
-    (benchmark JSON).
-
-    Two pipelined columns ride on the tuned-G conversion:
-
-      * *fused*  — macro-step fusion alone (``macro_m=MACRO_M``, depth 1):
-        ~MACRO_M× fewer grid steps, and the wall-clock win interpret mode
-        can actually observe (fewer sequential grid dispatches);
-      * *piped*  — the same fusion under the double-buffered pipeline
-        (``pipeline_depth=PIPE_DEPTH``).  Interpret mode executes grid
-        steps serially, so the prefetch overlap the second buffer buys on
-        hardware shows up here only as scratch-staging overhead — the
-        column is correctness-gated instead: depth-2 at the same conversion
-        must be *bitwise* equal to the depth-1 baseline (unbatched parity
-        contract of the piped kernels), and the macro-fused results must
-        agree to tolerance."""
+    """G=1 vs tuned-G: grid-step cost proxy for every matrix, plus
+    interpret-mode (Pallas) wall-clock on a subset — the panelization
+    speedup tracked in the perf trajectory (benchmark JSON)."""
     fmts = {g: loops_from_csr(csr, plan.r_boundary, plan.br, panel_g=g)
             for g in (1,) + tuple(G_CHOICES)}
     steps = {g: loops_grid_steps(f, N) for g, f in fmts.items()}
@@ -91,14 +74,6 @@ def panel_comparison(csr, plan, b, *, mid: str, name_dt: str, out,
     red_tuned = steps[1] / max(steps[tuned_g], 1)
     red_ref = steps[1] / max(steps[g_ref], 1)
 
-    fmt_fused = loops_from_csr(csr, plan.r_boundary, plan.br,
-                               panel_g=tuned_g, macro_m=MACRO_M)
-    fmt_piped = loops_from_csr(csr, plan.r_boundary, plan.br,
-                               panel_g=tuned_g, macro_m=MACRO_M,
-                               pipeline_depth=PIPE_DEPTH)
-    steps_fused = loops_grid_steps(fmt_fused, N)
-    steps_piped = loops_grid_steps(fmt_piped, N)
-
     wall = {}
     if wall_clock:
         repeats, warmup = (2, 1) if smoke else (3, 1)
@@ -106,55 +81,26 @@ def panel_comparison(csr, plan, b, *, mid: str, name_dt: str, out,
             f = jax.jit(lambda bb, fg=fmts[g]: loops_spmm(
                 fg, bb, backend="interpret"))
             wall[g] = time_fn(f, b, repeats=repeats, warmup=warmup)
-        for key, fmt_k in (("fused", fmt_fused), ("piped", fmt_piped)):
-            f = jax.jit(lambda bb, fk=fmt_k: loops_spmm(
-                fk, bb, backend="interpret"))
-            wall[key] = time_fn(f, b, repeats=repeats, warmup=warmup)
-        # Correctness gates for the pipelined columns.
-        fmt_d2 = loops_from_csr(csr, plan.r_boundary, plan.br,
-                                panel_g=tuned_g, pipeline_depth=PIPE_DEPTH)
-        y_base = np.asarray(loops_spmm(fmts[tuned_g], b,
-                                       backend="interpret"))
-        y_d2 = np.asarray(loops_spmm(fmt_d2, b, backend="interpret"))
-        np.testing.assert_array_equal(y_d2, y_base)   # bitwise, unbatched
-        tol = 1e-10 if name_dt == "fp64" else 1e-4
-        for fmt_k in (fmt_fused, fmt_piped):
-            np.testing.assert_allclose(
-                np.asarray(loops_spmm(fmt_k, b, backend="interpret")),
-                y_base, rtol=tol, atol=tol)
 
     wall_note = (f";wall_g1_us={wall[1] * 1e6:.1f}"
                  f";wall_tuned_us={wall[tuned_g] * 1e6:.1f}"
-                 f";wall_fused_us={wall['fused'] * 1e6:.1f}"
-                 f";wall_piped_us={wall['piped'] * 1e6:.1f}"
                  f";wall_speedup={wall[1] / wall[tuned_g]:.2f}x"
-                 f";wall_speedup_fused="
-                 f"{wall[tuned_g] / wall['fused']:.2f}x"
                  if wall else "")
     out(csv_row(f"fig4_{name_dt}_{mid}_panelG", steps[tuned_g],
                 f"panel_g={tuned_g};steps_g1={steps[1]};"
-                f"steps_tuned={steps[tuned_g]};steps_fused={steps_fused};"
-                f"steps_piped={steps_piped};"
-                f"pipeline_depth={PIPE_DEPTH};macro_m={MACRO_M};"
-                f"step_reduction="
+                f"steps_tuned={steps[tuned_g]};step_reduction="
                 f"{red_tuned:.2f}x;step_reduction_g{g_ref}={red_ref:.2f}x"
                 + wall_note))
     if record is not None:
         record({
             "suite": "fig4_panel", "matrix": mid, "dtype": name_dt,
             "panel_g": tuned_g,
-            "pipeline_depth": PIPE_DEPTH, "macro_m": MACRO_M,
             "steps_g1": steps[1], f"steps_g{g_ref}": steps[g_ref],
             "steps_tuned": steps[tuned_g],
-            "steps_fused": steps_fused,
-            "steps_piped": steps_piped,
             "step_reduction_tuned": red_tuned,
             f"step_reduction_g{g_ref}": red_ref,
-            "step_reduction_piped": steps[1] / max(steps_piped, 1),
             "wall_us_g1": wall.get(1, 0.0) * 1e6,
             "wall_us_tuned": wall.get(tuned_g, 0.0) * 1e6,
-            "wall_us_fused": wall.get("fused", 0.0) * 1e6,
-            "wall_us_piped": wall.get("piped", 0.0) * 1e6,
         })
     return red_ref
 
@@ -192,8 +138,6 @@ def run(dtype=np.float32, scale_rows: int = 1024, out=print, record=None,
             if record is not None:
                 record({"suite": "fig4", "matrix": mid, "dtype": name_dt,
                         "panel_g": plan.panel_g, "nnz": nnz,
-                        "pipeline_depth": getattr(plan, "pipeline_depth", 1),
-                        "macro_m": getattr(plan, "macro_m", 1),
                         "us_per_call": t_loops * 1e6, "gflops": g,
                         "vs_taco": t_taco / t_loops,
                         "vs_dense": t_arma / t_loops})

@@ -47,21 +47,12 @@ def main(out=print):
         t_c = time_fn(f_csr, b)
         g = gflops(nnz, N, t_h)
         # Packed-path wall clock: the Pallas kernels (interpret mode off-TPU)
-        # keep the bf16 B panels packed in scratch and accumulate in fp32 —
-        # measured on the macro-fused depth-2 pipeline vs the serial layout.
-        fmt_piped = loops_from_csr(csr, plan.r_boundary, plan.br,
-                                   panel_g=plan.panel_g, macro_m=4,
-                                   pipeline_depth=2)
+        # keep the bf16 B rows packed and accumulate in fp32.
         f_packed = jax.jit(lambda bb: loops_spmm(fmt, bb,
                                                  backend="interpret"))
-        f_packed_piped = jax.jit(lambda bb: loops_spmm(fmt_piped, bb,
-                                                       backend="interpret"))
         t_p = time_fn(f_packed, b, repeats=2, warmup=1)
-        t_pp = time_fn(f_packed_piped, b, repeats=2, warmup=1)
         out(csv_row(f"fig5_bf16_{mid}_packed", t_p * 1e6,
-                    f"packed_piped_us={t_pp * 1e6:.1f};"
-                    f"pipeline_depth=2;macro_m=4;"
-                    f"piped_speedup={t_p / max(t_pp, 1e-12):.2f}x"))
+                    f"panel_g={plan.panel_g}"))
         # padding waste of the block-only format (zero fraction of tiles)
         tiles = fmt_block.bcsr_part.tile_vals
         waste = 1.0 - (np.count_nonzero(tiles) / max(tiles.size, 1))

@@ -284,19 +284,6 @@ class LoopsFormat:
     access and cached: the Pallas kernels execute the panels, while the
     pure-jnp reference executes the flat ``csr_part``/``bcsr_part`` arrays
     and never pays for the packing — both views hold identical values.
-
-    ``macro_m`` is the macro-step fusion factor: ``macro_m`` consecutive
-    same-(block-)row G-panels are packed into ONE grid step by panelizing at
-    the effective width ``panel_g * macro_m`` (:attr:`panel_g_eff`).  The
-    kernels are macro-blind — they just see wider panels — and tails that
-    don't fill a macro step are validity-safe for free through the existing
-    per-lane padding mask.  Accumulator init/flush and the A-panel load thus
-    amortise over ``macro_m * G`` nonzeros and grid steps shrink ``~M×`` on
-    dense rows.
-
-    ``pipeline_depth`` selects the kernels' software-pipeline depth (1 =
-    serial gather->contract, 2 = double-buffered B-panel prefetch); it does
-    not change the panel layout, only how the engine dispatches it.
     """
 
     csr_part: CSR          # rows [0, r_boundary)
@@ -304,8 +291,6 @@ class LoopsFormat:
     r_boundary: int
     shape: Tuple[int, int]
     panel_g: int = 1
-    macro_m: int = 1
-    pipeline_depth: int = 1
 
     @property
     def nrows(self) -> int:
@@ -315,21 +300,15 @@ class LoopsFormat:
     def ncols(self) -> int:
         return self.shape[1]
 
-    @property
-    def panel_g_eff(self) -> int:
-        """Effective panel width after macro-step fusion: the panels are
-        physically packed at ``panel_g * macro_m`` lanes per grid step."""
-        return max(self.panel_g, 1) * max(self.macro_m, 1)
-
     @functools.cached_property
     def csr_panels(self) -> "PanelCSR":
         with span("loops.panelize", part="csr"):
-            return panelize_csr(self.csr_part, self.panel_g_eff)
+            return panelize_csr(self.csr_part, self.panel_g)
 
     @functools.cached_property
     def bcsr_panels(self) -> "PanelBCSR":
         with span("loops.panelize", part="bcsr"):
-            return panelize_bcsr(self.bcsr_part, self.panel_g_eff)
+            return panelize_bcsr(self.bcsr_part, self.panel_g)
 
     @functools.cached_property
     def nnz(self) -> int:
@@ -636,29 +615,20 @@ def panelize_bcsr(bcsr: VectorBCSR, g: int) -> PanelBCSR:
 # ---------------------------------------------------------------------------
 
 def loops_from_csr(csr: CSR, r_boundary: int, br: int,
-                   panel_g: int = DEFAULT_PANEL_G, *,
-                   macro_m: int = 1,
-                   pipeline_depth: int = 1) -> LoopsFormat:
+                   panel_g: int = DEFAULT_PANEL_G) -> LoopsFormat:
     """Algorithm 1: CSR-part = rows [0, r_boundary), BCSR-part = the rest.
 
     ``panel_g`` is the panel width the Pallas kernels consume (G nonzeros /
     tiles per grid step); the panelized views are derived lazily from the
-    flat arrays on first kernel use.  ``macro_m`` fuses that many
-    consecutive same-row panels into one grid step (the panels pack at
-    ``panel_g * macro_m`` lanes); ``pipeline_depth`` selects the kernels'
-    software-pipeline depth (1 or 2).  Both default to the knob-less
-    layout.
+    flat arrays on first kernel use.
     """
     if not 0 <= r_boundary <= csr.nrows:
         raise ValueError(f"r_boundary {r_boundary} out of range [0, {csr.nrows}]")
-    if macro_m < 1:
-        raise ValueError(f"macro_m must be >= 1, got {macro_m}")
     return LoopsFormat(csr_part=csr_slice_rows(csr, 0, r_boundary),
                        bcsr_part=bcsr_from_csr_rows(csr, r_boundary,
                                                     csr.nrows, br),
                        r_boundary=r_boundary, shape=csr.shape,
-                       panel_g=panel_g, macro_m=macro_m,
-                       pipeline_depth=pipeline_depth)
+                       panel_g=panel_g)
 
 
 def permute_rows(csr: CSR, order: np.ndarray) -> CSR:
@@ -674,8 +644,7 @@ def permute_rows(csr: CSR, order: np.ndarray) -> CSR:
 
 
 def loops_from_csr_sorted(csr: CSR, r_boundary: int, br: int,
-                          panel_g: int = DEFAULT_PANEL_G, *,
-                          macro_m: int = 1, pipeline_depth: int = 1
+                          panel_g: int = DEFAULT_PANEL_G
                           ) -> Tuple[LoopsFormat, np.ndarray]:
     """Beyond-paper variant (§Perf): sort rows by nnz descending before the
     positional split, so scattered hub rows all land in the CSR(vector) part
@@ -687,8 +656,7 @@ def loops_from_csr_sorted(csr: CSR, r_boundary: int, br: int,
     permuted row space (GNN layers don't care about row order)."""
     order = np.argsort(-np.diff(csr.row_ptr), kind="stable").astype(np.int64)
     return loops_from_csr(permute_rows(csr, order), r_boundary, br,
-                          panel_g=panel_g, macro_m=macro_m,
-                          pipeline_depth=pipeline_depth), order
+                          panel_g=panel_g), order
 
 
 # ---------------------------------------------------------------------------
@@ -720,8 +688,7 @@ class TransposedLoops:
 
 
 def loops_from_csr_mapped(csr: CSR, r_boundary: int, br: int,
-                          panel_g: int = DEFAULT_PANEL_G, *,
-                          macro_m: int = 1, pipeline_depth: int = 1
+                          panel_g: int = DEFAULT_PANEL_G
                           ) -> Tuple[LoopsFormat, int, np.ndarray]:
     """Algorithm 1 with value-slot bookkeeping (autodiff transpose variant).
 
@@ -745,8 +712,7 @@ def loops_from_csr_mapped(csr: CSR, r_boundary: int, br: int,
         csr, r_boundary, csr.nrows, br, keep_zeros=True, return_map=True)
     fmt = LoopsFormat(csr_part=csr_part, bcsr_part=bcsr_part,
                       r_boundary=r_boundary, shape=csr.shape,
-                      panel_g=panel_g, macro_m=macro_m,
-                      pipeline_depth=pipeline_depth)
+                      panel_g=panel_g)
     return fmt, csr_len, bcsr_slot
 
 
@@ -809,13 +775,9 @@ def _build_transposed(fmt: LoopsFormat, *, plan=None, tuner=None,
     csr_t, entry_src, entry_slot = _transposed_csr(fmt, live_values)
     if plan is None:
         _, plan = plan_and_convert(csr_t, total_workers=total_workers,
-                                   panel_g=fmt.panel_g or None, tuner=tuner,
-                                   macro_m=fmt.macro_m,
-                                   pipeline_depth=fmt.pipeline_depth)
+                                   panel_g=fmt.panel_g or None, tuner=tuner)
     fmt_t, csr_len, bcsr_slot = loops_from_csr_mapped(
-        csr_t, plan.r_boundary, plan.br, panel_g=plan.panel_g,
-        macro_m=int(getattr(plan, "macro_m", 1)),
-        pipeline_depth=int(getattr(plan, "pipeline_depth", 1)))
+        csr_t, plan.r_boundary, plan.br, panel_g=plan.panel_g)
     tl = TransposedLoops(fmt=fmt_t, plan=plan, entry_src=entry_src,
                          entry_slot=entry_slot, n_slots=csr_t.nnz,
                          csr_len=csr_len, bcsr_slot=bcsr_slot)

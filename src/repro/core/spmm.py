@@ -36,8 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..kernels import engine, ref
-from ..kernels.panel_common import (CSR_WORDS, default_bn, gather_bn,
-                                   panel_calls)
+from ..kernels.panel_common import default_bn, gather_bn
 from ..obs.runtime import span
 from ..resilience import fallback as _resilience
 from . import partition
@@ -59,8 +58,6 @@ class SpmmPlan:
     t_mxu: int      # paper: t_sme  — workers for the BCSR part
     br: int         # tile height (cntd / cntf / cnth analogue)
     panel_g: int = DEFAULT_PANEL_G  # panel width (Fig. 2 multi-tile count)
-    pipeline_depth: int = 1  # kernel software-pipeline depth (1 = serial)
-    macro_m: int = 1         # same-row panels fused per grid step
 
 
 def default_br(dtype) -> int:
@@ -80,8 +77,7 @@ def plan_and_convert(csr: CSR, *, total_workers: int = 8,
                      tp_vpu: float = 1.0, tp_mxu: float = 4.0,
                      br: int | None = None, panel_g: int | None = None,
                      paper_literal: bool = False,
-                     tuner=None, validate: str | None = "strict",
-                     pipeline_depth: int = 1, macro_m: int = 1
+                     tuner=None, validate: str | None = "strict"
                      ) -> tuple[LoopsFormat, SpmmPlan]:
     """Pick (t_vpu, t_mxu) via the perf model, solve Eq. 1, run Algorithm 1.
 
@@ -122,11 +118,9 @@ def plan_and_convert(csr: CSR, *, total_workers: int = 8,
         r_b = partition.choose_r_boundary(
             csr.nrows, tp_vpu, tp_mxu, t_vpu, t_mxu, br=br,
             paper_literal=paper_literal)
-        fmt = loops_from_csr(csr, r_b, br, panel_g=panel_g,
-                             macro_m=macro_m, pipeline_depth=pipeline_depth)
+        fmt = loops_from_csr(csr, r_b, br, panel_g=panel_g)
         return fmt, SpmmPlan(
-            r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br, panel_g=panel_g,
-            pipeline_depth=pipeline_depth, macro_m=macro_m)
+            r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br, panel_g=panel_g)
 
 
 def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
@@ -145,14 +139,13 @@ def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
     has_csr = fmt.r_boundary > 0
     has_bcsr = fmt.r_boundary < fmt.nrows
     pallas = backend != "jnp"   # panel views only materialise for Pallas
-    depth = int(getattr(fmt, "pipeline_depth", 1))
     if (has_csr and has_bcsr and pallas
             and fmt.r_boundary % fmt.bcsr_part.br == 0):
         try:
             return engine.loops_spmm_fused(
                 fmt, b, backend=backend, bn=bn, out_dtype=out_dtype,
                 csr_vals=csr_vals, bcsr_vals=bcsr_vals,
-                pipeline_depth=depth, transposed=transposed)
+                transposed=transposed)
         except Exception as e:   # noqa: BLE001 - the parts path IS the handler
             # The fused chain (pallas → interpret) is exhausted: degrade to
             # the two-pass parts path below, whose per-part chains reach the
@@ -168,12 +161,12 @@ def _loops_execute(fmt: LoopsFormat, b: jax.Array, backend: str, bn,
         parts.append(engine.csr_spmm(
             fmt.csr_part, b, backend=backend, bn=bn, out_dtype=out_dtype,
             panels=fmt.csr_panels if pallas else None, vals=csr_vals,
-            pipeline_depth=depth, transposed=transposed))
+            transposed=transposed))
     if has_bcsr:
         parts.append(engine.bcsr_spmm(
             fmt.bcsr_part, b, backend=backend, bn=bn, out_dtype=out_dtype,
             panels=fmt.bcsr_panels if pallas else None, vals=bcsr_vals,
-            pipeline_depth=depth, transposed=transposed))
+            transposed=transposed))
     if not parts:
         _, out = engine.resolve_dtypes(fmt.csr_part.vals.dtype, out_dtype)
         return jnp.zeros(b.shape[:-2] + (fmt.nrows, b.shape[-1]), out)
@@ -356,9 +349,7 @@ def loops_spmm_values(fmt: LoopsFormat, csr_vals: jax.Array,
         cv, bv, b_ = res
         db = _backward_db(fmt, dy, backend, bn, transpose_plan,
                           csr_vals=cv, bcsr_vals=bv)
-        d_cv, d_bv = engine.loops_sdd(
-            fmt, dy, b_, backend=backend, bn=bn,
-            pipeline_depth=int(getattr(fmt, "pipeline_depth", 1)))
+        d_cv, d_bv = engine.loops_sdd(fmt, dy, b_, backend=backend, bn=bn)
         return (d_cv.astype(cv.dtype), d_bv.astype(bv.dtype),
                 db.astype(b_.dtype))
 
@@ -373,25 +364,19 @@ def loops_grid_steps(fmt: LoopsFormat, n_cols: int,
 
     The CSR grid walks panels, not nonzeros, so its count drops from
     ``nnz_csr * col_blocks`` at G=1 towards a ``~G``-fold reduction
-    (padding at row boundaries is the gap from the ideal); ``macro_m > 1``
-    widens the effective panels (the cached panel views are built at
-    ``panel_g_eff``), and ``pipeline_depth = d`` adds ``d - 1`` fill/drain
-    ramp steps per ``pallas_call`` — one call per SMEM-sized panel chunk
-    (``panel_common.panel_calls``).  The BCSR grid walks window steps,
-    one per 128-lane window of a block row (``PanelBCSR.steps``), once per
-    column block of ``panel_common.gather_bn``, with no ramp.  A part the
-    executor skips contributes nothing.
+    (padding at row boundaries is the gap from the ideal), and its SMEM
+    chunking adds no step.  The BCSR grid walks window steps, one per
+    128-lane window of a block row (``PanelBCSR.steps``), once per column
+    block of ``panel_common.gather_bn``.  A part the executor skips
+    contributes nothing.
     """
     bn = bn or default_bn(n_cols)
-    depth = max(int(getattr(fmt, "pipeline_depth", 1)), 1)
     steps = 0
     # The empty BCSR part is not inherently zero-count
     # (``bcsr_from_csr_rows`` keeps >= 1 structural pad tile even for zero
     # rows), so a skipped part is read off the boundary.
     if fmt.r_boundary > 0:
-        p = fmt.csr_panels.npanels
-        steps += ((p + panel_calls(p, fmt.panel_g_eff, CSR_WORDS)
-                   * (depth - 1)) * -(-n_cols // bn))
+        steps += fmt.csr_panels.npanels * -(-n_cols // bn)
     if fmt.r_boundary < fmt.nrows:
         steps += (fmt.bcsr_panels.steps[0].shape[0]
                   * (n_cols // gather_bn(n_cols, bn)))

@@ -85,9 +85,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import batch_block, register_kernel, resolve_dtypes
-from .panel_common import (LANES, SMEM_PREFETCH_BYTES, check_pipeline_depth,
-                           dot_precision, first_last_at, gather_bn, parity,
-                           window_steps)
+from .panel_common import (LANES, SMEM_PREFETCH_BYTES, dot_precision,
+                           first_last_at, gather_bn, parity, window_steps)
 
 __all__ = ["bcsr_spmm_pallas", "bcsr_panels_spmm_pallas", "gather_layout",
            "steps_per_call"]
@@ -422,8 +421,7 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
                             row_block_offset: int = 0,
                             out_rows: int | None = None,
                             bn: int | None = None, out_dtype=None,
-                            carry: jax.Array | None = None,
-                            pipeline_depth: int = 1, steps=None,
+                            carry: jax.Array | None = None, steps=None,
                             per_call: int | None = None,
                             name: str = "loops_bcsr_spmm") -> jax.Array:
     """Panelized vector-wise BCSR SpMM over window steps.
@@ -452,8 +450,6 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
                   of 128 (``panel_common.gather_bn``), else all of N.
       carry:      optional (..., out_rows, N) array aliased into the output;
                   rows not visited here keep its contents (fused mode).
-      pipeline_depth: accepted for the panel kernels' common signature;
-                  this kernel always prefetches one step ahead.
       steps:      the host step table ``(step_rows, step_starts)``
                   (``PanelBCSR.steps``); built from ``panel_rows`` when
                   omitted.
@@ -466,7 +462,6 @@ def bcsr_panels_spmm_pallas(panel_rows, lane_cols, vals_window, b, *,
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
                          f"{b.ndim}")
-    check_pipeline_depth(pipeline_depth)
     step_rows, step_starts = (steps if steps is not None
                               else window_steps(np.asarray(panel_rows), g))
     per, lanes = steps_per_call(step_starts, per_call)

@@ -66,9 +66,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import batch_block, register_kernel, resolve_dtypes
-from .panel_common import (CSR_WORDS, check_pipeline_depth, default_bn,
-                           first_last, first_last_at, grid_dims, init_acc,
-                           panel_operands, parity, row_view,
+from .panel_common import (CSR_WORDS, default_bn, first_last, grid_dims,
+                           init_acc, panel_operands, row_view,
                            run_panel_chunks, split_panel_refs)
 from .panel_common import panels_per_call as default_panels_per_call
 
@@ -115,70 +114,15 @@ def _panel_kernel(g: int, has_carry: bool, bz: int | None, *refs):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _piped_panel_kernel(g: int, has_carry: bool, bz: int | None, depth: int,
-                        *refs):
-    """Depth-2 software pipeline: grid step ``k`` assembles panel
-    ``min(k, P-1)``'s B rows into ping-pong scratch slot ``k % 2`` while
-    contracting panel ``max(k - 1, 0)`` out of slot ``(k+1) % 2`` — the B
-    gathers of the next panel overlap the AXPY of the current one.  The
-    grid carries ``depth - 1`` extra fill/drain ramp steps; compute, init
-    and flush are predicated off during the fill ramp."""
-    (prev_ref, rows_ref, cols_ref, vals_ref), _, carry_ref, b_refs, \
-        (o_ref, bpan_ref, acc_ref) = \
-        split_panel_refs(refs, g, 4, 0, has_carry)
-    axis = 1 if bz is None else 2
-    k = pl.program_id(axis)
-    npanels = pl.num_programs(axis) - (depth - 1)
-
-    def _assemble(slot):
-        # Stage the raw (packed-dtype) B rows; the compute stream applies
-        # the lane mask exactly like the depth-1 kernel, so results stay
-        # bitwise identical.
-        for i, b_ref in enumerate(b_refs):
-            bpan_ref[slot, i] = b_ref[...]
-
-    for s in (0, 1):
-        @pl.when(parity(k) == s)
-        def _(s=s):
-            _assemble(s)
-
-    @pl.when(k >= depth - 1)
-    def _compute():
-        c = jnp.maximum(k - (depth - 1), 0)
-        first, last = first_last_at(rows_ref, c, npanels)
-
-        @pl.when(first)
-        def _init():
-            init_acc(acc_ref, carry_ref, prev_ref, rows_ref, c)
-
-        def _accumulate(slot):
-            acc = acc_ref[...]
-            for i in range(g):
-                acc = _axpy(acc, cols_ref, vals_ref, c * g + i,
-                            bpan_ref[slot, i])
-            acc_ref[...] = acc
-
-        for s in (0, 1):
-            @pl.when(parity(k + 1) == s)
-            def _(s=s):
-                _accumulate(s)
-
-        @pl.when(last)
-        def _flush():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("g", "nrows", "out_rows", "bn", "out_dtype",
-                     "interpret", "pipeline_depth", "panels_per_call",
-                     "name"))
+                     "interpret", "panels_per_call", "name"))
 def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                            lane_vals: jax.Array, b: jax.Array, *, g: int,
                            nrows: int, interpret: bool,
                            out_rows: int | None = None, bn: int | None = None,
                            out_dtype=None, carry: jax.Array | None = None,
-                           pipeline_depth: int = 1,
                            panels_per_call: int | None = None,
                            name: str = "loops_csr_spmm") -> jax.Array:
     """C[r] += sum_i vals[p,i] * B[cols[p,i], :] over the real lanes of
@@ -207,13 +151,6 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                   trick (several 128-lane tiles per visit).
       carry:      optional (..., out_rows, N) array aliased into the output;
                   rows not visited here keep its contents (fused mode).
-      pipeline_depth: 1 (serial gather->contract, default) or 2 (double-
-                  buffered B-panel prefetch: the next panel's rows assemble
-                  into a ping-pong VMEM slot while this panel contracts).
-                  Unbatched results are bitwise identical across depths
-                  (the compute stream replays the depth-1 expression);
-                  batched results agree to ~1 ulp (XLA's multiply-add
-                  contraction differs across the two graphs).
       panels_per_call: panels per ``pallas_call`` (default
                   ``panel_common.panels_per_call(G, CSR_WORDS)``, the SMEM
                   bound for prefetched columns and values); more panels
@@ -224,7 +161,6 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
     if b.ndim not in (2, 3):
         raise ValueError(f"b must be (K, N) or (batch, K, N); got rank "
                          f"{b.ndim}")
-    depth = check_pipeline_depth(pipeline_depth)
     n = b.shape[-1]
     bn = bn or default_bn(n)
     if n % bn:
@@ -243,52 +179,30 @@ def csr_panels_spmm_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
     def call(prev, rows, cols, vals, acc_in):
         npanels = rows.shape[0]
         has_carry = acc_in is not None
-        grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels,
-                            pipeline_depth=depth)
+        grid, _ = grid_dims(batch=batch, bz=bz, n=n, bn=bn, npanels=npanels)
         in_specs, args, aliases = panel_operands(
             g=g, bn=bn, b=b_view,
             carry=None if acc_in is None else row_view(acc_in, row_axis),
             carry_block=(None, 1, bn), row_map=_rows,
-            bz=None if batch is None else bz, pipeline_depth=depth,
-            npanels=npanels)
-
-        if depth == 1:
-            def _out_k(k):
-                return k
-        else:
-            def _out_k(k):
-                return jnp.maximum(k - (depth - 1), 0)
-
+            bz=None if batch is None else bz)
         if batch is None:
             out_specs = pl.BlockSpec(
-                (None, 1, bn), lambda j, k, *s: _rows(s[1], _out_k(k), j))
+                (None, 1, bn), lambda j, k, *s: _rows(s[1], k, j))
             acc_shape = (1, bn)
-            bpan_shape = (depth, g, 1, bn)
         else:
             out_specs = pl.BlockSpec(
                 (bz, None, 1, bn),
-                lambda z, j, k, *s: (z,) + _rows(s[1], _out_k(k), j))
+                lambda z, j, k, *s: (z,) + _rows(s[1], k, j))
             acc_shape = (bz, 1, bn)
-            bpan_shape = (depth, g, bz, 1, bn)
-
-        scratch = [pltpu.VMEM(acc_shape, acc_dtype)]
-        if depth > 1:
-            # Ping-pong B-panel buffer, packed in B's storage dtype (half
-            # precision stays half-width in VMEM; promotion happens at the
-            # multiply against the fp32-resident accumulator).
-            scratch.insert(0, pltpu.VMEM(bpan_shape, b.dtype))
-            kernel = functools.partial(_piped_panel_kernel, g, has_carry,
-                                       None if batch is None else bz, depth)
-        else:
-            kernel = functools.partial(_panel_kernel, g, has_carry,
-                                       None if batch is None else bz)
+        kernel = functools.partial(_panel_kernel, g, has_carry,
+                                   None if batch is None else bz)
 
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,  # prev_row, rows, signed cols, values
             grid=grid,
             in_specs=in_specs,
             out_specs=out_specs,
-            scratch_shapes=scratch,
+            scratch_shapes=[pltpu.VMEM(acc_shape, acc_dtype)],
         )
         out = pl.pallas_call(
             kernel,

@@ -248,37 +248,15 @@ def _const_bytes(*arrays) -> int:
     return int(sum(a.nbytes for a in arrays if isinstance(a, np.ndarray)))
 
 
-def _panel_note_fields(*, part: str, depth: int, npanels: int, nb: int,
-                       n: int, bn: int | None, g: int, br: int,
-                       b_dtype, value_dtype) -> dict:
-    """Pipeline observability fields for a G-wide CSR panel dispatch.
-
-    ``steps`` — grid steps including the ``depth - 1`` fill/drain ramp of
-    each ``pallas_call`` (one per SMEM-sized panel chunk)
-    (× batch groups, matching ``_note``'s default accounting at depth 1);
-    ``scratch_bytes`` — VMEM scratch footprint (accumulator + the packed
-    ping-pong B-panel buffer of depth 2, which stays in B's storage
-    dtype);
-    ``prefetch_overlap`` — fraction of grid steps whose B-row gathers
-    overlap a contraction (0.0 for the serial depth-1 kernels).
-    """
-    from .panel_common import CSR_WORDS, default_bn, panel_calls
-    groups = max(-(-nb // batch_block(nb)), 1)
-    bz = batch_block(nb)
-    bn_eff = bn or default_bn(n)
-    acc = acc_dtype_for(value_dtype)
-    scratch = bz * br * bn_eff * jnp.dtype(acc).itemsize
-    b_item = jnp.dtype(b_dtype).itemsize
-    # depth-1 CSR reads gathered B rows directly (no staging buffer)
-    bpan_elems = depth * g * bn_eff * bz if depth > 1 else 0
-    # Each SMEM-sized chunk is its own launch with its own ramp.
-    calls = panel_calls(npanels, g, CSR_WORDS if part == "csr" else 1)
-    steps = npanels + calls * (depth - 1)
-    overlap = (max(npanels - calls, 0) / steps) if depth > 1 else 0.0
-    return {"pipeline_depth": depth,
-            "steps": steps * groups,
-            "scratch_bytes": int(scratch + bpan_elems * b_item),
-            "prefetch_overlap": float(overlap)}
+def _panel_note_fields(*, nb: int, n: int, bn: int | None,
+                       value_dtype) -> dict:
+    """Dispatch facts of the CSR panel kernel beyond ``_note``'s default
+    step count: ``scratch_bytes`` — its VMEM scratch, the accumulator alone
+    (the kernel reads its gathered B rows in place)."""
+    from .panel_common import default_bn
+    acc = jnp.dtype(acc_dtype_for(value_dtype)).itemsize
+    return {"scratch_bytes": int(batch_block(nb) * (bn or default_bn(n))
+                                 * acc)}
 
 
 def _bcsr_note_fields(panels, *, nb: int, n: int, bn: int | None,
@@ -292,26 +270,22 @@ def _bcsr_note_fields(panels, *, nb: int, n: int, bn: int | None,
     included) whatever the width, so ``dmas / gathers`` is a little
     above 1;
     ``scratch_bytes`` — the accumulator, the two-slot 32-bit B stage and
-    the lane-group column; ``prefetch_overlap`` — steps whose rows the
-    step before fetched (all but the first of each call's sweep)."""
-    from .bcsr_spmm import gather_layout, steps_per_call
+    the lane-group column."""
+    from .bcsr_spmm import gather_layout
     from .panel_common import LANES
     rows, starts = panels.steps
     bz = batch_block(nb)
     bn, width, _ = gather_layout(n, bn)
     sweeps = (n // bn) * max(-(-nb // bz), 1)
     nsteps = int(rows.shape[0])
-    calls = -(-nsteps // steps_per_call(starts)[0])
     acc = jnp.dtype(acc_dtype_for(value_dtype)).itemsize
     scratch = (bz * panels.br * width * acc + 2 * bz * LANES * width * 4
                + 2 * LANES * 4)
-    return {"pipeline_depth": 1,
-            "units": nsteps,
+    return {"units": nsteps,
             "steps": nsteps * sweeps,
             "gathers": int(np.count_nonzero(panels.lane_cols >= 0)) * sweeps,
             "dmas": int(starts[-1]) * sweeps,
-            "scratch_bytes": int(scratch),
-            "prefetch_overlap": max(nsteps - calls, 0) / max(nsteps, 1)}
+            "scratch_bytes": int(scratch)}
 
 
 # ---------------------------------------------------------------------------
@@ -391,17 +365,15 @@ def spmm_kernel_name(part: str, transposed: bool) -> str:
 
 def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
              bn: int | None = None, out_dtype=None, panels=None,
-             vals=None, pipeline_depth: int = 1,
-             transposed: bool = False) -> jax.Array:
+             vals=None, transposed: bool = False) -> jax.Array:
     """SpMM of a ``repro.core.formats.CSR`` against dense ``b`` (..., K, N).
 
     ``panels`` — a ``repro.core.formats.PanelCSR`` view of the same matrix —
     routes the Pallas backends through the G-wide panel kernel.  ``vals`` —
     optional traced (nnz,) values replacing ``csr.vals``.  Leading batch
     dims of ``b`` execute as the kernels' native batch grid dimension.
-    ``pipeline_depth=2`` double-buffers the B-row gathers on the panel
-    kernel (ignored by the flat and jnp paths).  ``transposed`` names the
-    panel kernel for a custom VJP's Aᵀ (:func:`spmm_kernel_name`).
+    ``transposed`` names the panel kernel for a custom VJP's Aᵀ
+    (:func:`spmm_kernel_name`).
     """
     backend = resolve_backend(backend)
     check_rhs(csr.ncols, b)
@@ -424,11 +396,9 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
         b3, batch = flatten_batch(b)
         b3p = _pad_flat_batch(b3)
         nb = int(b3p.shape[0]) if b3p.ndim == 3 else 1
-        depth = int(pipeline_depth) if panels is not None else 1
         extra = _panel_note_fields(
-            part="csr", depth=depth, npanels=int(panels.npanels), nb=nb,
-            n=int(b.shape[-1]), bn=bn, g=int(panels.g), br=1,
-            b_dtype=b.dtype, value_dtype=v.dtype) if panels is not None else {}
+            nb=nb, n=int(b.shape[-1]), bn=bn,
+            value_dtype=v.dtype) if panels is not None else {}
         _note("csr", "spmm", backend=bk,
               impl="panels" if panels is not None else "flat",
               units=int(panels.npanels) if panels is not None
@@ -442,8 +412,7 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
                 jnp.asarray(panels.panel_rows), jnp.asarray(panels.lane_cols),
                 panel_values(panels, vals), b3p, g=panels.g,
                 nrows=csr.nrows, bn=bn, out_dtype=out_dtype,
-                interpret=interpret, pipeline_depth=depth,
-                name=spmm_kernel_name("csr", transposed))
+                interpret=interpret, name=spmm_kernel_name("csr", transposed))
         else:
             out = get_kernel("csr", "spmm", "flat")(
                 jnp.asarray(csr.row_ids), jnp.asarray(csr.col_idx), v, b3p,
@@ -458,8 +427,7 @@ def csr_spmm(csr, b: jax.Array, *, backend: str | None = None,
 
 def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
               bn: int | None = None, out_dtype=None, panels=None,
-              vals=None, pipeline_depth: int = 1,
-              transposed: bool = False) -> jax.Array:
+              vals=None, transposed: bool = False) -> jax.Array:
     """SpMM of a ``repro.core.formats.VectorBCSR`` against dense ``b``.
 
     Returns the *logical* (..., bcsr.nrows, N) result (padding rows
@@ -500,8 +468,8 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
                 panels.panel_rows, jnp.asarray(panels.lane_cols),
                 panel_values(panels, vals), b3p, g=panels.g,
                 nblocks=panels.nblocks, bn=bn, out_dtype=out_dtype,
-                interpret=interpret, pipeline_depth=pipeline_depth,
-                steps=panels.steps, name=spmm_kernel_name("bcsr", transposed))
+                interpret=interpret, steps=panels.steps,
+                name=spmm_kernel_name("bcsr", transposed))
         else:
             _note("bcsr", "spmm", backend=bk, impl="flat",
                   units=int(bcsr.ntiles), batch=nb, n=int(b.shape[-1]),
@@ -520,7 +488,6 @@ def bcsr_spmm(bcsr, b: jax.Array, *, backend: str | None = None,
 def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
                      bn: int | None = None, out_dtype=None,
                      csr_vals=None, bcsr_vals=None,
-                     pipeline_depth: int = 1,
                      transposed: bool = False) -> jax.Array:
     """Single-pass hybrid SpMM into ONE preallocated output.
 
@@ -559,15 +526,12 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
         b3, batch = flatten_batch(b)
         b3p = _pad_flat_batch(b3)
         nb = int(b3p.shape[0]) if b3p.ndim == 3 else 1
-        depth = int(pipeline_depth)
         vdt = fmt.csr_part.vals.dtype
         _note("csr", "spmm", backend=bk, impl="panels", fused=True,
               units=int(cp.npanels), batch=nb, n=int(b.shape[-1]),
               const_bytes=_panel_const_bytes(cp, csr_vals),
-              **_panel_note_fields(
-                  part="csr", depth=depth, npanels=int(cp.npanels), nb=nb,
-                  n=int(b.shape[-1]), bn=bn, g=int(cp.g), br=1,
-                  b_dtype=b.dtype, value_dtype=vdt))
+              **_panel_note_fields(nb=nb, n=int(b.shape[-1]), bn=bn,
+                                   value_dtype=vdt))
         _note("bcsr", "spmm", backend=bk, impl="panels", fused=True,
               batch=nb, n=int(b.shape[-1]),
               const_bytes=_panel_const_bytes(bp, bcsr_vals),
@@ -578,8 +542,7 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
             jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols),
             panel_values(cp, csr_vals), b3p, g=cp.g, nrows=r_b,
             out_rows=r_pad, bn=bn, out_dtype=out_dtype,
-            interpret=interpret, pipeline_depth=depth,
-            name=spmm_kernel_name("csr", transposed))
+            interpret=interpret, name=spmm_kernel_name("csr", transposed))
         out = get_kernel("bcsr", "spmm", "panels")(
             bp.panel_rows, jnp.asarray(bp.lane_cols),
             panel_values(bp, bcsr_vals), b3p, g=bp.g, nblocks=bp.nblocks,
@@ -599,8 +562,7 @@ def loops_spmm_fused(fmt, b: jax.Array, *, backend: str | None = None,
 
 
 def loops_sdd(fmt, dy: jax.Array, b: jax.Array, *,
-              backend: str | None = None, bn: int | None = None,
-              pipeline_depth: int = 1):
+              backend: str | None = None, bn: int | None = None):
     """Gradient of ``Y = A @ B`` w.r.t. A's stored values (both parts).
 
     Args:
@@ -628,22 +590,21 @@ def loops_sdd(fmt, dy: jax.Array, b: jax.Array, *,
         raise ValueError(f"dy batch dims {dy.shape[:-2]} do not match b "
                          f"batch dims {b.shape[:-2]}")
     if backend == "jnp" or _empty_batch(b):
-        return _loops_sdd_impl(fmt, dy, b, backend, bn, pipeline_depth)
+        return _loops_sdd_impl(fmt, dy, b, backend, bn)
 
     def attempt(bk: str):
         if bk == "jnp":
-            return _loops_sdd_impl(fmt, dy, b, bk, bn, pipeline_depth)
+            return _loops_sdd_impl(fmt, dy, b, bk, bn)
 
         @jax.custom_batching.custom_vmap
         def call(dy_, b_):
-            return _loops_sdd_impl(fmt, dy_, b_, bk, bn, pipeline_depth)
+            return _loops_sdd_impl(fmt, dy_, b_, bk, bn)
 
         @call.def_vmap
         def _vmap_rule(axis_size, in_batched, dy_, b_):
             dy_b, b_b = in_batched
             outs = [loops_sdd(fmt, dy_[i] if dy_b else dy_,
-                              b_[i] if b_b else b_, backend=bk, bn=bn,
-                              pipeline_depth=pipeline_depth)
+                              b_[i] if b_b else b_, backend=bk, bn=bn)
                     for i in range(axis_size)]
             return (jnp.stack([o[0] for o in outs]),
                     jnp.stack([o[1] for o in outs])), (True, True)
@@ -653,7 +614,7 @@ def loops_sdd(fmt, dy: jax.Array, b: jax.Array, *,
     return _fallback.run_chain("loops", "sdd", backend, attempt)
 
 
-def _loops_sdd_impl(fmt, dy, b, backend, bn, pipeline_depth=1):
+def _loops_sdd_impl(fmt, dy, b, backend, bn):
     """The actual SDD dispatch (batch summed); see :func:`loops_sdd`."""
     csr, bc = fmt.csr_part, fmt.bcsr_part
     nblocks, br = bc.nblocks, bc.br
@@ -689,26 +650,22 @@ def _loops_sdd_impl(fmt, dy, b, backend, bn, pipeline_depth=1):
     dy_pad3 = _pad_flat_batch(flatten_batch(dy_pad)[0])
     cp, bp = fmt.csr_panels, fmt.bcsr_panels
     nb = int(b3.shape[0]) if b3.ndim == 3 else 1
-    depth = int(pipeline_depth)
     if has_csr:
         _note("csr", "sdd", backend=backend, impl="panels",
-              units=int(cp.npanels), batch=nb, n=int(b.shape[-1]),
-              pipeline_depth=depth)
+              units=int(cp.npanels), batch=nb, n=int(b.shape[-1]))
     if has_bcsr:
         _note("bcsr", "sdd", backend=backend, impl="panels",
-              units=int(bp.npanels), batch=nb, n=int(b.shape[-1]),
-              pipeline_depth=depth)
+              units=int(bp.npanels), batch=nb, n=int(b.shape[-1]))
     if has_csr:
         d_csr = cp.gather_values(get_kernel("csr", "sdd", "panels")(
             jnp.asarray(cp.panel_rows), jnp.asarray(cp.lane_cols), dy3, b3,
-            g=cp.g, bn=bn, interpret=interpret, pipeline_depth=depth))
+            g=cp.g, bn=bn, interpret=interpret))
     else:
         d_csr = jnp.zeros((csr.nnz,), acc)
     if has_bcsr:
         d_bcsr = bp.gather_values(get_kernel("bcsr", "sdd", "panels")(
             jnp.asarray(bp.panel_rows), jnp.asarray(bp.lane_cols), dy_pad3,
-            b3, g=bp.g, br=br, bn=bn, interpret=interpret,
-            pipeline_depth=depth))
+            b3, g=bp.g, br=br, bn=bn, interpret=interpret))
     else:
         d_bcsr = jnp.zeros(bc.tile_vals.shape, acc)
     return d_csr, d_bcsr

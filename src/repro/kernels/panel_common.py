@@ -43,16 +43,6 @@ scalar-prefetch panel metadata is shared across the batch — A's static
 panel layout is loaded once per grid step and applied to all ``bz``
 slices.  ``grid_dims`` centralises the two grid layouts so the kernels'
 ``first``/``last`` revisit predicates can never disagree with the specs.
-
-Pipelining (``pipeline_depth=2``): the panel axis is stretched by
-``depth - 1`` ramp steps and the load/compute streams are skewed one step
-apart — grid step ``k`` *assembles* panel ``lidx(k) = min(k, P-1)``'s B rows
-into the ping-pong scratch slot ``k % 2`` while it *contracts* panel
-``cidx(k) = max(k - (depth-1), 0)`` out of slot ``(k+1) % 2``.  The B-row
-gathers (the dominant DMA traffic) for panel ``p+1`` thus overlap the MXU
-contraction of panel ``p``.  ``pipeline_index`` builds the two index maps;
-with ``depth=1`` both are the identity and every spec below is exactly the
-unpipelined layout.
 """
 from __future__ import annotations
 
@@ -61,24 +51,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["CARRY_OPERAND_INDEX", "CSR_WORDS", "PIPELINE_DEPTHS",
-           "SMEM_PREFETCH_BYTES",
-           "check_pipeline_depth", "default_bn", "dot_precision",
-           "first_last", "first_last_at", "gather_col", "grid_dims",
-           "init_acc", "panel_calls", "panel_operands", "panels_per_call",
-           "lane_cols", "parity", "pipeline_index", "row_view",
-           "run_panel_chunks", "split_panel_refs", "values_window",
-           "window_lanes", "window_steps", "block_row_steps", "gather_bn",
-           "LANES"]
+__all__ = ["CARRY_OPERAND_INDEX", "CSR_WORDS", "SMEM_PREFETCH_BYTES",
+           "default_bn", "dot_precision", "first_last", "first_last_at",
+           "gather_col", "grid_dims", "init_acc", "panel_calls",
+           "panel_operands", "panels_per_call", "lane_cols", "parity",
+           "row_view", "run_panel_chunks", "split_panel_refs",
+           "values_window", "window_lanes", "window_steps",
+           "block_row_steps", "gather_bn", "LANES"]
 
 # Position of the fused-path carry among ALL pallas_call operands of the CSR
 # kernel (scalar prefetch included): prev_row(0), rows(1), cols(2), vals(3),
 # carry(4).
 CARRY_OPERAND_INDEX = 4
-
-# Supported software-pipeline depths: 1 = today's serial gather->contract
-# kernels, 2 = double-buffered B-panel prefetch (ping-pong scratch).
-PIPELINE_DEPTHS = (1, 2)
 
 # SMEM bytes one pallas_call may spend on its scalar-prefetched panel
 # metadata: ``panel_rows`` (P,) plus ``words_per_lane`` flat (P·G,) 32-bit
@@ -131,7 +115,7 @@ def panels_per_call(g: int, words_per_lane: int = 1) -> int:
 def panel_calls(npanels: int, g: int, words_per_lane: int = 1,
                 per_call: int | None = None) -> int:
     """``pallas_call`` launches (chunks) needed for ``npanels`` panels of
-    width ``g`` — each launch adds its own pipeline ramp steps."""
+    width ``g``."""
     per = per_call or panels_per_call(g, words_per_lane)
     return max(-(-int(npanels) // per), 1)
 
@@ -228,39 +212,13 @@ def parity(k):
     return jax.lax.rem(k, jnp.asarray(2, k.dtype))
 
 
-def check_pipeline_depth(pipeline_depth: int) -> int:
-    depth = int(pipeline_depth)
-    if depth not in PIPELINE_DEPTHS:
-        raise ValueError(f"pipeline_depth must be one of {PIPELINE_DEPTHS}, "
-                         f"got {pipeline_depth}")
-    return depth
-
-
-def pipeline_index(depth: int, npanels: int):
-    """``(lidx, cidx)`` index maps for a depth-deep panel pipeline.
-
-    ``lidx(k)`` is the panel whose B rows grid step ``k`` loads (clamped to
-    the last panel during the drain); ``cidx(k)`` is the panel it contracts
-    (clamped to 0 during the fill ramp — compute is predicated off there,
-    the clamp only keeps the block indices in range).  ``depth=1`` returns
-    identities, reproducing the unpipelined specs exactly.
-    """
-    if depth == 1:
-        return (lambda k: k), (lambda k: k)
-    return (lambda k: jnp.minimum(k, npanels - 1),
-            lambda k: jnp.maximum(k - (depth - 1), 0))
-
-
-def grid_dims(*, batch: int | None, bz: int, n: int, bn: int, npanels: int,
-              pipeline_depth: int = 1):
+def grid_dims(*, batch: int | None, bz: int, n: int, bn: int, npanels: int):
     """``(grid, panel_axis)`` for a panel kernel: the panel axis is always
     innermost (the accumulator-revisit protocol needs all panels of a row
-    consecutive); batched calls prepend a batch-block axis.  A depth-``d``
-    pipeline stretches the panel axis by ``d - 1`` fill/drain ramp steps."""
-    steps = npanels + check_pipeline_depth(pipeline_depth) - 1
+    consecutive); batched calls prepend a batch-block axis."""
     if batch is None:
-        return (n // bn, steps), 1
-    return (batch // bz, n // bn, steps), 2
+        return (n // bn, npanels), 1
+    return (batch // bz, n // bn, npanels), 2
 
 
 def first_last(rows_ref, panel_axis: int = 1):
@@ -273,9 +231,8 @@ def first_last(rows_ref, panel_axis: int = 1):
 
 def first_last_at(rows_ref, c, npanels):
     """(first, last) revisit predicates evaluated at an explicit panel
-    index ``c`` over ``npanels`` panels — the pipelined kernels compute
-    panel ``cidx(k)``, not panel ``k``, so the predicates must follow the
-    compute stream, not the grid step."""
+    (or window step) index ``c`` over ``npanels`` of them — the BCSR
+    kernel's step index, which its grid axis shares with nothing else."""
     row_here = rows_ref[c]
     row_prev = rows_ref[jnp.maximum(c - 1, 0)]
     row_next = rows_ref[jnp.minimum(c + 1, npanels - 1)]
@@ -319,9 +276,8 @@ def split_panel_refs(refs, g: int, n_scalar: int, n_lead: int,
 
 
 def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
-                   row_map=None, bz: int | None = None,
-                   pipeline_depth: int = 1, npanels: int | None = None):
-    """Assemble the tensor-operand train shared by both panel kernels.
+                   row_map=None, bz: int | None = None):
+    """Assemble the tensor-operand train of the CSR panel kernel.
 
     Index maps receive the grid indices, then the scalar-prefetch refs
     ``(prev_row, rows, cols, ...)``.
@@ -334,20 +290,12 @@ def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
       carry_block: block shape of the carry, squeezed dims as ``None``.
       bz:          batch slices per grid step, or None for the unbatched
                    layout.
-      pipeline_depth / npanels: skew the load stream (B gathers, indexed at
-                   ``lidx(k)``) ``depth - 1`` steps ahead of the compute
-                   stream (values window + carry, indexed at ``cidx(k)``).
-                   ``depth=1`` keeps both at ``k`` — today's layout.
 
     Returns ``(in_specs, args, input_output_aliases)``: the optional
     aliased carry, then G gathers of ``b`` indexed by
     the scalar-prefetched, signed ``panel_cols`` — one DMA stream per panel
     lane, ``bz`` batch slices wide when batched.
     """
-    depth = check_pipeline_depth(pipeline_depth)
-    if depth > 1 and npanels is None:
-        raise ValueError("pipelined panel_operands needs npanels")
-    lidx, cidx = pipeline_index(depth, npanels if npanels is not None else 0)
     grid_lead = (lambda z: ()) if bz is None else (lambda z: (z,))
     if bz is None:
         def spec(block, index):
@@ -360,14 +308,14 @@ def panel_operands(*, g: int, bn: int, b, carry=None, carry_block=None,
     if carry is not None:
         lead = () if bz is None else (bz,)
         in_specs.append(spec(lead + tuple(carry_block), lambda z, j, k, s:
-                             grid_lead(z) + row_map(s[1], cidx(k), j)))
+                             grid_lead(z) + row_map(s[1], k, j)))
         args.append(carry)
         aliases = {CARRY_OPERAND_INDEX: 0}
     b_block = (None, 1, bn) if bz is None else (bz, None, 1, bn)
     for i in range(g):
         in_specs.append(spec(b_block, lambda z, j, k, s, i=i:
                              grid_lead(z)
-                             + (gather_col(s[2], lidx(k) * g + i), 0, j)))
+                             + (gather_col(s[2], k * g + i), 0, j)))
         args.append(b)
     return in_specs, args, aliases
 

@@ -54,51 +54,35 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .engine import acc_dtype_for, batch_block, register_kernel
-from .panel_common import (check_pipeline_depth, default_bn, dot_precision,
-                           gather_col, panels_per_call, parity, row_view)
+from .panel_common import (default_bn, dot_precision, gather_col,
+                           panels_per_call, row_view)
 
 __all__ = ["csr_sdd_panels_pallas", "bcsr_sdd_panels_pallas"]
 
 
-def _reduction_edges(bz: int | None, depth: int = 1):
+def _reduction_edges(bz: int | None):
     """(first, last) predicates over the per-panel reduction axes — the
     column blocks and, when batched, the batch blocks — shared by both SDD
-    kernels so init/flush can never disagree with the grid layout.  A
-    depth-``d`` pipeline skews the compute stream ``d - 1`` steps behind
-    the column-block grid axis (the fill-ramp steps are load-only), so the
-    reduction opens at ``j == depth - 1`` instead of 0."""
+    kernels so init/flush can never disagree with the grid layout."""
     if bz is None:
         j = pl.program_id(1)
         nb = pl.num_programs(1)
-        return j == depth - 1, j == nb - 1
+        return j == 0, j == nb - 1
     z, j = pl.program_id(1), pl.program_id(2)
     nz, nb = pl.num_programs(1), pl.num_programs(2)
-    return jnp.logical_and(z == 0, j == depth - 1), \
+    return jnp.logical_and(z == 0, j == 0), \
         jnp.logical_and(z == nz - 1, j == nb - 1)
 
 
-def _sdd_col_maps(depth: int, nb: int):
-    """``(lj, cj)`` column-block index maps for the SDD reduction axis:
-    grid step ``jj`` loads B's column block ``lj(jj) = min(jj, nb-1)`` and
-    reduces the cotangent's column block ``cj(jj) = max(jj - (depth-1), 0)``.
-    Identity maps at depth 1."""
-    if depth == 1:
-        return (lambda jj: jj), (lambda jj: jj)
-    return (lambda jj: jnp.minimum(jj, nb - 1),
-            lambda jj: jnp.maximum(jj - (depth - 1), 0))
-
-
-def _gather_rows(b_refs, bpan_ref, bz: int | None, slot=None):
+def _gather_rows(b_refs, bpan_ref, bz: int | None):
     """Copy the G gathered B rows into the ``(G, bn)`` panel scratch
-    (``(bz, G, bn)`` when batched, behind a leading ping-pong ``slot`` axis
-    when pipelined), packed in B's storage dtype."""
-    lead = () if slot is None else (slot,)
+    (``(bz, G, bn)`` when batched), packed in B's storage dtype."""
     for i, b_ref in enumerate(b_refs):
         row = b_ref[...].astype(bpan_ref.dtype)   # (1, bn) / (bz, 1, bn)
         if bz is None:
-            bpan_ref[lead + (pl.ds(i, 1), slice(None))] = row
+            bpan_ref[pl.ds(i, 1), :] = row
         else:
-            bpan_ref[lead + (slice(None), pl.ds(i, 1), slice(None))] = row
+            bpan_ref[:, pl.ds(i, 1), :] = row
 
 
 def _contract_dy(acc_ref, dy, bpan, bz: int | None):
@@ -138,39 +122,6 @@ def _sdd_kernel(g: int, bz: int | None, *refs):
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def _piped_sdd_kernel(g: int, bz: int | None, depth: int, *refs):
-    """Depth-2 SDD pipeline over the column-block reduction axis: step
-    ``jj`` copies B's column block ``min(jj, nb-1)`` into ping-pong scratch
-    slot ``jj % 2`` (packed in B's storage dtype) while contracting the
-    cotangent's column block ``max(jj - 1, 0)`` against slot
-    ``(jj+1) % 2``."""
-    _, _, dy_ref, *rest = refs
-    b_refs, (o_ref, bpan_ref, acc_ref) = rest[:g], rest[g:]
-    jaxis = 1 if bz is None else 2
-    jj = pl.program_id(jaxis)
-    first, last = _reduction_edges(bz, depth)
-
-    for s in (0, 1):
-        @pl.when(parity(jj) == s)
-        def _(s=s):
-            _gather_rows(b_refs, bpan_ref, bz, slot=s)
-
-    @pl.when(jj >= depth - 1)
-    def _compute():
-        @pl.when(first)
-        def _init():
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        for s in (0, 1):
-            @pl.when(parity(jj + 1) == s)
-            def _(s=s):
-                _contract_dy(acc_ref, dy_ref[...], bpan_ref[s], bz)
-
-        @pl.when(last)
-        def _flush():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
 def _run_chunks(call, rows, cols, g: int, per_call: int):
     """``call(rows, cols)`` over SMEM-sized chunks of the panel axis: a
     ``lax.fori_loop`` over the full chunks (one compiled kernel) plus a
@@ -198,14 +149,13 @@ def _run_chunks(call, rows, cols, g: int, per_call: int):
 
 
 def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
-                bn, interpret: bool, pipeline_depth: int, per_call):
+                bn, interpret: bool, per_call):
     """Shared entry of both SDD kernels: ``br=None`` is the CSR part
     (one cotangent row per panel, addressed through its ``(M, 1, N)``
     view), an int the BCSR part (one ``(Br, bn)`` block-row slab)."""
     if dy.ndim != b.ndim or b.ndim not in (2, 3):
         raise ValueError(f"dy/b must both be rank 2 or 3; got {dy.ndim} / "
                          f"{b.ndim}")
-    depth = check_pipeline_depth(pipeline_depth)
     n = b.shape[-1]
     bn = bn or default_bn(n)
     if n % bn:
@@ -213,24 +163,23 @@ def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
     acc_dtype = acc_dtype_for(b.dtype)
     batch = b.shape[0] if b.ndim == 3 else None
     nb = n // bn
-    lj, cj = _sdd_col_maps(depth, nb)
     row_axis = b.ndim - 2
     b_view = row_view(b, row_axis)
     rows_out = 1 if br is None else br
     if br is None:
         dy = row_view(dy, row_axis)
-        dy_block, dy_index = (None, 1, bn), (lambda r, j: (r, 0, cj(j)))
+        dy_block, dy_index = (None, 1, bn), (lambda r, j: (r, 0, j))
     else:
-        dy_block, dy_index = (br, bn), (lambda r, j: (r, cj(j)))
+        dy_block, dy_index = (br, bn), (lambda r, j: (r, j))
     if batch is None:
         bz = None
-        grid = (nb + depth - 1,)
+        grid = (nb,)
         in_specs = [
             pl.BlockSpec(dy_block, lambda p, j, rows, cols:
                          dy_index(rows[p], j)),
             *[pl.BlockSpec((None, 1, bn),
                            lambda p, j, rows, cols, i=i:
-                           (gather_col(cols, p * g + i), 0, lj(j)))
+                           (gather_col(cols, p * g + i), 0, j))
               for i in range(g)],
         ]
         out_specs = pl.BlockSpec((None, rows_out, g),
@@ -238,23 +187,19 @@ def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
         bpan_shape = (g, bn)
     else:
         bz = batch_block(batch)
-        grid = (batch // bz, nb + depth - 1)
+        grid = (batch // bz, nb)
         in_specs = [
             pl.BlockSpec((bz,) + dy_block, lambda p, z, j, rows, cols:
                          (z,) + dy_index(rows[p], j)),
             *[pl.BlockSpec((bz, None, 1, bn),
                            lambda p, z, j, rows, cols, i=i:
-                           (z, gather_col(cols, p * g + i), 0, lj(j)))
+                           (z, gather_col(cols, p * g + i), 0, j))
               for i in range(g)],
         ]
         out_specs = pl.BlockSpec((None, rows_out, g),
                                  lambda p, z, j, rows, cols: (p, 0, 0))
         bpan_shape = (bz, g, bn)
-    if depth > 1:
-        bpan_shape = (depth,) + bpan_shape     # packed ping-pong
-        kernel = functools.partial(_piped_sdd_kernel, g, bz, depth)
-    else:
-        kernel = functools.partial(_sdd_kernel, g, bz)
+    kernel = functools.partial(_sdd_kernel, g, bz)
     scratch = [pltpu.VMEM(bpan_shape, b.dtype),
                pltpu.VMEM((rows_out, g), acc_dtype)]
 
@@ -282,12 +227,11 @@ def _sdd_panels(panel_rows, lane_cols, dy, b, *, g: int, br: int | None,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("g", "bn", "interpret", "pipeline_depth",
+                   static_argnames=("g", "bn", "interpret",
                                     "panels_per_call"))
 def csr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                           dy: jax.Array, b: jax.Array, *, g: int,
                           interpret: bool, bn: int | None = None,
-                          pipeline_depth: int = 1,
                           panels_per_call: int | None = None) -> jax.Array:
     """Per-nonzero gradients for the CSR part, in panel layout.
 
@@ -308,17 +252,14 @@ def csr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
       gather real slots with ``PanelCSR.gather_values``.
     """
     return _sdd_panels(panel_rows, lane_cols, dy, b, g=g, br=None, bn=bn,
-                       interpret=interpret, pipeline_depth=pipeline_depth,
-                       per_call=panels_per_call)
+                       interpret=interpret, per_call=panels_per_call)
 
 
 @functools.partial(jax.jit, static_argnames=("g", "br", "bn", "interpret",
-                                             "pipeline_depth",
                                              "panels_per_call"))
 def bcsr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
                            dy_pad: jax.Array, b: jax.Array, *, g: int,
                            br: int, interpret: bool, bn: int | None = None,
-                           pipeline_depth: int = 1,
                            panels_per_call: int | None = None) -> jax.Array:
     """Per-tile-element gradients for the BCSR part, in panel layout.
 
@@ -340,8 +281,7 @@ def bcsr_sdd_panels_pallas(panel_rows: jax.Array, lane_cols: jax.Array,
       undefined — gather real slots with ``PanelBCSR.gather_values``.
     """
     return _sdd_panels(panel_rows, lane_cols, dy_pad, b, g=g, br=br, bn=bn,
-                       interpret=interpret, pipeline_depth=pipeline_depth,
-                       per_call=panels_per_call)
+                       interpret=interpret, per_call=panels_per_call)
 
 
 register_kernel("csr", "sdd", "panels", csr_sdd_panels_pallas)

@@ -256,10 +256,6 @@ class _EngineTracer:
         sb = fields.get("scratch_bytes")
         if sb is not None:
             m.gauge("kernel.scratch_bytes", part=part, op=op).set(float(sb))
-        ov = fields.get("prefetch_overlap")
-        if ov is not None:
-            m.gauge("engine.prefetch_overlap", part=part,
-                    op=op).set(float(ov))
         if self.prev is not None:
             self.prev.on_dispatch(part=part, op=op, **fields)
 

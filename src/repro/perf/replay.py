@@ -30,8 +30,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..kernels.panel_common import (CSR_WORDS, block_row_steps, default_bn,
-                                   gather_bn, panel_calls)
+from ..kernels.panel_common import block_row_steps, default_bn, gather_bn
 from .trace import fit_cost_model, load_traces
 
 __all__ = ["predict_grid_steps", "predict_part_steps", "TraceDB", "replay"]
@@ -53,22 +52,17 @@ def predict_part_steps(csr, plan, n_cols: int,
         *nonzero-valued* entries in the block (``bcsr_from_csr_rows`` drops
         zero-valued structural pads and keeps ≥ 1 pad tile per empty
         block-row); their lanes give the kernel's window steps
-        (``panel_common.block_row_steps``), with no ramp, once per column
-        block of ``panel_common.gather_bn``;
+        (``panel_common.block_row_steps``), once per column block of
+        ``panel_common.gather_bn``;
       * a part the executor skips entirely (``r_b == 0`` / ``r_b == nrows``)
         contributes zero;
-      * ``macro_m > 1`` panelizes at the effective width ``panel_g·macro_m``
-        and ``pipeline_depth = d`` adds ``d - 1`` ramp steps per
-        ``pallas_call`` (SMEM-sized panel chunk) of a non-empty CSR part,
-        exactly like the conversion;
       * the CSR count scales by ``ceil(n_cols / bn)`` column blocks
         (``bn`` defaults to ``panel_common.default_bn(n_cols)`` like the
         executor).
     """
     r_b = int(plan.r_boundary)
     br = int(plan.br)
-    g = max(int(plan.panel_g), 1) * max(int(getattr(plan, "macro_m", 1)), 1)
-    depth = max(int(getattr(plan, "pipeline_depth", 1)), 1)
+    g = max(int(plan.panel_g), 1)
     n = int(csr.nrows)
     bn = bn or default_bn(int(n_cols))
     col_blocks = -(-int(n_cols) // bn)
@@ -98,10 +92,7 @@ def predict_part_steps(csr, plan, n_cols: int,
                                       minlength=nblocks)
         w_bcsr = block_row_steps(np.maximum(-(-tiles_per_block // g), 1) * g)
 
-    # One ramp per SMEM-sized chunk launch of the CSR part.
-    s_csr = ((p_csr + panel_calls(p_csr, g, CSR_WORDS) * (depth - 1))
-             * col_blocks if p_csr else 0)
-    return s_csr, w_bcsr * (int(n_cols) // gather_bn(int(n_cols), bn))
+    return p_csr * col_blocks, w_bcsr * (int(n_cols) // gather_bn(int(n_cols), bn))
 
 
 def predict_grid_steps(csr, plan, n_cols: int, bn: int | None = None) -> int:
@@ -153,15 +144,9 @@ class TraceDB:
         panel widths (or there are too few for 5 coefficients) the fit
         drops to the 3-term form with the ``b`` terms pinned at zero.
 
-        When the cells span more than one ``pipeline_depth`` a sixth
-        ``d_pipe·(depth-1)·(steps_csr+steps_bcsr)`` term is fitted — the
-        marginal cost (or saving) of running a step under the
-        double-buffered pipeline.
-
-        Returns ``[c0, a_csr, a_bcsr, b_csr, b_bcsr]`` (optionally extended
-        with ``d_pipe``) or ``None`` when the cells cannot determine a
-        positive per-step cost (fewer than two distinct step counts, or a
-        degenerate fit).
+        Returns ``[c0, a_csr, a_bcsr, b_csr, b_bcsr]`` or ``None`` when the
+        cells cannot determine a positive per-step cost (fewer than two
+        distinct step counts, or a degenerate fit).
         """
         cells = self._cells(backend)
         if len(cells) < 2:
@@ -171,45 +156,33 @@ class TraceDB:
         sb = np.array([r.get("grid_steps_bcsr", 0) for r in cells],
                       np.float64)
         g = np.array([r.get("panel_g", 1) for r in cells], np.float64)
-        d = np.array([r.get("pipeline_depth", 1) for r in cells], np.float64)
         w = np.array([r["wall_us"] for r in cells], np.float64)
         if len(np.unique(sc + sb)) < 2:
             return None
         use_g = len(np.unique(g)) > 1 and len(cells) >= 6
-        use_d = len(np.unique(d)) > 1 and len(cells) >= (8 if use_g else 5)
         cols = [np.ones_like(sc), sc, sb]
         if use_g:
             cols += [sc * g, sb * g]
-        if use_d:
-            cols += [(d - 1.0) * (sc + sb)]
         design = np.stack(cols, axis=1)
         ncoef = design.shape[1]
         ata = design.T @ design
         lam = ridge * max(float(np.trace(ata)) / ncoef, 1.0)
         coef = np.linalg.solve(ata + lam * np.eye(ncoef), design.T @ w)
-        if use_d:
-            d_pipe = coef[-1:]          # may legitimately be negative
-            coef = coef[:-1]
-        else:
-            d_pipe = np.zeros((0,))
         if not use_g:
             coef = np.concatenate([coef, [0.0, 0.0]])
         # A usable model needs a non-negative floor and at least one
-        # positive per-step cost; clamp tiny negatives from noise (the
-        # pipeline term is exempt — overlap SHOULD make it negative).
+        # positive per-step cost; clamp tiny negatives from noise.
         coef = np.maximum(coef, 0.0)
         if coef[1:].sum() <= 0:
             return None
-        return np.concatenate([coef, d_pipe]) if use_d else coef
+        return coef
 
     def predict_us(self, coef: np.ndarray, s_csr: int, s_bcsr: int,
-                   g: int, depth: int = 1) -> float:
-        """Evaluate a :meth:`step_cost` coefficient vector at one cell.
-        ``g`` is the *effective* panel width (``panel_g × macro_m``)."""
+                   g: int) -> float:
+        """Evaluate a :meth:`step_cost` coefficient vector at one cell of
+        panel width ``g``."""
         us = float(coef[0] + (coef[1] + coef[3] * g) * s_csr
                    + (coef[2] + coef[4] * g) * s_bcsr)
-        if len(coef) > 5:
-            us += float(coef[5]) * (depth - 1) * (s_csr + s_bcsr)
         return max(us, 0.0)
 
     def cost_model(self, *, ridge: float = 1e-3):
@@ -233,8 +206,5 @@ def replay(plan, trace_db: TraceDB, *, csr, n_cols: int,
     if coef is None:
         return None
     s_csr, s_bcsr = predict_part_steps(csr, plan, n_cols, bn)
-    g_eff = (max(int(plan.panel_g), 1)
-             * max(int(getattr(plan, "macro_m", 1)), 1))
-    us = trace_db.predict_us(coef, s_csr, s_bcsr, g_eff,
-                             depth=int(getattr(plan, "pipeline_depth", 1)))
+    us = trace_db.predict_us(coef, s_csr, s_bcsr, max(int(plan.panel_g), 1))
     return us * 1e-6

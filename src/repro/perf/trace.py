@@ -76,8 +76,6 @@ def _plan_fields(plan, nrows: int) -> Dict:
         "r_frac": float(plan.r_boundary) / max(int(nrows), 1),
         "t_vpu": int(plan.t_vpu), "t_mxu": int(plan.t_mxu),
         "br": int(plan.br), "panel_g": int(plan.panel_g),
-        "pipeline_depth": int(getattr(plan, "pipeline_depth", 1)),
-        "macro_m": int(getattr(plan, "macro_m", 1)),
     }
 
 

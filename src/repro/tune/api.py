@@ -44,7 +44,6 @@ def default_cache() -> PlanCache:
 
 def make_record(features, *, dtype, n_cols: int, backend: str, r_frac: float,
                 t_vpu: int, t_mxu: int, br: int, panel_g: int = 1,
-                pipeline_depth: int = 1, macro_m: int = 1,
                 gflops: float = 0.0, trials: int = 0) -> Dict:
     """The one place the cache-record schema is spelled out (the distributed
     scheduler and the search path both store through here).  ``r_frac`` (not
@@ -58,9 +57,7 @@ def make_record(features, *, dtype, n_cols: int, backend: str, r_frac: float,
         "backend": backend,
         "plan": {"r_frac": float(r_frac), "t_vpu": int(t_vpu),
                  "t_mxu": int(t_mxu), "br": int(br),
-                 "panel_g": int(panel_g),
-                 "pipeline_depth": int(pipeline_depth),
-                 "macro_m": int(macro_m)},
+                 "panel_g": int(panel_g)},
         "gflops": float(gflops),
         "trials": int(trials),
     }
@@ -73,10 +70,7 @@ def record_from_result(fp: Fingerprint, res: SearchResult, *, nrows: int,
         fp.features(), dtype=dtype, n_cols=n_cols, backend=backend,
         r_frac=float(res.plan.r_boundary) / max(nrows, 1),
         t_vpu=res.plan.t_vpu, t_mxu=res.plan.t_mxu, br=res.plan.br,
-        panel_g=res.plan.panel_g,
-        pipeline_depth=getattr(res.plan, "pipeline_depth", 1),
-        macro_m=getattr(res.plan, "macro_m", 1),
-        gflops=res.gflops, trials=res.measured)
+        panel_g=res.plan.panel_g, gflops=res.gflops, trials=res.measured)
 
 
 def plan_from_record(rec: Mapping, nrows: int) -> SpmmPlan:
@@ -99,9 +93,7 @@ def plan_from_record(rec: Mapping, nrows: int) -> SpmmPlan:
     elif t_vpu == 0:                   # no vector workers -> pure BCSR
         r_b = 0
     return SpmmPlan(r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br,
-                    panel_g=int(p.get("panel_g", 1)),
-                    pipeline_depth=int(p.get("pipeline_depth", 1)),
-                    macro_m=int(p.get("macro_m", 1)))
+                    panel_g=int(p.get("panel_g", 1)))
 
 
 def autotune(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
@@ -156,9 +148,7 @@ def autotune(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
             cache.put(key, {**rec,
                             "fingerprint": [float(f) for f in fp.features()]})
         return loops_from_csr(csr, plan.r_boundary, plan.br,
-                              panel_g=plan.panel_g,
-                              macro_m=plan.macro_m,
-                              pipeline_depth=plan.pipeline_depth), plan
+                              panel_g=plan.panel_g), plan
     if on_miss == "model":
         from ..core.spmm import plan_and_convert
         fmt, plan = plan_and_convert(csr, total_workers=total_workers,
@@ -167,10 +157,7 @@ def autotune(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
             fp.features(), dtype=dt, n_cols=n_cols, backend=backend,
             r_frac=float(plan.r_boundary) / max(csr.nrows, 1),
             t_vpu=plan.t_vpu, t_mxu=plan.t_mxu, br=plan.br,
-            panel_g=plan.panel_g,
-            pipeline_depth=getattr(plan, "pipeline_depth", 1),
-            macro_m=getattr(plan, "macro_m", 1),
-            gflops=0.0, trials=0))
+            panel_g=plan.panel_g, gflops=0.0, trials=0))
         return fmt, plan
     res = search(csr, n_cols=n_cols, rhs_shape=rhs_shape,
                  total_workers=total_workers,

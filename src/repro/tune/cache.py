@@ -58,11 +58,12 @@ __all__ = ["PlanCache", "CacheStats", "CACHE_VERSION", "default_cache_dir"]
 # batched execution engine amortises A's panels across batch slices, so a
 # v2 record keyed on the trailing dim alone would transfer a plan tuned for
 # an 8x narrower workload.
-# v4: plans carry ``pipeline_depth`` (double-buffered B-panel prefetch) and
-# ``macro_m`` (same-row macro-step fusion) — knob-less v3 plans were tuned
-# against a strictly serial, unfused search space and must never replay as
-# if depth-1/macro-1 were still the only execution shape.
-CACHE_VERSION = 4
+# v4: plans carried a software-pipeline depth and a same-row macro-step
+# factor that multiplied ``panel_g``.
+# v5: both are gone and ``panel_g`` is the width the panels are packed at —
+# a v4 plan with a macro-step factor of 4 would replay at a quarter of the
+# width it was tuned at.
+CACHE_VERSION = 5
 
 # Lock-free read-retry: parse attempts before a persistently unparseable
 # file is quarantined, and the wait between them (a racing atomic write

@@ -7,8 +7,9 @@ The plan space is the cross product the paper's pipeline exposes:
     fraction sweep (the Algorithm 1 conversion is re-run per candidate, as a
     per-shape search would on hardware);
   * ``Br ∈ {2, 4, 8}`` — tile heights (cntd/cntf/cnth analogues);
-  * ``G ∈ {1, 4, 8}`` — panel widths (Figure-2 multi-tile fmopa rounds per
-    ZA-tile visit; the kernels' grid shrinks ~G-fold, padding permitting);
+  * ``G ∈ {1, 4, 8, 16, 32}`` — panel widths (Figure-2 multi-tile fmopa
+    rounds per ZA-tile visit; the CSR kernel's grid shrinks ~G-fold,
+    padding permitting);
   * ``(t_vpu, t_mxu)`` — worker splits with ``t_vpu + t_mxu = T``.
 
 Exhaustively *measuring* that space is what the paper avoids — its quadratic
@@ -100,15 +101,10 @@ def _r_candidates(csr: CSR, br: int, splits: Sequence[Tuple[int, int]],
 
 def enumerate_plans(csr: CSR, *, total_workers: int = 8,
                     br_choices: Sequence[int] = (2, 4, 8),
-                    g_choices: Sequence[int] = (1, 4, 8),
-                    depth_choices: Sequence[int] = (1, 2),
-                    macro_choices: Sequence[int] = (1, 4),
+                    g_choices: Sequence[int] = (1, 4, 8, 16, 32),
                     tp_vpu: float = 1.0, tp_mxu: float = 4.0
                     ) -> List[SpmmPlan]:
-    """The full (deduplicated) candidate plan space, including the pipeline
-    axes: ``pipeline_depth`` (double-buffered B-panel prefetch) and
-    ``macro_m`` (same-row macro-step fusion, panelizing at the effective
-    width ``panel_g·macro_m``)."""
+    """The full (deduplicated) candidate plan space."""
     seen, plans = set(), []
     splits = [(x, y) for (x, y) in _worker_splits(total_workers) if x + y > 0]
     for br in br_choices:
@@ -121,16 +117,13 @@ def enumerate_plans(csr: CSR, *, total_workers: int = 8,
                 if r_b < csr.nrows and t_mxu == 0:
                     continue
                 for g in g_choices:
-                    for d in depth_choices:
-                        for m in macro_choices:
-                            key = (r_b, br, t_vpu, t_mxu, g, d, m)
-                            if key in seen:
-                                continue
-                            seen.add(key)
-                            plans.append(SpmmPlan(
-                                r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu,
-                                br=br, panel_g=g, pipeline_depth=d,
-                                macro_m=m))
+                    key = (r_b, br, t_vpu, t_mxu, g)
+                    if key in seen:
+                        continue
+                    seen.add(key)
+                    plans.append(SpmmPlan(
+                        r_boundary=r_b, t_vpu=t_vpu, t_mxu=t_mxu, br=br,
+                        panel_g=g))
     return plans
 
 
@@ -158,9 +151,7 @@ def measure_plan_gflops(csr: CSR, plan: SpmmPlan, b: jax.Array, *,
     ``prod(batch) * N`` the engine actually processes."""
     from .fingerprint import effective_n_cols
     fmt = loops_from_csr(csr, plan.r_boundary, plan.br,
-                         panel_g=plan.panel_g,
-                         macro_m=getattr(plan, "macro_m", 1),
-                         pipeline_depth=getattr(plan, "pipeline_depth", 1))
+                         panel_g=plan.panel_g)
     f = jax.jit(lambda bb: loops_spmm(fmt, bb, backend=backend))
     secs = _time_fn(f, b, repeats=budget.repeats, warmup=budget.warmup)
     nnz = max(fmt.nnz, 1)
@@ -183,9 +174,7 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
            total_workers: int = 8,
            model: Optional[QuadraticPerfModel] = None,
            br_choices: Sequence[int] = (2, 4, 8),
-           g_choices: Sequence[int] = (1, 4, 8),
-           depth_choices: Sequence[int] = (1, 2),
-           macro_choices: Sequence[int] = (1, 4),
+           g_choices: Sequence[int] = (1, 4, 8, 16, 32),
            budget: SearchBudget = SearchBudget(), backend: str = "jnp",
            b: Optional[jax.Array] = None, seed: int = 0,
            tp_vpu: float = 1.0, tp_mxu: float = 4.0,
@@ -235,8 +224,6 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
             or (unit,)
     plans = enumerate_plans(csr, total_workers=total_workers,
                             br_choices=br_choices, g_choices=g_choices,
-                            depth_choices=depth_choices,
-                            macro_choices=macro_choices,
                             tp_vpu=tp_vpu, tp_mxu=tp_mxu)
 
     # Warm start.  The Eq. 2 model only sees the worker split, so by itself
@@ -248,30 +235,17 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
     # is ranked by its measured panel terms when the model has them, else by
     # the structural grid-step reduction it buys on this matrix.
     n = max(csr.nrows, 1)
-    # Priors are computed over *effective* widths (panel_g·macro_m) — the
-    # width the conversion actually panelizes at — so the macro axis shares
-    # the same structural step-reduction signal as the G axis.
-    eff_widths = sorted({max(g, 1) * max(m, 1)
-                         for g in g_choices for m in macro_choices}
-                        | set(g_choices))
-    step_prior = _step_reduction_priors(csr, eff_widths)
+    step_prior = _step_reduction_priors(csr, g_choices)
 
     if measure is None and backend == "jnp":
         # The jnp reference executes the flat arrays — wall clock on it is
-        # blind to panel_g/macro_m/pipeline_depth, so "measuring" those axes
-        # would let timing noise pick the cached knobs.  Pin (G, macro_m) to
-        # the structural winner (max grid-step reduction at the effective
-        # width; ties prefer the narrower effective panel, whose padding DMA
-        # is smaller, and within a width the macro-fused shape, which costs
-        # fewer grid dispatches), pin depth to 1 (ramp steps only ever add
-        # work the jnp path cannot observe), and spend the whole measurement
-        # budget on genuinely different (r_boundary, br) conversions.
-        g_star, m_star = max(
-            ((g, m) for g in g_choices for m in macro_choices),
-            key=lambda gm: (step_prior.get(gm[0] * gm[1], 0.0),
-                            -(gm[0] * gm[1]), gm[1]))
-        plans = [p for p in plans if p.panel_g == g_star
-                 and p.macro_m == m_star and p.pipeline_depth == 1]
+        # blind to panel_g, so "measuring" that axis would let timing noise
+        # pick the cached width.  Pin G to the structural winner (max
+        # grid-step reduction; ties prefer the narrower panel, whose
+        # padding DMA is smaller) and spend the whole measurement budget on
+        # genuinely different (r_boundary, br) conversions.
+        g_star = max(g_choices, key=lambda g: (step_prior.get(g, 0.0), -g))
+        plans = [p for p in plans if p.panel_g == g_star]
 
     def _prior(p: SpmmPlan) -> float:
         t_v = p.r_boundary / (tp_vpu * p.t_vpu) if p.r_boundary else 0.0
@@ -283,7 +257,7 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
             g_scale = 1.0
         else:
             capacity = float(model.predict(p.t_vpu, p.t_mxu))
-            g_scale = step_prior.get(p.panel_g * p.macro_m, 1.0)
+            g_scale = step_prior.get(p.panel_g, 1.0)
         return max(capacity, 1e-12) * g_scale * n / bottleneck
 
     # Replay-based pruning: when a trace database can support a per-step
@@ -300,9 +274,7 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
                 else n_cols
             def replay_rank(p: SpmmPlan) -> float:  # noqa: E731-style rebind
                 s_csr, s_bcsr = predict_part_steps(csr, p, eff_cols)
-                return trace_db.predict_us(
-                    coef, s_csr, s_bcsr, p.panel_g * p.macro_m,
-                    depth=p.pipeline_depth)
+                return trace_db.predict_us(coef, s_csr, s_bcsr, p.panel_g)
 
     scored = sorted(plans, key=(replay_rank if replay_rank is not None
                                 else lambda p: -_prior(p)))
@@ -311,25 +283,23 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
     seen_base = set()
     k = min(budget.top_k, budget.max_trials)
     # Two-pass slot allocation: a small budget must still span genuinely
-    # different (r_boundary, br) conversions — the panel/pipeline axes
-    # multiply the space and would otherwise fill every slot with shape
-    # variants of the single best boundary.  Each boundary/tile pair is
-    # represented by its best-ranked (G, macro_m, depth) shape; leftover
-    # slots then explore the remaining variants in rank order.
+    # different (r_boundary, br) conversions — the panel axis multiplies
+    # the space and would otherwise fill every slot with width variants of
+    # the single best boundary.  Each boundary/tile pair is represented by
+    # its best-ranked width; leftover slots then explore the remaining
+    # variants in rank order.
     for p in scored:
         base = (p.r_boundary, p.br)
         if base in seen_base:
             continue
         seen_base.add(base)
-        seen_conv.add((p.r_boundary, p.br, p.panel_g, p.macro_m,
-                       p.pipeline_depth))
+        seen_conv.add((p.r_boundary, p.br, p.panel_g))
         survivors.append(p)
         if len(survivors) >= k:
             break
     if len(survivors) < k:
         for p in scored:
-            conv = (p.r_boundary, p.br, p.panel_g, p.macro_m,
-                    p.pipeline_depth)
+            conv = (p.r_boundary, p.br, p.panel_g)
             if conv in seen_conv:
                 continue
             seen_conv.add(conv)
@@ -380,9 +350,7 @@ def search(csr: CSR, *, n_cols: int = 32, rhs_shape=None,
         note_degraded("tune.search.degraded", reason="all-trials-failed")
         best_plan = survivors[0] if survivors else scored[0]
         best_fmt = loops_from_csr(csr, best_plan.r_boundary, best_plan.br,
-                                  panel_g=best_plan.panel_g,
-                                  macro_m=best_plan.macro_m,
-                                  pipeline_depth=best_plan.pipeline_depth)
+                                  panel_g=best_plan.panel_g)
         best_g = 0.0
     return SearchResult(plan=best_plan, fmt=best_fmt, gflops=best_g,
                         trials=tuple(trials))

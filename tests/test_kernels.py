@@ -280,20 +280,19 @@ def _hub_matrix(rng):
     return a
 
 
-@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("g", [1, 4])
 @pytest.mark.parametrize("batched", [False, True])
-def test_csr_chunks_resume_rows_across_seams(rng, depth, batched):
+def test_csr_chunks_resume_rows_across_seams(rng, g, batched):
     a = _hub_matrix(rng)
     csr = csr_from_dense(a)
-    p = panelize_csr(csr, 4)
+    p = panelize_csr(csr, g)
     shape = ((3, 48, 8) if batched else (48, 8))
     b = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
     args = (jnp.asarray(p.panel_rows), jnp.asarray(p.lane_cols),
             jnp.asarray(p.lane_vals), b)
-    whole = csr_panels_spmm_pallas(*args, g=4, nrows=9, interpret=True,
-                                   pipeline_depth=depth)
-    chunked = csr_panels_spmm_pallas(*args, g=4, nrows=9, interpret=True,
-                                     pipeline_depth=depth, panels_per_call=3)
+    whole = csr_panels_spmm_pallas(*args, g=g, nrows=9, interpret=True)
+    chunked = csr_panels_spmm_pallas(*args, g=g, nrows=9, interpret=True,
+                                     panels_per_call=3)
     want = a @ np.asarray(b)
     np.testing.assert_allclose(np.asarray(chunked), want, rtol=1e-5,
                                atol=1e-5)
@@ -301,28 +300,34 @@ def test_csr_chunks_resume_rows_across_seams(rng, depth, batched):
                                rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.parametrize("depth", [1, 2])
-def test_bcsr_chunks_with_carry_keep_foreign_rows(rng, depth):
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 1e-3)],
+                         ids=["f32", "bf16"])
+def test_bcsr_chunks_with_carry_keep_foreign_rows(rng, dtype, tol):
     """The fused path's carry across chunk launches: rows the kernel never
     visits keep the carry's values, visited block-rows spanning a seam sum
     every step.  Block-row 1 (300 tiles) spans three 128-lane windows, so
-    at 1 or 2 steps per call both its window seams are call seams."""
+    at 1 or 2 steps per call both its window seams are call seams.  bf16
+    operands accumulate in fp32, so the fp32 product of the same bf16
+    values is the oracle."""
     a = np.zeros((16, 300), np.float32)
     a[4:8] = rng.standard_normal((4, 300))         # block-row 1: 300 tiles
     a[0, 3], a[13, 7] = 1.0, -2.0
+    a = np.asarray(jnp.asarray(a, dtype))
     fmt = loops_from_csr(csr_from_dense(a), 0, 4, panel_g=4)
     p = fmt.bcsr_panels
     assert p.steps[0].tolist() == [0, 1, 1, 1, 2, 3]
-    b = jnp.asarray(rng.standard_normal((300, 8)).astype(np.float32))
+    b = jnp.asarray(rng.standard_normal((300, 8)), dtype)
+    want = (np.asarray(a, np.float64) @ np.asarray(b, np.float64))
     sentinel = jnp.full((24, 8), 7.0, jnp.float32)  # rows 16..23: foreign
     for per_call in (1, 2, 4):
         got = bcsr_panels_spmm_pallas(
             p.panel_rows, jnp.asarray(p.lane_cols),
             jnp.asarray(p.vals_window), b, g=4, nblocks=p.nblocks,
-            out_rows=24, carry=sentinel, interpret=True,
-            pipeline_depth=depth, per_call=per_call)
-        np.testing.assert_allclose(np.asarray(got)[:16], a @ np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
+            out_rows=24, carry=sentinel, interpret=True, per_call=per_call)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got)[:16], want, rtol=tol,
+                                   atol=tol)
         np.testing.assert_array_equal(np.asarray(got)[16:], 7.0)
 
 
@@ -347,20 +352,18 @@ def test_sdd_chunks_match_single_call(rng, part):
                                np.asarray(run()), rtol=1e-6, atol=1e-6)
 
 
-def test_grid_steps_count_one_ramp_per_chunk():
-    """Depth-2 grids pay their ramp once per launch: a part with more
-    panels than one SMEM chunk holds counts every launch's ramp, and the
-    replay predictor agrees exactly."""
+def test_grid_steps_equal_panels_whatever_the_chunking():
+    """A part with more panels than one SMEM chunk holds runs as several
+    launches, and its grid still takes exactly one step per panel: the
+    chunking adds none, and the replay predictor agrees exactly."""
     from repro.core.spmm import SpmmPlan
     from repro.kernels.panel_common import CSR_WORDS, panel_calls
     from repro.perf.replay import predict_part_steps
     a = np.zeros((30_000, 64), np.float32)
     a[np.arange(30_000), np.arange(30_000) % 64] = 1.0
     csr = csr_from_dense(a)
-    plan = SpmmPlan(r_boundary=30_000, t_vpu=1, t_mxu=0, br=8, panel_g=1,
-                    pipeline_depth=2)
-    fmt = loops_from_csr(csr, 30_000, 8, panel_g=1, pipeline_depth=2)
-    calls = panel_calls(fmt.csr_panels.npanels, 1, CSR_WORDS)
-    assert calls > 1
-    assert loops_grid_steps(fmt, 8) == 30_000 + calls
-    assert predict_part_steps(csr, plan, 8) == (30_000 + calls, 0)
+    plan = SpmmPlan(r_boundary=30_000, t_vpu=1, t_mxu=0, br=8, panel_g=1)
+    fmt = loops_from_csr(csr, 30_000, 8, panel_g=1)
+    assert panel_calls(fmt.csr_panels.npanels, 1, CSR_WORDS) > 1
+    assert loops_grid_steps(fmt, 8) == 30_000
+    assert predict_part_steps(csr, plan, 8) == (30_000, 0)

@@ -1,21 +1,20 @@
-"""Pipelined panel kernels: depth/macro parity, grid-step accounting,
-packed half-precision, knob plumbing, and the default_bn regression.
+"""Panel kernels at every width: results against the oracles, grid-step
+accounting, packed half precision, plan plumbing, and the default_bn
+regression.
 
-The contracts under test (docs/architecture.md §"Pipelined panels"):
+The contracts under test (docs/architecture.md §"Packed half precision"):
 
-  * ``pipeline_depth ∈ {1, 2}`` NEVER changes results — unbatched results
-    are *bitwise* identical across depths (the piped compute stream replays
-    the depth-1 expression from scratch); batched results agree to ~1 ulp
-    (XLA contracts multiply-adds differently across the two graphs);
-  * ``macro_m`` panelizes at the effective width ``panel_g·macro_m`` and
-    agrees with the oracle to dtype tolerance;
-  * grid steps = ``(panels_at_g_eff + depth - 1) × col_blocks`` for the
-    CSR part plus the BCSR part's window steps, and
-    ``perf.replay.predict_part_steps`` replicates the conversion exactly;
+  * ``panel_g`` is the one panel width: the fused path, each part alone,
+    the batched grid, row-boundary tails and the custom VJP all agree with
+    the jnp oracle at every width;
+  * grid steps = ``panels_at_g × col_blocks`` for the CSR part plus the
+    BCSR part's window steps, and ``perf.replay.predict_part_steps``
+    replicates the conversion exactly;
   * ``default_bn`` picks the largest lane-aligned divisor ≤ 512 (the
     ``N=600`` ValueError regression);
-  * plans round-trip the knobs through the v4 tuner cache, and dispatch
-    notes carry ``scratch_bytes``/``prefetch_overlap`` into obs gauges.
+  * plans round-trip ``panel_g`` through the v5 tuner cache, the tuner's
+    width axis is ``{1, 4, 8, 16, 32}``, and dispatch notes carry
+    ``scratch_bytes`` into obs gauges.
 """
 import jax
 import jax.numpy as jnp
@@ -43,37 +42,39 @@ def _sparse(rng, m, k, density, dtype):
     return np.asarray(jnp.asarray(a, dtype))
 
 
-def _fmt(csr, g, depth, macro, r_frac=0.5, br=4):
+def _fmt(csr, g, r_frac=0.5, br=4):
     r_b = min(max(int(r_frac * csr.nrows) // br * br, 0), csr.nrows)
-    return loops_from_csr(csr, r_b, br, panel_g=g, pipeline_depth=depth,
-                          macro_m=macro)
+    return loops_from_csr(csr, r_b, br, panel_g=g)
 
 
-# -- forward parity vs oracle: dtypes x G x depth x macro -------------------
+def _oracle(fmt, b):
+    return np.asarray(loops_spmm(fmt, b, backend="jnp"), np.float32)
+
+
+# -- forward vs oracle: dtypes x G x N ---------------------------------------
 
 @pytest.mark.parametrize("dtype,tol", DTYPES)
-@pytest.mark.parametrize("g", [1, 8])
-@pytest.mark.parametrize("depth", [1, 2])
-@pytest.mark.parametrize("macro", [1, 4])
-def test_piped_fused_path_matches_oracle(rng, dtype, tol, g, depth, macro):
-    """The fused single-pass engine path (input_output_aliases carry) under
-    every knob combination must agree with the dense oracle."""
+@pytest.mark.parametrize("g", [1, 4, 8, 32])
+@pytest.mark.parametrize("n", [16, 40])
+def test_fused_path_matches_oracle(rng, dtype, tol, g, n):
+    """The fused single-pass engine path (input_output_aliases carry) at
+    every panel width must agree with the dense oracle."""
     a = _sparse(rng, M, K, 0.3, dtype)
-    b = jnp.asarray(rng.standard_normal((K, N)), dtype)
-    fmt = _fmt(csr_from_dense(a), g, depth, macro)
+    b = jnp.asarray(rng.standard_normal((K, n)), dtype)
+    fmt = _fmt(csr_from_dense(a), g)
     got = loops_spmm(fmt, b, backend="interpret")
     want = np.asarray(a, np.float32) @ np.asarray(b, np.float32)
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=10 * tol, atol=10 * tol)
 
 
-def test_fp64_piped_matches_oracle(rng):
+def test_fp64_fused_path_matches_oracle(rng):
     jax.config.update("jax_enable_x64", True)
     try:
         a = _sparse(rng, M, K, 0.3, jnp.float64)
         b = jnp.asarray(rng.standard_normal((K, N)), jnp.float64)
-        for g in (1, 8):
-            fmt = _fmt(csr_from_dense(a), g, 2, 4)
+        for g in (1, 8, 32):
+            fmt = _fmt(csr_from_dense(a), g)
             got = loops_spmm(fmt, b, backend="interpret")
             np.testing.assert_allclose(np.asarray(got),
                                        np.asarray(a) @ np.asarray(b),
@@ -82,91 +83,81 @@ def test_fp64_piped_matches_oracle(rng):
         jax.config.update("jax_enable_x64", False)
 
 
-# -- the depth contract: bitwise unbatched, ~ulp batched --------------------
+# -- each part alone, the batched grid, tails and gradients vs oracle -------
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype,tol", DTYPES)
 @pytest.mark.parametrize("g", [1, 4, 8])
-@pytest.mark.parametrize("macro", [1, 4])
-def test_depth_is_bitwise_invariant_unbatched(rng, dtype, g, macro):
-    """pipeline_depth=2 must be EXACTLY depth-1, bit for bit (unbatched):
-    the piped kernels stage raw B rows + the mask panel and replay the
-    depth-1 expression, so the float graphs are identical."""
+@pytest.mark.parametrize("r_frac", [0.0, 1.0])
+def test_each_part_alone_matches_oracle(rng, dtype, tol, g, r_frac):
+    """A pure-BCSR (``r_frac`` 0) or pure-CSR (``r_frac`` 1) format runs
+    one kernel through the parts path and must agree with the jnp oracle
+    on the same format."""
     a = _sparse(rng, M, K, 0.3, dtype)
-    csr = csr_from_dense(a)
     b = jnp.asarray(rng.standard_normal((K, N)), dtype)
-    y1 = loops_spmm(_fmt(csr, g, 1, macro), b, backend="interpret")
-    y2 = loops_spmm(_fmt(csr, g, 2, macro), b, backend="interpret")
-    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
+    fmt = _fmt(csr_from_dense(a), g, r_frac=r_frac)
+    got = loops_spmm(fmt, b, backend="interpret")
+    np.testing.assert_allclose(np.asarray(got, np.float32), _oracle(fmt, b),
+                               rtol=10 * tol, atol=10 * tol)
 
 
-def test_depth_parity_batched(rng):
-    """Batched (rank-3) depth parity: allclose, not bitwise — XLA contracts
-    the multiply-adds of the two graphs differently at bz > 1."""
+def test_batched_matches_oracle(rng):
+    """The batched (rank-3) grid agrees with the jnp oracle slice by
+    slice."""
     a = _sparse(rng, M, K, 0.3, jnp.float32)
-    csr = csr_from_dense(a)
+    fmt = _fmt(csr_from_dense(a), 4)
     b3 = jnp.asarray(rng.standard_normal((4, K, N)).astype(np.float32))
-    y1 = loops_spmm(_fmt(csr, 4, 1, 1), b3, backend="interpret")
-    y2 = loops_spmm(_fmt(csr, 4, 2, 1), b3, backend="interpret")
-    assert y1.shape == (4, M, N)
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2),
-                               rtol=1e-6, atol=1e-6)
+    got = loops_spmm(fmt, b3, backend="interpret")
+    assert got.shape == (4, M, N)
+    np.testing.assert_allclose(np.asarray(got), _oracle(fmt, b3),
+                               rtol=1e-5, atol=1e-5)
 
 
-def test_depth_parity_row_boundary_tails(rng):
+def test_row_boundary_tails_match_oracle(rng):
     """Row-boundary tails: a conversion whose last CSR panel and last BCSR
-    block-row are both partial must stay depth-invariant."""
+    block-row are both partial agrees with the oracle."""
     a = _sparse(rng, 23, 19, 0.4, jnp.float32)
     csr = csr_from_dense(a)
     b = jnp.asarray(rng.standard_normal((19, 8)).astype(np.float32))
     for r_b in (4, 20):     # tails in both parts
-        y1 = loops_spmm(loops_from_csr(csr, r_b, 8, panel_g=4), b,
-                        backend="interpret")
-        y2 = loops_spmm(loops_from_csr(csr, r_b, 8, panel_g=4,
-                                       pipeline_depth=2), b,
-                        backend="interpret")
-        np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
+        fmt = loops_from_csr(csr, r_b, 8, panel_g=4)
+        np.testing.assert_allclose(
+            np.asarray(loops_spmm(fmt, b, backend="interpret")),
+            _oracle(fmt, b), rtol=1e-5, atol=1e-5)
 
 
-def test_depth_parity_gradients(rng):
-    """The SDD backward pipeline (depth-2 column-block reduction) must
-    produce the same gradients as the serial path."""
+def test_gradients_match_reference(rng):
+    """The custom VJP (Aᵀ through the panel kernels) agrees with
+    ``jax.grad`` of the jnp reference, which differentiates natively."""
     a = _sparse(rng, M, K, 0.3, jnp.float32)
-    csr = csr_from_dense(a)
+    fmt = _fmt(csr_from_dense(a), 4)
     b = jnp.asarray(rng.standard_normal((K, N)).astype(np.float32))
 
-    def loss(fmt):
+    def grad(backend):
         return jax.grad(lambda bb: jnp.sum(
-            loops_spmm(fmt, bb, backend="interpret")))(b)
+            loops_spmm(fmt, bb, backend=backend) ** 2))(b)
 
-    g1 = loss(_fmt(csr, 4, 1, 1))
-    g2 = loss(_fmt(csr, 4, 2, 1))
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
-                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(grad("interpret")),
+                               np.asarray(grad("jnp")), rtol=1e-5,
+                               atol=1e-5)
 
 
 if HAVE_HYPOTHESIS:
     @given(seed=st.integers(0, 2**31 - 1),
-           g=st.sampled_from([1, 4, 8]),
-           depth=st.sampled_from([1, 2]),
-           macro=st.sampled_from([1, 2, 4]),
+           g=st.sampled_from([1, 4, 8, 16, 32]),
+           br=st.sampled_from([2, 4, 8]),
            density=st.floats(0.05, 0.6))
-    def test_knobs_never_change_results_property(seed, g, depth, macro,
-                                                 density):
-        """Property: for ANY seeded matrix, (depth, macro) only reshape the
-        schedule — the result still matches the knob-less execution to
-        float32 tolerance, and depth alone is bitwise-invariant."""
+    def test_knobs_never_change_results_property(seed, g, br, density):
+        """Property: for ANY seeded matrix, the panel width and tile
+        height only reshape the schedule — the result matches the G=1
+        execution to float32 tolerance."""
         rng = np.random.default_rng(seed)
         a = _sparse(rng, 12, 10, density, jnp.float32)
         csr = csr_from_dense(a)
         b = jnp.asarray(rng.standard_normal((10, 8)).astype(np.float32))
-        base = loops_spmm(_fmt(csr, g, 1, 1), b, backend="interpret")
-        knobbed = loops_spmm(_fmt(csr, g, depth, macro), b,
-                             backend="interpret")
+        base = loops_spmm(_fmt(csr, 1, br=br), b, backend="interpret")
+        knobbed = loops_spmm(_fmt(csr, g, br=br), b, backend="interpret")
         np.testing.assert_allclose(np.asarray(base), np.asarray(knobbed),
                                    rtol=1e-5, atol=1e-5)
-        if macro == 1:
-            np.testing.assert_array_equal(
-                np.asarray(base), np.asarray(knobbed))
 else:
     def test_knobs_never_change_results_property():
         pytest.skip("hypothesis not installed")
@@ -194,135 +185,119 @@ def test_wide_operand_n600_regression(rng):
     a = _sparse(rng, 16, 12, 0.3, jnp.float32)
     csr = csr_from_dense(a)
     b = jnp.asarray(rng.standard_normal((12, 600)).astype(np.float32))
-    got = loops_spmm(_fmt(csr, 4, 2, 4), b, backend="interpret")
+    got = loops_spmm(_fmt(csr, 16), b, backend="interpret")
     want = np.asarray(a) @ np.asarray(b)
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-4, atol=1e-4)
 
 
 # -- grid-step accounting ---------------------------------------------------
 
-def test_grid_steps_ramp_and_macro(rng):
-    """Steps = (panels_at_g_eff + depth - 1) x col_blocks for the CSR part
-    plus the BCSR part's window steps, which have no ramp; macro_m shrinks
-    the panel count, depth adds the CSR ramp."""
+def test_grid_steps_at_panel_width(rng):
+    """Steps = panels_at_g x col_blocks for the CSR part plus the BCSR
+    part's window steps; a wider panel shrinks the CSR grid."""
     a = _sparse(rng, 24, 20, 0.4, jnp.float32)
     csr = csr_from_dense(a)
-    base = loops_grid_steps(_fmt(csr, 4, 1, 1), 16)
-    fused = loops_grid_steps(_fmt(csr, 4, 1, 4), 16)
-    piped = loops_grid_steps(_fmt(csr, 4, 2, 1), 16)
-    assert fused < base                  # macro fusion shrinks the grid
-    assert piped == base + 1             # one ramp step, the CSR part's
+    narrow, wide = _fmt(csr, 4), _fmt(csr, 16)
+    for fmt in (narrow, wide):
+        assert loops_grid_steps(fmt, 16) == (
+            fmt.csr_panels.npanels + fmt.bcsr_panels.steps[0].shape[0])
+    assert wide.csr_panels.npanels < narrow.csr_panels.npanels
+    assert loops_grid_steps(wide, 16) < loops_grid_steps(narrow, 16)
 
 
-@pytest.mark.parametrize("depth", [1, 2])
-@pytest.mark.parametrize("macro", [1, 4])
+@pytest.mark.parametrize("g", [1, 4, 8, 32])
 @pytest.mark.parametrize("n_cols", [16, 600])
-def test_predict_part_steps_matches_conversion(rng, depth, macro, n_cols):
+def test_predict_part_steps_matches_conversion(rng, g, n_cols):
     """perf.replay's structural predictor must replicate the conversion's
-    grid-step count exactly for every knob combination."""
+    grid-step count exactly at every panel width."""
     a = _sparse(rng, 32, 24, 0.25, jnp.float32)
     csr = csr_from_dense(a)
     for r_frac in (0.0, 0.5, 1.0):
         r_b = min(max(int(r_frac * 32) // 4 * 4, 0), 32)
-        plan = SpmmPlan(r_boundary=r_b, t_vpu=2, t_mxu=2, br=4, panel_g=4,
-                        pipeline_depth=depth, macro_m=macro)
-        fmt = loops_from_csr(csr, r_b, 4, panel_g=4, pipeline_depth=depth,
-                             macro_m=macro)
+        plan = SpmmPlan(r_boundary=r_b, t_vpu=2, t_mxu=2, br=4, panel_g=g)
+        fmt = loops_from_csr(csr, r_b, 4, panel_g=g)
         s_csr, s_bcsr = predict_part_steps(csr, plan, n_cols)
         assert s_csr + s_bcsr == loops_grid_steps(fmt, n_cols)
 
 
-# -- knob plumbing: plan/convert, tuner cache v4, dispatch notes ------------
+# -- plumbing: plan/convert, tuner cache v5, search space, dispatch notes ---
 
 def test_plan_and_convert_threads_knobs(rng):
+    """``br`` and ``panel_g`` reach the plan, the format and its panels."""
     a = _sparse(rng, 24, 20, 0.3, jnp.float32)
-    fmt, plan = plan_and_convert(csr_from_dense(a), total_workers=4,
-                                 pipeline_depth=2, macro_m=4)
-    assert plan.pipeline_depth == 2 and plan.macro_m == 4
-    assert fmt.pipeline_depth == 2 and fmt.macro_m == 4
-    assert fmt.panel_g_eff == max(fmt.panel_g, 1) * 4
+    fmt, plan = plan_and_convert(csr_from_dense(a), total_workers=4, br=4,
+                                 panel_g=16)
+    assert plan.br == 4 and plan.panel_g == 16
+    assert fmt.panel_g == 16 and fmt.bcsr_part.br == 4
+    assert fmt.csr_panels.g == 16 and fmt.bcsr_panels.g == 16
+    assert fmt.bcsr_panels.br == 4
 
 
-def test_cache_v4_roundtrip_and_v3_miss(tmp_path, rng):
-    """Records round-trip the knobs; a v3 (knob-less) cache file misses
-    cleanly under CACHE_VERSION 4."""
+def test_cache_v5_roundtrip_and_v4_miss(tmp_path, rng):
+    """Records round-trip the panel width; a v4 cache file misses cleanly
+    under CACHE_VERSION 5."""
     import json
 
     from repro.tune.api import make_record, plan_from_record
     from repro.tune.cache import CACHE_VERSION, PlanCache
 
-    assert CACHE_VERSION == 4
+    assert CACHE_VERSION == 5
     rec = make_record([1.0], dtype=np.float32, n_cols=32, backend="jnp",
-                      r_frac=0.5, t_vpu=2, t_mxu=2, br=4, panel_g=8,
-                      pipeline_depth=2, macro_m=4)
+                      r_frac=0.5, t_vpu=2, t_mxu=2, br=4, panel_g=32)
+    assert rec["version"] == 5
     plan = plan_from_record(rec, 48)
-    assert plan.pipeline_depth == 2 and plan.macro_m == 4
-    # knob-less records (a near-hit from an old neighbour) default to serial
+    assert plan.panel_g == 32 and plan.br == 4 and plan.r_boundary == 24
+    # a record without a width (a near-hit from an old neighbour) is G=1
     legacy = {"plan": {"r_frac": 0.5, "t_vpu": 2, "t_mxu": 2, "br": 4}}
-    p0 = plan_from_record(legacy, 48)
-    assert p0.pipeline_depth == 1 and p0.macro_m == 1
+    assert plan_from_record(legacy, 48).panel_g == 1
 
     stale = tmp_path / "stale"
     stale.mkdir()
     (stale / "plans.json").write_text(json.dumps(
-        {"version": 3, "entries": {"k": {"version": 3}}}))
+        {"version": 4, "entries": {"k": {"version": 4}}}))
     cache = PlanCache(path=str(stale))
     assert len(cache) == 0 and cache.lookup("k") is None
 
 
-def test_search_space_has_pipeline_axes(rng):
+def test_search_space_width_axis(rng):
+    """The tuner's width axis covers every panelization the product of the
+    old width and macro-step axes reached, each plan once."""
     from repro.tune.search import enumerate_plans
     a = _sparse(rng, 24, 20, 0.3, jnp.float32)
     plans = enumerate_plans(csr_from_dense(a), total_workers=4)
-    assert {p.pipeline_depth for p in plans} == {1, 2}
-    assert {p.macro_m for p in plans} == {1, 4}
+    assert {p.panel_g for p in plans} == {1, 4, 8, 16, 32}
+    assert len(set(plans)) == len(plans)
 
 
-def test_obs_gauges_scratch_and_overlap(rng):
-    """Dispatch notes surface scratch bytes + prefetch overlap as gauges."""
+def test_obs_gauges_scratch(rng):
+    """Dispatch notes surface each kernel's VMEM scratch as a gauge: the
+    CSR kernel's fp32 accumulator row, the BCSR kernel's larger stage."""
     from repro.obs import Obs
     a = _sparse(rng, 24, 20, 0.3, jnp.float32)
-    fmt = _fmt(csr_from_dense(a), 4, 2, 2)
+    fmt = _fmt(csr_from_dense(a), 4)
     obs = Obs(source="pipeline-test")
     with obs.attach_engine():
         loops_spmm(fmt, jnp.ones((20, 16), jnp.float32),
                    backend="interpret")
-    recs = obs.records()
-    sb = [r for r in recs if r.get("metric") == "kernel.scratch_bytes"]
-    ov = [r for r in recs if r.get("metric") == "engine.prefetch_overlap"]
-    assert sb and all(r["value"] > 0 for r in sb)
-    assert ov and any(r["value"] > 0 for r in ov)   # depth 2 => overlap
-    # serial execution reports zero overlap for the CSR part; the BCSR
-    # kernel prefetches the next step's rows at every depth
-    obs2 = Obs(source="pipeline-test-serial")
-    fmt1 = _fmt(csr_from_dense(a), 4, 1, 1)
-    with obs2.attach_engine():
-        loops_spmm(fmt1, jnp.ones((20, 16), jnp.float32),
-                   backend="interpret")
-    ov1 = {r["labels"]["part"]: r["value"] for r in obs2.records()
-           if r.get("metric") == "engine.prefetch_overlap"}
-    assert ov1["csr"] == 0.0
-    assert 0.0 <= ov1["bcsr"] < 1.0
+    sb = {r["labels"]["part"]: r["value"] for r in obs.records()
+          if r.get("metric") == "kernel.scratch_bytes"}
+    assert sb["csr"] == 16 * 4
+    assert sb["bcsr"] > sb["csr"]
 
 
 def test_packed_halfprec_scratch_and_accumulate(rng):
-    """bf16 B panels stay packed (b.dtype scratch) with fp32 accumulation:
-    the bf16 result must match the fp32-upcast oracle to bf16 tolerance,
-    and the scratch note must reflect the packed (2-byte) element size."""
+    """bf16 B rows stay packed with fp32 accumulation: the bf16 result must
+    match the fp32-upcast oracle to bf16 tolerance, and the scratch note
+    shows the same fp32 accumulator as an fp32 operand's."""
     from repro.kernels.engine import _panel_note_fields
     a = _sparse(rng, M, K, 0.3, jnp.bfloat16)
     b16 = jnp.asarray(rng.standard_normal((K, N)), jnp.bfloat16)
-    fmt = _fmt(csr_from_dense(a), 4, 2, 1)
+    fmt = _fmt(csr_from_dense(a), 4)
     got = loops_spmm(fmt, b16, backend="interpret")
     want = np.asarray(a, np.float32) @ np.asarray(b16, np.float32)
     np.testing.assert_allclose(np.asarray(got, np.float32), want,
                                rtol=3e-2, atol=3e-2)
-    packed = _panel_note_fields(part="csr", depth=2, npanels=8, nb=1, n=N,
-                                bn=None, g=4, br=1,
-                                b_dtype=jnp.bfloat16,
+    packed = _panel_note_fields(nb=1, n=N, bn=None,
                                 value_dtype=jnp.bfloat16)
-    wide = _panel_note_fields(part="csr", depth=2, npanels=8, nb=1, n=N,
-                              bn=None, g=4, br=1,
-                              b_dtype=jnp.float32,
-                              value_dtype=jnp.float32)
-    assert packed["scratch_bytes"] < wide["scratch_bytes"]
+    wide = _panel_note_fields(nb=1, n=N, bn=None, value_dtype=jnp.float32)
+    assert packed["scratch_bytes"] == wide["scratch_bytes"] == N * 4

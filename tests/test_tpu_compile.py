@@ -88,32 +88,31 @@ def _block_rows(npanels, nblocks):
     return (np.arange(npanels) * nblocks // npanels).astype(np.int32)
 
 
-def _kernel(part, op, *, br, depth, nrows=M, npanels=P):
+def _kernel(part, op, *, br, nrows=M, npanels=P):
     if (part, op) == ("csr", "spmm"):
         return lambda r, c, v, b: csr_panels_spmm_pallas(
-            r, c, v, b, g=G, nrows=nrows, interpret=False,
-            pipeline_depth=depth)
+            r, c, v, b, g=G, nrows=nrows, interpret=False)
     if (part, op) == ("bcsr", "spmm"):
         rows = _block_rows(npanels, nrows // br)
         return lambda r, c, v, b: bcsr_panels_spmm_pallas(
-            rows, c, v, b, g=G, nblocks=nrows // br, interpret=False,
-            pipeline_depth=depth)
+            rows, c, v, b, g=G, nblocks=nrows // br, interpret=False)
     if (part, op) == ("csr", "sdd"):
         return lambda r, c, dy, b: csr_sdd_panels_pallas(
-            r, c, dy, b, g=G, interpret=False, pipeline_depth=depth)
+            r, c, dy, b, g=G, interpret=False)
     return lambda r, c, dy, b: bcsr_sdd_panels_pallas(
-        r, c, dy, b, g=G, br=br, interpret=False, pipeline_depth=depth)
+        r, c, dy, b, g=G, br=br, interpret=False)
 
 
-@pytest.mark.parametrize("depth", [1, 2])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
-@pytest.mark.parametrize("n", [32, 128])
+@pytest.mark.parametrize("n", [32, 40, 128, 256])
 @pytest.mark.parametrize("part,op", [("csr", "spmm"), ("bcsr", "spmm"),
                                      ("csr", "sdd"), ("bcsr", "sdd")])
-def test_panel_kernel_compiles(one_chip, part, op, n, dtype, depth):
+def test_panel_kernel_compiles(one_chip, part, op, n, dtype):
+    """Each kernel at the widths the cells run (32; a GCN's 40 and 256)
+    and at one lane tile."""
     shapes, br = _operands(part, op, npanels=P, n=n, dtype=dtype)
-    hlo = _compile(_kernel(part, op, br=br, depth=depth), one_chip, *shapes)
+    hlo = _compile(_kernel(part, op, br=br), one_chip, *shapes)
     assert "tpu_custom_call" in hlo
 
 
@@ -124,7 +123,7 @@ def test_chunked_metadata_compiles(one_chip, part):
     npanels = 2 * panels_per_call(G) + 1000
     shapes, br = _operands(part, "spmm", npanels=npanels, n=32,
                            dtype=jnp.float32)
-    hlo = _compile(_kernel(part, "spmm", br=br, depth=1, npanels=npanels),
+    hlo = _compile(_kernel(part, "spmm", br=br, npanels=npanels),
                    one_chip, *shapes)
     assert hlo.count("tpu_custom_call") >= 2
 
@@ -154,11 +153,11 @@ def test_bcsr_window_steps_compile(one_chip, n, dtype, layout):
 def test_batched_kernel_compiles(one_chip, op):
     shapes, br = _operands("bcsr", op, npanels=P, n=32, dtype=jnp.float32,
                            batch=4)
-    hlo = _compile(_kernel("bcsr", op, br=br, depth=1), one_chip, *shapes)
+    hlo = _compile(_kernel("bcsr", op, br=br), one_chip, *shapes)
     assert "tpu_custom_call" in hlo
     shapes, br = _operands("csr", op, npanels=P, n=32, dtype=jnp.float32,
                            batch=4)
-    hlo = _compile(_kernel("csr", op, br=br, depth=1), one_chip, *shapes)
+    hlo = _compile(_kernel("csr", op, br=br), one_chip, *shapes)
     assert "tpu_custom_call" in hlo
 
 
@@ -166,19 +165,19 @@ _CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*custom-call\(.*"
                           r"custom_call_target=\"tpu_custom_call\"")
 
 
-@pytest.mark.parametrize("depth", [1, 2])
-def test_fused_kernels_carry_their_names(one_chip, depth):
+@pytest.mark.parametrize("n", [256, 40])
+def test_fused_kernels_carry_their_names(one_chip, n):
     """The fused ``loops_spmm`` names every kernel instruction after its
     part, so that a device trace tells the CSR kernel from the BCSR one;
     the BCSR part here has more panels than one call's SMEM holds, so its
-    chunk loop and remainder are named too."""
+    chunk loop and remainder are named too.  At a GCN's widths, fp32."""
     rng = np.random.default_rng(0)
     a = ((rng.random((8192, 256)) < 0.05)
          * rng.standard_normal((8192, 256))).astype(np.float32)
-    fmt, _ = plan_and_convert(csr_from_dense(a), pipeline_depth=depth)
-    assert fmt.bcsr_panels.npanels > panels_per_call(fmt.panel_g_eff)
+    fmt, _ = plan_and_convert(csr_from_dense(a))
+    assert fmt.bcsr_panels.npanels > panels_per_call(fmt.panel_g)
     hlo = _compile(lambda b: loops_spmm(fmt, b, backend="pallas"), one_chip,
-                   ((256, 32), jnp.float32))
+                   ((256, n), jnp.float32))
     names = [m.group(1) for m in map(_CUSTOM_CALL.match, hlo.splitlines())
              if m]
     assert len(names) == hlo.count('custom_call_target="tpu_custom_call"')

@@ -158,9 +158,7 @@ def test_search_matches_exhaustive_on_tiny_space():
     plans = enumerate_plans(csr, total_workers=4, br_choices=(2, 4),
                             g_choices=(1, 8))
     # budget large enough that pruning keeps every distinct conversion
-    # (the pipeline knobs are part of the conversion identity since v4)
-    n_convs = len({(p.r_boundary, p.br, p.panel_g, p.macro_m,
-                    p.pipeline_depth) for p in plans})
+    n_convs = len({(p.r_boundary, p.br, p.panel_g) for p in plans})
     res = search(csr, n_cols=8, total_workers=4, br_choices=(2, 4),
                  g_choices=(1, 8),
                  budget=SearchBudget(top_k=n_convs, max_trials=n_convs),
@@ -220,7 +218,7 @@ def test_search_warm_start_spans_conversions():
     search(csr, n_cols=8, total_workers=8, measure=measure)
     r_bs = {p.r_boundary for p in measured}
     assert any(0 < r < csr.nrows for r in r_bs), r_bs
-    assert len({(p.r_boundary, p.br, p.panel_g, p.macro_m, p.pipeline_depth)
+    assert len({(p.r_boundary, p.br, p.panel_g)
                 for p in measured}) == len(measured)
 
 
@@ -429,6 +427,24 @@ def test_shard_loops_auto_consults_cache(tmp_path):
     # a different device count is a different cache context
     shard_loops_auto(fmt, 8, cache=cache)
     assert cache.stats.misses == 2
+
+
+@pytest.mark.parametrize("g", [4, 32])
+def test_shard_loops_auto_records_the_format_width(tmp_path, g):
+    """The device split a distributed re-plan stores carries the width the
+    format's panels are packed at, and replays at that width."""
+    from repro.core import loops_from_csr
+    from repro.core.distributed import shard_loops_auto
+    from repro.tune.fingerprint import cache_key, loops_fingerprint
+    cache = PlanCache(str(tmp_path))
+    fmt = loops_from_csr(csr_from_dense(_dense(7, 64, 32, 0.2)), 32, 8,
+                         panel_g=g)
+    shard_loops_auto(fmt, 4, cache=cache)
+    key = cache_key(loops_fingerprint(fmt), n_cols=0,
+                    dtype=np.dtype(np.float32), backend="dist4")
+    rec = cache.peek(key)
+    assert rec["plan"]["panel_g"] == g == fmt.csr_panels.g
+    assert tune_api.plan_from_record(rec, fmt.nrows).panel_g == g
 
 
 def test_effective_n_cols_and_batched_cache_key(tmp_path):

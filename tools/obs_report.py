@@ -163,24 +163,18 @@ def report(records: List[Dict], *, top: int = 10,
                 f"{int(c.get('value', 0)):>8}{extra}")
         out(f"  total dispatches: {n_disp}")
 
-    # Pipelined-kernel gauges: VMEM scratch footprint and the fraction of
-    # grid steps whose B-panel assembly overlaps compute (0.0 = serial).
-    kern = defaultdict(dict)
+    # VMEM scratch footprint of each kernel, per (part, op).
+    kern = {}
     for g in gauges:
-        m = g.get("metric", "")
-        if m in ("kernel.scratch_bytes", "engine.prefetch_overlap"):
+        if g.get("metric", "") == "kernel.scratch_bytes":
             key = _label_str({k: v for k, v in g.get("labels", {}).items()
                               if k in ("part", "op")})
-            kern[key][m] = float(g.get("value", 0.0))
+            kern[key] = float(g.get("value", 0.0))
     if kern:
-        out("\nkernel pipeline (per (part, op)):")
-        out(f"  {'labels':<40} {'scratch':>12} {'overlap':>8}")
-        for key, row in sorted(kern.items()):
-            sb = row.get("kernel.scratch_bytes")
-            ov = row.get("engine.prefetch_overlap")
-            sb_s = f"{int(sb):>10}B " if sb is not None else f"{'-':>12}"
-            ov_s = f"{ov:>7.2f} " if ov is not None else f"{'-':>8}"
-            out(f"  {key:<40} {sb_s} {ov_s}")
+        out("\nkernel scratch (per (part, op)):")
+        out(f"  {'labels':<40} {'scratch':>12}")
+        for key, sb in sorted(kern.items()):
+            out(f"  {key:<40} {int(sb):>10}B")
 
     if hists:
         out("\nlatency histograms:")
